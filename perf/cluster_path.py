@@ -381,8 +381,11 @@ async def main() -> int:
     await gateway.stop()
     note("metrics + /shards scrape ok")
 
+    from stl_fusion_tpu.graph import require_accelerator
+
     out = {
         "metric": "cluster_path",
+        **require_accelerator("perf/cluster_path.py"),
         "ok": True,
         "servers": n_servers,
         "n_shards": n_shards,

@@ -101,22 +101,6 @@ def note(msg: str) -> None:
     print(f"# {msg}", file=sys.stderr, flush=True)
 
 
-def _setup_jax_cache() -> None:
-    import jax
-
-    cache = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
-    )
-    os.environ.setdefault(
-        "FUSION_MIRROR_CACHE", os.path.join(os.path.dirname(cache), ".fusion_mirror_cache")
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # noqa: BLE001 — cache is an optimization only
-        note(f"compilation cache unavailable: {e}")
-
-
 from stl_fusion_tpu.client import install_compute_call_type  # noqa: E402
 from stl_fusion_tpu.core import (  # noqa: E402
     ComputeService,
@@ -270,7 +254,10 @@ class Edge:
 
 
 async def main() -> None:
-    _setup_jax_cache()
+    from stl_fusion_tpu.graph import enable_program_cache, require_accelerator
+
+    device = require_accelerator("perf/edge_path.py")
+    enable_program_cache()
     n = int(os.environ.get("EDGE_GRAPH_NODES", 2_000_000))
     n_edges = int(os.environ.get("EDGE_NODES", 4))
     n_sessions = int(os.environ.get("EDGE_SESSIONS", 1_000_000))
@@ -672,6 +659,7 @@ async def main() -> None:
 
         result = {
             "metric": "edge_path",
+            **device,
             "graph_nodes": n,
             "edges_graph": int(backend.edge_count),
             "edge_nodes": n_edges,

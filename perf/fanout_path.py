@@ -57,22 +57,6 @@ def note(msg: str) -> None:
     print(f"# {msg}", file=sys.stderr, flush=True)
 
 
-def _setup_jax_cache() -> None:
-    import jax
-
-    cache = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
-    )
-    os.environ.setdefault(
-        "FUSION_MIRROR_CACHE", os.path.join(os.path.dirname(cache), ".fusion_mirror_cache")
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # noqa: BLE001 — cache is an optimization only
-        note(f"compilation cache unavailable: {e}")
-
-
 from stl_fusion_tpu.client import compute_client, install_compute_call_type  # noqa: E402
 from stl_fusion_tpu.core import (  # noqa: E402
     ComputeService,
@@ -224,8 +208,8 @@ async def run_mode(
         observer.arm(total_subs)
         t0 = time.perf_counter()
         # clients subscribe CONCURRENTLY (each client's keys in order):
-        # per-subscription cost is dominated by dispatch latency through
-        # the relay, which overlaps across clients
+        # per-subscription cost is dominated by dispatch latency, which
+        # overlaps across clients
         await asyncio.gather(*(c.subscribe(observer) for c in clients))
         sub_s = time.perf_counter() - t0
         await settle()
@@ -332,7 +316,10 @@ async def run_lone_ab(backend, block, server_rpc, client, samples, fanout_index)
 
 
 async def main() -> None:
-    _setup_jax_cache()
+    from stl_fusion_tpu.graph import enable_program_cache, require_accelerator
+
+    device = require_accelerator("perf/fanout_path.py")
+    enable_program_cache()
     n = int(os.environ.get("FANOUT_NODES", 10_000_000))
     n_clients = int(os.environ.get("FANOUT_CLIENTS", 100))
     # (re-subscription storms are affordable now that flush() coalesces
@@ -435,6 +422,7 @@ async def main() -> None:
                 speedup = round(a / b, 2)
         result = {
             "metric": "fanout_path",
+            **device,
             "nodes": n,
             "edges": int(backend.edge_count),
             "clients": n_clients,

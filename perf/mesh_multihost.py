@@ -128,7 +128,7 @@ async def _dcn_leg(ctx, mh_dir: str, result: dict) -> None:
     """The real-DCN marker (ISSUE 15 satellite): host 0 serves a live
     mini-hub whose fan-out scope marks host 1's member OFF-mesh; host 1
     subscribes over a real TCP socket and must observe the fence. The
-    relay therefore crosses an actual process boundary and
+    DCN relay therefore crosses an actual process boundary and
     ``fusion_mesh_dcn_fallback_total`` is exercised, not merely counted."""
     import asyncio
 
@@ -1594,7 +1594,13 @@ def main() -> None:
         if os.environ.get("MESH_MH_PHASE") == "elastic":
             sys.exit(run_elastic_worker())
         sys.exit(run_worker())
-    out: dict = {"violations": []}
+    # the hosts this harness spawns are pinned to the CPU backend
+    # (cluster/multihost.py::host_env); the parent only orchestrates
+    from stl_fusion_tpu.graph import require_accelerator
+
+    out: dict = {
+        **require_accelerator("perf/mesh_multihost.py"), "violations": [],
+    }
     run_multihost(out)
     ok = not out["violations"]
     out["ok"] = ok

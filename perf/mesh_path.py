@@ -16,8 +16,8 @@ Two legs, one JSON line on stdout (full record on stderr):
    TpuGraphBackend with ``enable_mesh_routing``: the nonblocking
    WavePipeline dispatches fused chains THROUGH the routed mesh path,
    a mid-burst reshard (kill one member) MOVES device shards with
-   zero oracle-divergent reads, and the fan-out relay scope proves the
-   frontier never re-entered through per-key host RPC. Chain-difference
+   zero oracle-divergent reads, and the fan-out's member-relay scope
+   proves the frontier never re-entered through per-key host RPC. Chain-difference
    sampling yields the wave_chain p50/p99 for intra-host shards.
 
 GATES (exit 1 — the tier1 mesh smoke rides them):
@@ -107,6 +107,21 @@ def compact_trace(stitched) -> dict:
     }
 
 
+def check_shard_devices(graph, mesh, out: dict, leg: str) -> list:
+    """Every resident array of a routed graph must have block ``d`` on
+    ``mesh.devices.flat[d]``, the device DevicePlacement assigns it: all
+    of it on device 0 would still give right answers, so it is a gate."""
+    want = [d.id for d in mesh.devices.flat]
+    layout = graph.device_layout()
+    log(f"{leg}: mesh devices {want}; array -> shard devices {layout}")
+    wrong = sorted(name for name, got in layout.items() if got != want)
+    if wrong:
+        out["violations"].append(
+            f"{leg}: arrays not laid out over the mesh devices: {wrong}"
+        )
+    return want
+
+
 def run_static(mesh, out: dict) -> None:
     from stl_fusion_tpu.cluster import DevicePlacement, ShardMap
     from stl_fusion_tpu.graph.synthetic import power_law_dag
@@ -130,6 +145,7 @@ def run_static(mesh, out: dict) -> None:
     build_s = time.time() - t0
     log(f"static: routed shards built in {build_s:.1f}s "
         f"(e_cap {graph.e_cap}, bucket_cap {graph.bucket_cap})")
+    shard_devices = check_shard_devices(graph, mesh, out, "static")
 
     rng = np.random.default_rng(123)
     seed_sets = [
@@ -169,6 +185,7 @@ def run_static(mesh, out: dict) -> None:
         "nodes": n,
         "edges": int(len(src)),
         "mesh_devices": int(mesh.devices.size),
+        "shard_devices": shard_devices,
         "members": n_members,
         "shards": n_shards,
         "exchange": exchange,
@@ -456,8 +473,10 @@ async def run_live(mesh, out: dict) -> None:
 
         # --- mid-burst reshard: kill m{last} -> device shards MOVE
         new_map = smap.with_members(members[:-1])
-        pre = backend._routed_mirror["graph"].shard_moves
         moves = backend.apply_mesh_reshard(new_map)
+        shard_devices = check_shard_devices(
+            backend.routed_mirror()["graph"], mesh, out, "live (after reshard)"
+        )
         post_groups = [rng.choice(ns, size=3, replace=False).tolist() for _ in range(3)]
         tickets = [pipe.submit_rows(blk, g) for g in post_groups]
         pipe.drain()
@@ -523,6 +542,7 @@ async def run_live(mesh, out: dict) -> None:
             "wave_chain_rejects": rejects,
             "reshard_moves": int(moves),
             "reshard_epoch": new_map.epoch,
+            "shard_devices": shard_devices,
             "oracle_divergence": divergence,
             "external_client_fences": fanout.drained_total,
             "mesh_member_relays": fanout.mesh_member_relays,
@@ -543,21 +563,19 @@ def main() -> None:
     # measure a 1-device "mesh"
     import asyncio
 
-    import jax
-
-    if "cpu" in os.environ.get("JAX_PLATFORMS", "") and jax.config.jax_platforms != "cpu":
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
+    from stl_fusion_tpu.graph import enable_program_cache, require_accelerator
     from stl_fusion_tpu.parallel import graph_mesh
 
-    n_dev = len(jax.devices())
+    device = require_accelerator("perf/mesh_path.py")
+    enable_program_cache()
+    n_dev = device["device_count"]
     if n_dev < 2:
-        print(json.dumps({"error": f"mesh path needs >1 device, have {n_dev}"}))
+        print(json.dumps(
+            {**device, "error": f"mesh path needs >1 device, have {n_dev}"}
+        ))
         sys.exit(2)
     mesh = graph_mesh()
-    out: dict = {"mesh_devices": n_dev, "violations": []}
+    out: dict = {**device, "mesh_devices": n_dev, "violations": []}
     if os.environ.get("MESH_SKIP_STATIC", "0") != "1":
         run_static(mesh, out)
     if os.environ.get("MESH_ASYNC", "0") == "1":

@@ -425,8 +425,11 @@ async def main() -> int:
         await peer_rpc.stop()
         await telem_server.stop()
 
+        from stl_fusion_tpu.graph import require_accelerator
+
         print(json.dumps({
             "metric": "telemetry_smoke",
+            **require_accelerator("perf/telemetry_smoke.py"),
             "ok": True,
             "subscriptions": len(nodes),
             "delivery_count": int(delivery_count),

@@ -77,23 +77,6 @@ def note(msg: str) -> None:
     print(f"# {msg}", file=sys.stderr, flush=True)
 
 
-def _setup_jax_cache() -> None:
-    import jax
-
-    cache = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
-    )
-    os.environ.setdefault(
-        "FUSION_MIRROR_CACHE",
-        os.path.join(os.path.dirname(cache), ".fusion_mirror_cache"),
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # noqa: BLE001 — cache is an optimization only
-        note(f"compilation cache unavailable: {e}")
-
-
 from stl_fusion_tpu.client import install_compute_call_type  # noqa: E402
 from stl_fusion_tpu.cluster import ShardMap, ShardMapRouter  # noqa: E402
 from stl_fusion_tpu.core import (  # noqa: E402
@@ -335,7 +318,10 @@ class Edge:
 
 
 async def main() -> None:
-    _setup_jax_cache()
+    from stl_fusion_tpu.graph import enable_program_cache, require_accelerator
+
+    device = require_accelerator("perf/traffic_path.py")
+    enable_program_cache()
     smoke = os.environ.get("TRAFFIC_SMOKE", "0") == "1"
 
     def env_int(name, full, small):
@@ -563,7 +549,7 @@ async def main() -> None:
             }
 
         upstream_total = sum(len(e.node._subs) for e in edges)
-        results: dict = {"metric": "traffic_path", "smoke": smoke,
+        results: dict = {"metric": "traffic_path", **device, "smoke": smoke,
                          "graph_nodes": n, "edge_nodes": n_edges,
                          "distinct_keys": n_keys, "base_sessions": n_sessions,
                          "workers": n_workers}

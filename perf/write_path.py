@@ -63,23 +63,6 @@ def note(msg: str) -> None:
     print(f"# {msg}", file=sys.stderr, flush=True)
 
 
-def _setup_jax_cache() -> None:
-    import jax
-
-    cache = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
-    )
-    os.environ.setdefault(
-        "FUSION_MIRROR_CACHE",
-        os.path.join(os.path.dirname(cache), ".fusion_mirror_cache"),
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # noqa: BLE001 — cache is an optimization only
-        note(f"compilation cache unavailable: {e}")
-
-
 from stl_fusion_tpu.client import install_compute_call_type  # noqa: E402
 from stl_fusion_tpu.cluster import (  # noqa: E402
     ClusterMember,
@@ -363,7 +346,10 @@ class WriteCluster:
 
 
 async def main() -> None:
-    _setup_jax_cache()
+    from stl_fusion_tpu.graph import enable_program_cache, require_accelerator
+
+    device = require_accelerator("perf/write_path.py")
+    enable_program_cache()
     smoke = os.environ.get("WRITE_SMOKE", "0") == "1"
 
     def env_int(name, full, small):
@@ -561,7 +547,7 @@ async def main() -> None:
         eager0 = pipe.stats()["eager_waves"]
         errors0 = errors_c.value
 
-        results: dict = {"metric": "write_path", "smoke": smoke,
+        results: dict = {"metric": "write_path", **device, "smoke": smoke,
                          "carts": n_carts, "writers": n_writers,
                          "members": n_members, "sessions": n_sessions}
 

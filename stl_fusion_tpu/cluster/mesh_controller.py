@@ -4,7 +4,7 @@ PR 15 proved the honest 2-host mesh and measured its production weakness:
 the stock ``jax.distributed`` world is all-or-nothing. Any task death
 propagates a fatal coordination-service error that ABORTS every survivor
 (measured rc=-6 inside ``PollForError``, no Python frame on the stack),
-so a host kill forced a full survivor restart — 71.8 s in MULTICHIP_r07.
+so a host kill forced a full survivor restart (71.8 s on the emulated mesh).
 This module is the replacement failure-domain owner:
 
 - **Evidence convergence.** A peer is declared dead only when independent

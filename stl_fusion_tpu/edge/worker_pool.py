@@ -955,7 +955,7 @@ class EdgeWorkerPool:
                 )
             key_strs = self.node.acquire_keys(specs)
         except Exception as e:  # noqa: BLE001 — the CLIENT's bad input
-            # counted on the SAME shed taxonomy as the SSE plane's 400s
+            # counted on the SAME shed classification as the SSE plane's 400s
             # (the worker answers the HTTP 400; the parent owns the count)
             self.node.count_shed("bad_request")
             w.send_json(b"A", {"conn": conn, "error": str(e)})

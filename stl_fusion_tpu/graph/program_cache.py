@@ -1,98 +1,82 @@
 """Persistent program cache — compiled lane/burst programs survive restarts.
 
-The cold-start budget's biggest line items are compiles, not data:
-BENCH_r05 recorded lane_program_warm 60.2 s and compile_s swinging 5-100 s
-run to run. XLA already ships a persistent compilation cache; this module
-is the ONE place the project configures it (bench.py, perf/live_path.py
-and any serving process call :func:`enable_program_cache` instead of
-hand-rolling ``jax.config`` calls), plus the restart-warmth telemetry:
-``stats()`` counts cached executables so the warm-rejoin path
-(cluster/rejoin.py, DURABILITY.md) can report whether a restart actually
-pre-warmed from disk or recompiled cold.
+The cold-start budget's biggest line items are compiles, not data. XLA
+already ships a persistent compilation cache; this module is the ONE place
+the project wires it (bench.py, chip_smoke.py, every perf/*.py script and
+any serving process call :func:`enable_program_cache`; nothing else
+touches ``jax_compilation_cache_dir``), plus the restart-warmth telemetry:
+:func:`program_cache_stats` counts cached executables so the warm-rejoin
+path (cluster/rejoin.py, DURABILITY.md) can report whether a restart
+actually pre-warmed from disk or recompiled cold.
 
-The same call also anchors ``FUSION_MIRROR_CACHE`` (the topo-mirror disk
-cache, device_graph.py) next to the program cache by default, so "warm
-workspace" means ONE directory pair an operator can ship to a new box.
+Where the cache lives is decided from OUTSIDE the program: when
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading of it stands and
+this module sets no directory; when it is not, the directory is
+``<checkout>/.jax_cache``, a FIXED path (the path is part of the cache
+key, so a directory that moves never hits). The topo-mirror disk cache
+(device_graph.py) sits beside it at ``<checkout>/.fusion_mirror_cache``
+unless ``FUSION_MIRROR_CACHE`` names another directory.
 """
 from __future__ import annotations
 
-import logging
 import os
-from typing import Optional
-
-log = logging.getLogger("stl_fusion_tpu")
 
 __all__ = [
     "enable_program_cache",
+    "program_cache_dir",
     "program_cache_stats",
     "time_program_warm",
     "program_warm_report",
     "reset_program_warms",
 ]
 
-#: env override for the cache root (matches FUSION_MIRROR_CACHE's shape)
-CACHE_ENV = "FUSION_PROGRAM_CACHE"
+#: JAX's own variable; when set, this module leaves the directory alone
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+MIRROR_CACHE_ENV = "FUSION_MIRROR_CACHE"
+
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
-def _default_root() -> str:
-    return os.environ.get(
-        CACHE_ENV,
-        os.path.join(os.path.expanduser("~"), ".cache", "stl_fusion_tpu"),
-    )
+def enable_program_cache() -> dict:
+    """Turn on XLA's persistent compilation cache and the topo-mirror disk
+    cache (module docstring: where each lives). Idempotent. Raises when
+    the cache directory cannot be created — a run that silently compiles
+    cold is a different run, so callers hear about it. Returns
+    ``{jax_cache_dir, mirror_cache_dir, from_env}``."""
+    import jax
 
-
-def enable_program_cache(
-    root: Optional[str] = None,
-    *,
-    jax_dir: Optional[str] = None,
-    mirror_dir: Optional[str] = None,
-    min_compile_seconds: float = 1.0,
-    mirror_cache: bool = True,
-) -> dict:
-    """Point XLA's persistent compilation cache at ``<root>/jax`` (and,
-    by default, the topo-mirror disk cache at ``<root>/mirror`` unless
-    FUSION_MIRROR_CACHE is already set). ``jax_dir``/``mirror_dir``
-    override the exact directories (bench.py keeps its historic
-    repo-local ``.jax_cache``/``.fusion_mirror_cache`` so warm workspaces
-    stay warm). Idempotent; returns an info dict ``{root, jax_cache_dir,
-    mirror_cache_dir, enabled, error}`` — callers report it rather than
-    assuming the cache took (older jax builds and read-only filesystems
-    degrade to cold compiles, never to a crash)."""
-    root = root or _default_root()
-    jax_dir = jax_dir or os.path.join(root, "jax")
-    mirror_dir = mirror_dir or os.path.join(root, "mirror")
-    info = {
-        "root": root,
-        "jax_cache_dir": jax_dir,
-        "mirror_cache_dir": None,
-        "enabled": False,
-        "error": None,
-    }
-    if mirror_cache:
-        os.environ.setdefault("FUSION_MIRROR_CACHE", mirror_dir)
-        info["mirror_cache_dir"] = os.environ["FUSION_MIRROR_CACHE"]
-    try:
-        os.makedirs(jax_dir, exist_ok=True)
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", jax_dir)
+    from_env = bool(os.environ.get(JAX_CACHE_ENV))
+    if not from_env:
         jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", float(min_compile_seconds)
+            "jax_compilation_cache_dir", os.path.join(_CHECKOUT, ".jax_cache")
         )
-        info["enabled"] = True
-    except Exception as e:  # noqa: BLE001 — the cache is an optimization only
-        info["error"] = repr(e)
-        log.warning("program cache unavailable (%s); compiles stay cold", e)
-    try:
-        from ..diagnostics.metrics import global_metrics
+    jax_dir = program_cache_dir()
+    os.makedirs(jax_dir, exist_ok=True)
+    mirror_dir = os.environ.setdefault(
+        MIRROR_CACHE_ENV, os.path.join(_CHECKOUT, ".fusion_mirror_cache")
+    )
+    from ..diagnostics.metrics import global_metrics
 
-        global_metrics().gauge(
-            "fusion_program_cache_enabled",
-            help="1 when the persistent XLA compilation cache is active",
-        ).set(1 if info["enabled"] else 0)
-    except Exception:  # noqa: BLE001 — metrics must never block enabling
-        pass
-    return info
+    global_metrics().gauge(
+        "fusion_program_cache_enabled",
+        help="1 when the persistent XLA compilation cache is active",
+    ).set(1)
+    return {
+        "jax_cache_dir": jax_dir,
+        "mirror_cache_dir": mirror_dir,
+        "from_env": from_env,
+    }
+
+
+def program_cache_dir():
+    """The EFFECTIVE compilation-cache directory, as ``jax.config`` holds
+    it (from ``JAX_COMPILATION_CACHE_DIR`` or :func:`enable_program_cache`);
+    None while no cache is configured."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or None
 
 
 #: per-program warm records: name -> {"key", "warm_s", "cache_hit",
@@ -102,9 +86,9 @@ _PROGRAM_WARMS: dict = {}
 
 class time_program_warm:
     """Context manager timing ONE program family's warm-up, attributing it
-    to the persistent cache (ISSUE 14 cold-start satellite — BENCH_r05's
-    ``lane_program_warm_s`` was 60.22 s with no way to tell a cache-served
-    warm from a cold compile). ``key`` names what the program is keyed on
+    to the persistent cache (a lane-program warm of a minute used to be
+    recorded with no way to tell a cache-served warm from a cold compile).
+    ``key`` names what the program is keyed on
     — geometry, depth, exchange — so two runs with different keys never
     read as the same warm. ``cache_hit`` is judged from the persistent
     cache dir: a warm that added NO new executables (and the cache is
@@ -118,16 +102,15 @@ class time_program_warm:
             backend.cascade_rows_lanes(block, group_ids)
     """
 
-    def __init__(self, name: str, key=None, jax_dir: Optional[str] = None):
+    def __init__(self, name: str, key=None):
         self.name = name
         self.key = key
-        self.jax_dir = jax_dir
         self._t0 = 0.0
         self._entries0 = 0
 
     def _entries(self) -> int:
         try:
-            return program_cache_stats(self.jax_dir)["entries"]
+            return program_cache_stats()["entries"]
         except OSError:  # an unreadable cache dir reads as empty
             return 0
 
@@ -146,10 +129,8 @@ class time_program_warm:
         # with no cache dir on disk the entry delta proves nothing — a
         # cold 60 s compile must never be recorded as cache-served
         # (cache_hit=None = unattributable, the honest answer)
-        cache_present = os.path.isdir(
-            program_cache_stats(self.jax_dir)["dir"]
-            if self.jax_dir is None else self.jax_dir
-        )
+        jax_dir = program_cache_dir()
+        cache_present = jax_dir is not None and os.path.isdir(jax_dir)
         _PROGRAM_WARMS[self.name] = {
             "key": repr(self.key) if self.key is not None else None,
             "warm_s": round(dt, 3),
@@ -174,18 +155,14 @@ def reset_program_warms() -> None:
     _PROGRAM_WARMS.clear()
 
 
-def program_cache_stats(root: Optional[str] = None) -> dict:
-    """Count cached executables + bytes under the cache dir — the
-    restart-warmth signal (``entries > 0`` before first compile of a new
-    process means the restart pre-warms from disk)."""
-    root = root or _default_root()
-    # accept either a cache ROOT (<root>/jax holds the executables) or
-    # the exact jax cache dir (bench's repo-local .jax_cache layout)
-    sub = os.path.join(root, "jax")
-    jax_dir = sub if os.path.isdir(sub) else root
+def program_cache_stats() -> dict:
+    """Count cached executables + bytes under the effective cache dir —
+    the restart-warmth signal (``entries > 0`` before first compile of a
+    new process means the restart pre-warms from disk)."""
+    jax_dir = program_cache_dir()
     entries = 0
     size = 0
-    if os.path.isdir(jax_dir):
+    if jax_dir is not None and os.path.isdir(jax_dir):
         for dirpath, _dirnames, filenames in os.walk(jax_dir):
             for name in filenames:
                 entries += 1

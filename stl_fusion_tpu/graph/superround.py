@@ -3,9 +3,8 @@
 PR 7 fused wave chains and PR 9 moved cross-shard frontiers on-device, but
 the live loop still re-entered the host BETWEEN stages every round: seed
 prep, columnar refresh staging, memo-table apply, and fence extraction each
-cost a relay hop, and BENCH_r05 measured ``burst_s`` 24.8 of a 30.4 s loop
-against a 7.1 G inv/s static kernel — a ~40× live-vs-static gap whose
-remaining cost was the seams, not the kernels. This module is the
+cost a host round trip, leaving a live-vs-static gap whose remaining cost
+was the seams, not the kernels. This module is the
 FuseFlow-style answer (PAPERS.md: fusion across sparse-pipeline STAGE
 boundaries, not just within a stage; "Composing Distributed Computations
 Through Task and Kernel Fusion": the win is deleting the host round trips
@@ -465,10 +464,13 @@ class SuperRoundProgram:
                 ticket = self._dispatch_routed(staged, cause, seqs, t0)
             else:
                 ticket = self._dispatch_lanes(staged, cause, seqs, t0)
-        except (RuntimeError, ValueError):
+        except (RuntimeError, ValueError) as e:
             # not a fault: the mirror cannot serve the fused path right now
             # (invalid, multi-pass pileup, out-of-contract seeds) — the
-            # counted eager fallback, same policy as the WavePipeline
+            # counted eager fallback, same policy as the WavePipeline.
+            # The reason is logged: a device runtime error is a
+            # RuntimeError too, and must not pass for a fusibility decline
+            log.warning("super-round: eager fallback (%r)", e)
             return self._eager_ticket(staged, cause, seqs, t0)
         except Exception as e:  # noqa: BLE001 — dispatch fault: contain + count
             return self._fault_ticket(e, staged, cause, seqs)
@@ -484,6 +486,12 @@ class SuperRoundProgram:
 
         backend = self.backend
         dg = backend.graph
+        if dg._topo_mirror is not None:
+            # bring the mirror up to date BEFORE judging the staged buffer:
+            # a delta the patcher cannot absorb rebuilds (re-levels) the
+            # mirror right here, and a buffer packed against the old order
+            # would otherwise be enqueued as seeds at the wrong nodes
+            dg.build_topo_mirror()
         if staged.mats is None or staged.mirror_rebuilds != dg.mirror_rebuilds:
             # the buffer was packed against a mirror that has since
             # re-leveled (new inv_perm — the staged NEW-ids are garbage in

@@ -1,46 +1,29 @@
-"""Hand-written Pallas TPU kernels for the invalidation hot path.
+"""The hand-written Pallas TPU kernel of the invalidation hot path.
 
-Two kernels live here (the rest of the wave pipeline deliberately stays in
-XLA — its gathers/scatters are already near-optimal and fuse well):
+The rest of the wave pipeline deliberately stays in XLA — its
+gathers/scatters fuse well.
 
-- :func:`or_popcount` — the wave FINALIZER: merge a new invalidation bit
-  vector into the accumulated one and count newly-lit bits, in ONE pass
-  over the words (XLA materializes ``new & ~old`` as an intermediate before
-  the reduce unless it fuses; here merge + delta-popcount + scalar
-  accumulation share a single VMEM-resident tile walk).
-- :func:`make_ring_all_gather` — the per-level frontier exchange as an
-  explicit ICI ring: each device forwards its bit-packed frontier words
-  around a logical ring with double-buffered RDMA
-  (``pltpu.make_async_remote_copy``), the guide's ring-collective pattern.
-  This is the kernel form of SURVEY §5.8's "intra-pod invalidation fan-out
-  = ICI all-gather of per-host frontier buffers"; ``lax.all_gather`` stays
-  the default (XLA's collective scheduler overlaps it fine), the ring
-  kernel is for meshes where the frontier exchange needs manual overlap
-  control.
-
-Both kernels auto-fall back to interpreter mode off-TPU so the CPU-mesh
-test suite exercises their logic; on-chip they compile via Mosaic.
+:func:`or_popcount` is the wave FINALIZER: merge a new invalidation bit
+vector into the accumulated one and count newly-lit bits, in ONE pass over
+the words (XLA materializes ``new & ~old`` as an intermediate before the
+reduce unless it fuses; here merge + delta-popcount + scalar accumulation
+share a single VMEM-resident tile walk). It compiles through Mosaic by
+default; a CPU test asks for the interpreter itself (``interpret=True``).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["or_popcount", "make_ring_all_gather"]
+__all__ = ["or_popcount"]
 
 _LANES = 128
 _BLOCK_ROWS = 256  # 256x128 int32 = 128 KiB per buffer — 3 buffers well under VMEM
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------- or+popcount
@@ -82,14 +65,13 @@ def _or_popcount_2d(new2d, old2d, interpret: bool):
     return merged, count[0, 0]
 
 
-def or_popcount(new_bits, old_bits, interpret: Optional[bool] = None):
+def or_popcount(new_bits, old_bits, interpret: bool = False):
     """``(old | new, popcount(new & ~old))`` over int32 bit-vector words.
 
     1-D int32 inputs of equal length; zero-pads internally to the kernel
     tile. Returns (merged 1-D array, newly-lit bit count as 0-d int32).
+    Compiled by Mosaic unless the caller asks for ``interpret=True``.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     n = new_bits.shape[0]
     tile = _BLOCK_ROWS * _LANES
     n_pad = (n + tile - 1) // tile * tile
@@ -97,95 +79,3 @@ def or_popcount(new_bits, old_bits, interpret: Optional[bool] = None):
     old2d = jnp.zeros(n_pad, jnp.int32).at[:n].set(old_bits).reshape(-1, _LANES)
     merged, count = _or_popcount_2d(new2d, old2d, interpret)
     return merged.reshape(-1)[:n], count
-
-
-# ---------------------------------------------------------------- ring gather
-def _ring_kernel(local_ref, out_ref, comm_ref, send_sem, recv_sem, *, axis: str):
-    n_dev = lax.axis_size(axis)
-    my_id = lax.axis_index(axis)
-    chunk = local_ref.shape[0]
-
-    # slot my own chunk into the gathered output
-    out_ref[pl.ds(my_id * chunk, chunk), :] = local_ref[...]
-    comm_ref[0] = local_ref[...]
-
-    def step_body(step, _):
-        send_slot = step % 2
-        recv_slot = 1 - send_slot
-        dst = lax.rem(my_id + 1, n_dev)
-        src_owner = lax.rem(my_id - step - 1 + 2 * n_dev, n_dev)
-
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_ref.at[send_slot],
-            dst_ref=comm_ref.at[recv_slot],
-            send_sem=send_sem.at[send_slot],
-            recv_sem=recv_sem.at[recv_slot],
-            device_id=dst,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.start()
-        rdma.wait()
-        out_ref[pl.ds(src_owner * chunk, chunk), :] = comm_ref[recv_slot]
-        return 0
-
-    lax.fori_loop(0, n_dev - 1, step_body, 0)
-
-
-def ring_all_gather_supported() -> bool:
-    """The ring kernel leans on newer-jax APIs (``lax.axis_size``, varying
-    manual-axes ShapeDtypeStructs); older jax runs every other exchange
-    but must DECLINE this one loudly instead of failing mid-trace."""
-    import inspect
-
-    import jax as _jax
-
-    try:
-        return hasattr(lax, "axis_size") and "vma" in inspect.signature(
-            _jax.ShapeDtypeStruct
-        ).parameters
-    except (TypeError, ValueError):
-        return False
-
-
-def make_ring_all_gather(axis: str, interpret: Optional[bool] = None):
-    """A shard_map-inner ``all_gather(..., tiled=True)`` replacement.
-
-    Returns ``ring(local_words)`` for use INSIDE ``shard_map``: takes this
-    device's uint32 frontier words ``(chunk,)`` and returns the full
-    ``(n_dev * chunk,)`` gathered vector, moved hop-by-hop over the ICI
-    ring with double-buffered RDMA. ``chunk`` must be a multiple of 128.
-    """
-    if not ring_all_gather_supported():
-        raise NotImplementedError(
-            "the Pallas ring all-gather needs lax.axis_size + vma-aware "
-            "ShapeDtypeStruct (newer jax); use exchange='packed'/'bool'"
-        )
-    if interpret is None:
-        interpret = not _on_tpu()
-
-    def ring(local_words):
-        chunk = local_words.shape[0]
-        assert chunk % _LANES == 0, "ring chunk must be a multiple of 128 lanes"
-        rows = chunk // _LANES
-        local2d = local_words.reshape(rows, _LANES).astype(jnp.uint32)
-        n_dev_static = lax.axis_size(axis)  # static for a bound mesh axis
-        out = pl.pallas_call(
-            functools.partial(_ring_kernel, axis=axis),
-            out_shape=jax.ShapeDtypeStruct(
-                (n_dev_static * rows, _LANES), jnp.uint32, vma=frozenset({axis})
-            ),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[
-                pltpu.VMEM((2, rows, _LANES), jnp.uint32),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                has_side_effects=True, collective_id=7
-            ),
-            interpret=interpret,
-        )(local2d)
-        return out.reshape(-1)
-
-    return ring

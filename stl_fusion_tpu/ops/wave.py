@@ -160,7 +160,7 @@ def run_waves_chained(
 
     Each wave cascades over the state the previous one left (the live
     burst shape: many commands completing back-to-back get ONE dispatch +
-    ONE readback instead of W relay round trips). Returns
+    ONE readback instead of W host round trips). Returns
     (g, per-wave newly-invalidated counts int32[W], union newly mask).
     """
     inv_before = g.invalid

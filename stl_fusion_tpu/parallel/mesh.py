@@ -8,38 +8,25 @@ processes over WebSockets, the dependency graph itself is sharded over a
 """
 from __future__ import annotations
 
-import inspect
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax ≥ 0.6 exports it top-level with the check_vma kwarg
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax: experimental home, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 __all__ = ["graph_mesh", "shard_map_compat", "P", "Mesh", "NamedSharding"]
 
 GRAPH_AXIS = "graph"
 
-#: which replication-check kwarg THIS jax's shard_map takes (the flag was
-#: renamed check_rep → check_vma across releases; pallas interpret-mode
-#: lowering can't track either, so callers disable it by whatever name)
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
 
 def shard_map_compat(mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` decorator across the jax versions this repo meets
-    (top-level vs experimental module, check_vma vs check_rep)."""
+    """``jax.shard_map`` as a decorator with the varying-manual-axes check
+    off by default (the wave bodies mix replicated and sharded carries in
+    ways the checker cannot follow)."""
     def deco(f):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{_CHECK_KW: check}
+        return shard_map(
+            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
         )
 
     return deco

@@ -1387,6 +1387,25 @@ class RoutedShardedGraph:
         return counts, stage_ids, info
 
     # ------------------------------------------------------------------ state
+    def device_layout(self) -> dict:
+        """Where each resident array's shards actually live: ``{array name:
+        [device id of block 0, block 1, ...]}`` over this process's
+        addressable shards, in block order. Block ``d`` belongs on
+        ``mesh.devices.flat[d]`` (the device ``DevicePlacement`` assigns
+        shard group ``d``): a bring-up check that nothing collapsed onto
+        device 0."""
+        layout = {}
+        for name in (
+            "g_node_epoch", "g_invalid", "g_is_real", "g_send", "g_hsend",
+            "g_eprod", "g_ebslot", "g_ebit", "g_edst", "g_elsrc", "g_eep",
+        ):
+            shards = sorted(
+                getattr(self, name).addressable_shards,
+                key=lambda sh: sh.index[0].start or 0,
+            )
+            layout[name] = [sh.device.id for sh in shards]
+        return layout
+
     def invalid_mask(self) -> np.ndarray:
         """bool[n_nodes] in NODE space (reads the device state once)."""
         arr = self._fetch(self.g_invalid)

@@ -59,24 +59,16 @@ def build_sharded_wave(mesh: Mesh, n_global: int, exchange: str = "packed"):
       before the all-gather — 8x fewer bytes over ICI than gathering the
       bool lane (XLA bools travel as one byte each); sources then test
       ``word >> (id & 31)`` instead of gathering bools.
-    - ``"ring"``: packed words move through the hand-written Pallas ICI
-      ring-RDMA kernel (ops/pallas_kernels.make_ring_all_gather) instead of
-      ``lax.all_gather`` — explicit hop-by-hop overlap control.
     - ``"bool"``: the plain boolean all-gather (reference for equivalence
       tests and as a fallback).
     """
     n_dev = mesh.devices.size
     n_local = n_global // n_dev
     assert n_global % n_dev == 0, "node capacity must divide evenly over the mesh"
-    if exchange not in ("packed", "bool", "ring"):
+    if exchange not in ("packed", "bool"):
         raise ValueError(f"unknown exchange {exchange!r}")
-    if exchange in ("packed", "ring"):
-        assert n_local % 32 == 0, "packed/ring exchange needs n_local % 32 == 0"
-    ring = None
-    if exchange == "ring":
-        from ..ops.pallas_kernels import make_ring_all_gather
-
-        ring = make_ring_all_gather(GRAPH_AXIS)
+    if exchange == "packed":
+        assert n_local % 32 == 0, "packed exchange needs n_local % 32 == 0"
 
     node_spec = P(GRAPH_AXIS)
     edge_spec = P(GRAPH_AXIS)
@@ -92,21 +84,9 @@ def build_sharded_wave(mesh: Mesh, n_global: int, exchange: str = "packed"):
         if exchange == "bool":
             f_full = lax.all_gather(f_l, GRAPH_AXIS, tiled=True)
             return f_full[esrc_l]
-        if exchange == "packed":
-            f_full_w = lax.all_gather(_pack_words(f_l), GRAPH_AXIS, tiled=True)
-            word = f_full_w[esrc_l >> 5]
-            return ((word >> (esrc_l & 31).astype(jnp.uint32)) & 1).astype(bool)
-        # ring: pad this device's words to the kernel's 128-lane tile; the
-        # gathered vector is then BLOCK-padded per device, so the word index
-        # for global id g is owner(g)*padded + (g within owner)/32
-        w = n_local // 32
-        wp = (w + 127) // 128 * 128
-        words = jnp.zeros(wp, jnp.uint32).at[:w].set(_pack_words(f_l))
-        full = ring(words)  # (n_dev * wp,)
-        dev = esrc_l // n_local
-        within = esrc_l - dev * n_local
-        word = full[dev * wp + (within >> 5)]
-        return ((word >> (within & 31).astype(jnp.uint32)) & 1).astype(bool)
+        f_full_w = lax.all_gather(_pack_words(f_l), GRAPH_AXIS, tiled=True)
+        word = f_full_w[esrc_l >> 5]
+        return ((word >> (esrc_l & 31).astype(jnp.uint32)) & 1).astype(bool)
 
     @shard_map_compat(
         mesh=mesh,
@@ -151,7 +131,7 @@ def build_sharded_wave(mesh: Mesh, n_global: int, exchange: str = "packed"):
     def wave_chain(seed_mat: jax.Array, g: ShardedGraphArrays, reset_between: bool):
         """W waves in ONE compiled program with a single readback — the
         multi-chip analogue of the single-chip bench's lax.scan batching
-        (per-wave host dispatch pays a relay/dispatch round trip each; the
+        (per-wave host dispatch pays a host round trip each; the
         chain pays it once). ``reset_between`` clears ``invalid`` before
         each wave (the bench's churn model: the graph is re-consistent
         between waves)."""

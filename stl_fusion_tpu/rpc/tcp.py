@@ -139,6 +139,10 @@ class RpcTcpServer:
         self.port = port
         self.ref_prefix = ref_prefix
         self._server: Optional[asyncio.base_events.Server] = None
+        #: accepted stream writers still open: ``stop()`` closes them, since
+        #: ``Server.wait_closed()`` (Python >= 3.12) waits for every
+        #: connection and ``_handle`` holds each one open until its socket dies
+        self._writers: set[asyncio.StreamWriter] = set()
         #: dials that died before a valid hello (probes, port scans) and
         #: handler teardown races — operator stats, never silent exits
         self.hello_failures = 0
@@ -157,13 +161,22 @@ class RpcTcpServer:
     async def _handle(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
+        self._writers.add(writer)
+        try:
+            await self._serve(reader, writer)
+        finally:
+            self._writers.discard(writer)
+
+    async def _serve(
+        self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
+    ) -> None:
         try:
             hello = await asyncio.wait_for(
                 reader.readline(), timeout=10.0
             )
         except Exception:  # noqa: BLE001 — probe/dead dial before hello: a
             # normal exit, not an RPC failure (the PR 12 health-probe
-            # taxonomy lesson), but still visible in the server stats
+            # classification lesson), but still visible in the server stats
             self.hello_failures += 1
             writer.close()
             return
@@ -186,6 +199,8 @@ class RpcTcpServer:
     async def stop(self) -> None:
         if self._server is not None:
             self._server.close()
+            for writer in list(self._writers):
+                writer.close()
             await self._server.wait_closed()
             self._server = None
 

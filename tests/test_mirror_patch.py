@@ -383,14 +383,27 @@ def test_mirror_disk_cache_roundtrip(tmp_path, monkeypatch):
 
     g1 = fresh()
     g1.build_topo_mirror()
+    def landed():
+        # the writer's in-progress file is <entry>.tmp.npz: not an entry yet
+        return [p for p in tmp_path.glob("*.npz") if ".tmp" not in p.name]
+
     deadline = time.time() + 20
-    while not list(tmp_path.glob("*.npz")) and time.time() < deadline:
+    while not landed() and time.time() < deadline:
         time.sleep(0.1)
-    assert list(tmp_path.glob("*.npz")), "background cache save did not land"
+    assert landed(), "background cache save did not land"
 
     g2 = fresh()
     g2.build_topo_mirror()
     assert g2._topo_mirror["lat"] is not None
+    assert g2.mirror_cache_hits == 1
+    # an entry laid out by other builder code (changed source hash) misses
+    from stl_fusion_tpu.graph import device_graph
+
+    with monkeypatch.context() as mp:
+        mp.setattr(device_graph, "_mirror_builder_hash", lambda: "0" * 16)
+        g3 = fresh()
+        g3.build_topo_mirror()
+    assert g3.mirror_cache_hits == 0 and g3.mirror_cache_misses == 1
     c1, _ = g1.run_waves_union([[10]])
     c2, _ = g2.run_waves_union([[10]])
     assert c1 == c2 == n - 10

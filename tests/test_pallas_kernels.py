@@ -1,21 +1,13 @@
-"""Pallas kernel tests (interpreter mode on the CPU mesh; the same kernels
-compile via Mosaic on-chip): the or+popcount wave finalizer and the ICI
-ring all-gather frontier exchange."""
-import functools
-
+"""Pallas kernel tests: the or+popcount wave finalizer, run in interpreter
+mode because THIS suite runs on the CPU (``interpret=True`` said here, not
+guessed by the kernel); chip_smoke.py compiles the same kernel through
+Mosaic on the chip and checks it against numpy there."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from stl_fusion_tpu.ops.pallas_kernels import (
-    make_ring_all_gather,
-    or_popcount,
-    ring_all_gather_supported,
-)
-from stl_fusion_tpu.parallel.mesh import shard_map_compat
+from stl_fusion_tpu.ops.pallas_kernels import or_popcount
 
 
 @pytest.mark.parametrize("n", [7, 128, 32768, 40000])
@@ -23,7 +15,7 @@ def test_or_popcount_matches_numpy(n):
     rng = np.random.default_rng(n)
     new = rng.integers(-(2**31), 2**31, size=n, dtype=np.int32)
     old = rng.integers(-(2**31), 2**31, size=n, dtype=np.int32)
-    merged, count = or_popcount(jnp.asarray(new), jnp.asarray(old))
+    merged, count = or_popcount(jnp.asarray(new), jnp.asarray(old), interpret=True)
     np.testing.assert_array_equal(np.asarray(merged), new | old)
     expect = int(np.bitwise_count((new & ~old).astype(np.uint32)).sum())
     assert int(count) == expect
@@ -31,36 +23,6 @@ def test_or_popcount_matches_numpy(n):
 
 def test_or_popcount_zero_delta():
     x = jnp.asarray(np.full(1000, 0x0F0F0F0F, dtype=np.int32))
-    merged, count = or_popcount(x, x)
+    merged, count = or_popcount(x, x, interpret=True)
     assert int(count) == 0
     np.testing.assert_array_equal(np.asarray(merged), np.asarray(x))
-
-
-def test_ring_all_gather_matches_lax():
-    devices = jax.devices()
-    if len(devices) < 2:
-        pytest.skip("needs a multi-device mesh")
-    if not ring_all_gather_supported():
-        pytest.skip("jax on this image lacks the ring kernel's APIs")
-    mesh = Mesh(np.array(devices), ("graph",))
-    n_dev = len(devices)
-    chunk = 256
-    rng = np.random.default_rng(3)
-    words = rng.integers(0, 2**32, size=n_dev * chunk, dtype=np.uint32)
-    sharded = jax.device_put(
-        jnp.asarray(words), NamedSharding(mesh, P("graph"))
-    )
-
-    ring = make_ring_all_gather("graph")
-
-    @shard_map_compat(mesh=mesh, in_specs=P("graph"), out_specs=P("graph"))
-    def gather_ring(w_local):
-        full = ring(w_local)
-        # every device returns its view; slice back to local block so the
-        # stacked result reconstructs n_dev copies for comparison
-        return full.reshape(n_dev, -1)
-
-    # out_specs concatenates each device's (n_dev, chunk) view along axis 0
-    got = np.asarray(gather_ring(sharded)).reshape(n_dev, n_dev * chunk)
-    for d in range(n_dev):
-        np.testing.assert_array_equal(got[d], words, err_msg=f"device {d}")

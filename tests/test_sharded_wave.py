@@ -79,22 +79,6 @@ def test_packed_exchange_matches_bool(seed):
         np.testing.assert_array_equal(packed.invalid_mask(), plain.invalid_mask())
 
 
-def test_ring_exchange_matches_bool():
-    from stl_fusion_tpu.ops.pallas_kernels import ring_all_gather_supported
-
-    if not ring_all_gather_supported():
-        pytest.skip("jax on this image lacks the ring kernel's APIs")
-    rng = np.random.default_rng(5)
-    n = 500
-    edges = random_dag(rng, n, avg_deg=3.0)
-    arr = np.asarray(edges, dtype=np.int32)
-    ring = ShardedDeviceGraph(arr[:, 0], arr[:, 1], n, exchange="ring")
-    plain = ShardedDeviceGraph(arr[:, 0], arr[:, 1], n, exchange="bool")
-    seeds = rng.choice(n, size=6, replace=False).tolist()
-    assert ring.run_wave(seeds) == plain.run_wave(seeds)
-    np.testing.assert_array_equal(ring.invalid_mask(), plain.invalid_mask())
-
-
 def test_chained_waves_match_per_wave_runs():
     """run_waves_chained == W separate run_wave calls with resets."""
     from stl_fusion_tpu.graph.synthetic import power_law_dag

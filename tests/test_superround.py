@@ -240,6 +240,33 @@ async def test_relevel_between_stage_and_dispatch_restages():
         set_default_hub(old)
 
 
+async def test_rebuild_inside_dispatch_restages():
+    """Churn the patcher cannot absorb (one row's in-degree overflowing its
+    mirror slots) rebuilds the mirror while dispatch() brings it up to
+    date: a buffer staged BEFORE that must be re-packed (counted), not
+    enqueued in the old id order as seeds at the wrong nodes."""
+    hub, b, svc, table, blk = make_stack()
+    old = set_default_hub(hub)
+    try:
+        prog = b.enable_super_rounds(blk, depth=1)
+        bursts = round_bursts(1)
+        staged = prog.stage(bursts)
+        srcs, dsts = np.arange(16), np.full(16, N - 1)
+        b.declare_row_edges(blk, srcs, blk, dsts)
+        rebuilds0 = b.graph.mirror_rebuilds
+        per_burst = prog.dispatch(staged).harvest()
+        assert b.graph.mirror_rebuilds == rebuilds0 + 1
+        assert prog.restages == 1 and prog.eager_rounds == 0
+
+        hub_b, b_b, _s2, table_b, blk_b = make_stack()
+        set_default_hub(hub_b)
+        b_b.declare_row_edges(blk_b, srcs, blk_b, dsts)
+        want = b_b.cascade_rows_lanes(blk_b, bursts[0])
+        assert per_burst[0].tolist() == want.tolist()
+    finally:
+        set_default_hub(old)
+
+
 # ---------------------------------------------------------------- faults
 
 
