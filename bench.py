@@ -253,16 +253,6 @@ def run_single_chip(n_nodes, avg_deg, seeds_per_wave, n_waves, rng):
         # stands).
         positive = np.sort(raw[raw > 0])
         rejects = int((raw <= 0).sum())
-        if rejects:
-            # the negative-timing belt, observable beyond this record
-            # (ISSUE 7 satellite): the same counter the live profiler
-            # exports, so /metrics shows rejects wherever they happen
-            from stl_fusion_tpu.diagnostics.metrics import global_metrics
-
-            global_metrics().counter(
-                "fusion_wave_timing_rejects_total",
-                help="negative per-wave timing samples rejected as measurement artifacts",
-            ).inc(rejects)
         # gate on the PRE-trim measurement count: the trim is an estimator
         # choice, not lost data
         if len(positive) < max(8, n_samples // 2):

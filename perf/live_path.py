@@ -346,11 +346,6 @@ async def main() -> None:
                 f"(t[{m_long} seq waves] - t[{m_short}]) / {m_long - m_short} "
                 f"via cascade_rows_batch_seq — per-dispatch cost cancels"
             )
-            if chain_rejects:
-                # the negative-timing belt is now observable system-side
-                # (ISSUE 7 satellite): rejects land in the metrics registry
-                # + FusionMonitor.report()["waves"], not just this record
-                backend.profiler.note_timing_rejects(chain_rejects, "wave_chain")
             if table.stale_count():
                 backend.refresh_block_on_device(block)
             backend.flush()

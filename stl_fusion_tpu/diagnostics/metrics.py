@@ -508,12 +508,18 @@ class WaveProfiler:
     """Per-wave timeline ring buffer for a TpuGraphBackend.
 
     One record per device wave dispatch (union / lanes / seq / collect /
-    icasc): seed count, newly-invalidated size, device milliseconds
-    (dispatch → readback), host-apply milliseconds (two-tier apply + hook
-    drain), the journal depth the preceding flush replayed (pre/post
-    coalescing) and its host cost, and the wave's cause id — the same id
-    the fan-out stamps into ``$sys-c`` frames, so a client-side delivery
-    sample joins back to its wave record.
+    icasc): seed count, newly-invalidated size, ``device_ms``, host-apply
+    milliseconds (two-tier apply + hook drain), the journal depth the
+    preceding flush replayed (pre/post coalescing) and its host cost, and
+    the wave's cause id — the same id the fan-out stamps into ``$sys-c``
+    frames, so a client-side delivery sample joins back to its wave record.
+
+    ``device_ms`` (and the ``fusion_wave_device_ms`` histogram it feeds)
+    is the HOST clock from the dispatch until the readback returned: device
+    time, transfer and host wait together, not device time. Device time
+    comes from a profiler trace (the benchmark's ``lat_device_ms_per_wave``
+    and ``sweep_device_s_per_round``); the hot-path spans
+    (``diagnostics/tracing.py``) split the host's share.
 
     Bounded and cheap: a deque of small dicts plus two registry histograms;
     ``enabled = False`` reduces every call to one attribute check (the
@@ -532,11 +538,9 @@ class WaveProfiler:
         self.newly_total = 0
         self._pending_flush: Optional[dict] = None
         #: fused-chain accounting (ISSUE 7): logical waves per physical
-        #: dispatch, and the bench-layer negative-timing rejects that were
-        #: previously counted only inside BENCH_*.json
+        #: dispatch
         self.fused_dispatches = 0
         self.fused_waves_total = 0
-        self.timing_rejects_total = 0
 
     # ------------------------------------------------------------------ feed
     def note_flush(self, journal_pre: int, journal_post: int, host_ms: float) -> None:
@@ -573,21 +577,6 @@ class WaveProfiler:
             help="logical waves per physical device dispatch (wave-chain fusion; depth>1 only)",
             unit="waves", lo=1.0, hi=4096.0,
         ).record(float(fused_depth))
-
-    def note_timing_rejects(self, n: int, source: str = "") -> None:
-        """Negative chain-difference samples rejected by the PR-6 timing
-        belt (bench.py / live_path.py) — previously bench-local counters;
-        exported here as ``fusion_wave_timing_rejects_total`` and surfaced
-        in ``FusionMonitor.report()["waves"]`` so the belt is observable
-        in production scrapes, not just BENCH_*.json."""
-        if n <= 0:
-            return
-        self.timing_rejects_total += int(n)
-        c = self.metrics.counter(
-            "fusion_wave_timing_rejects_total",
-            help="negative per-wave timing samples rejected as measurement artifacts",
-        )
-        c.inc(int(n))
 
     def record_wave(
         self,
@@ -641,7 +630,9 @@ class WaveProfiler:
         self.apply_ms_total += apply_ms
         self.newly_total += int(newly)
         self.metrics.histogram(
-            "fusion_wave_device_ms", help="device wave dispatch->readback latency"
+            "fusion_wave_device_ms",
+            help="host clock, wave dispatch until the readback returned "
+                 "(device time, transfer and host wait together)",
         ).record(device_ms, cause=cause)
         self.metrics.histogram(
             "fusion_wave_apply_ms", help="host two-tier wave application latency"
@@ -681,8 +672,6 @@ class WaveProfiler:
                 round(fused.percentile(99), 2)
                 if fused is not None and fused.count else None
             ),
-            # the PR-6 negative-timing belt, observable (ISSUE 7 satellite)
-            "timing_rejects": self.timing_rejects_total,
         }
 
     def report(self, recent: int = 32) -> dict:

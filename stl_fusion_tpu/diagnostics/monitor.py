@@ -358,9 +358,8 @@ class FusionMonitor:
         backend = getattr(self.hub, "graph_backend", None)
         profiler = getattr(backend, "profiler", None)
         if profiler is not None:
-            # includes fused_depth_p50/p99 + timing_rejects (ISSUE 7): the
-            # fused-path engagement and the negative-timing belt are part
-            # of the standard waves report, not bench-only fields
+            # includes fused_depth_p50/p99 (ISSUE 7): the fused-path
+            # engagement is part of the standard waves report
             extra["waves"] = profiler.report()
         # nonblocking wave pipeline (ISSUE 7): accumulator depth, fused
         # dispatch count, eager/fault fallbacks, overlap occupancy

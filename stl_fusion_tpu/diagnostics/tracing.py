@@ -9,6 +9,20 @@ assertions) subscribe via ``add_listener``.
 
 Spans nest via a contextvar, so a trace tree can be reconstructed from
 ``parent_id`` — the analogue of Activity.Current parenting.
+
+Two kinds of span share the id space and the parent chain. The always-on
+``Span`` above serves commands, the op-log reader and rejoin: it lands in the
+2,048-entry ring and notifies listeners. The *hot-path* span
+(:func:`hot_span`) serves the live loop (``graph/backend.py``,
+``graph/device_graph.py``, ``graph/superround.py``), where a wave takes a
+couple of milliseconds: it records only while a ``jax.profiler`` trace is
+being taken, or after :func:`enable_hot_spans`; otherwise a span site costs
+one predicate and gets the shared no-op. On, each hot span is also a
+``jax.profiler.TraceAnnotation`` named ``fusion:<name>``, so it shows on the
+host line of the profiler's trace, on the clock of the device planes (the
+outermost span that knows its wave's seq when it opens carries it as the
+event's ``wave`` stat), and its (name, start, end, parent, wave seq) goes to
+an in-memory record that :func:`hot_spans` hands to a reader.
 """
 from __future__ import annotations
 
@@ -19,7 +33,7 @@ import os
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Deque, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, NamedTuple, Optional
 
 log = logging.getLogger("stl_fusion_tpu.tracing")
 
@@ -34,6 +48,13 @@ __all__ = [
     "span_cause_id",
     "current_cause_id",
     "find_span_by_cause",
+    "HotSpanRecord",
+    "hot_span",
+    "hot_spans",
+    "hot_spans_on",
+    "enable_hot_spans",
+    "disable_hot_spans",
+    "clear_hot_spans",
 ]
 
 #: process-unique cause-id prefix (shared with graph/backend.py wave ids):
@@ -204,3 +225,151 @@ def clear_recent() -> None:
     ``tests/conftest.py`` calls this per test (and snapshots/restores the
     listener list) so span assertions are hermetic."""
     _recent.clear()
+
+
+# ---------------------------------------------------------------- hot-path spans
+#: annotation prefix of a hot span in the profiler's trace
+HOT_ANNOTATION_PREFIX = "fusion:"
+#: the record keeps the newest spans only: a 30 s window of lone edits is
+#: about 11,000 waves of about ten spans, and an operator who leaves the
+#: spans on must not grow the process without bound
+HOT_RECORD_CAP = 1 << 19
+
+
+class HotSpanRecord(NamedTuple):
+    """One closed hot span. ``start``/``end`` are ``time.perf_counter``
+    seconds; ``parent_id`` is the enclosing hot span's id (else the open
+    always-on span's, else None); spans of one wave share ``wave``, the seq
+    ``TpuGraphBackend._begin_wave`` minted."""
+
+    name: str
+    start: float
+    end: float
+    span_id: int
+    parent_id: Optional[int]
+    wave: Optional[int]
+
+
+#: plain tuples in HotSpanRecord's field order (a NamedTuple costs the hot
+#: path several times a tuple); :func:`hot_spans` names the fields
+_hot_record: Deque[tuple] = deque(maxlen=HOT_RECORD_CAP)
+_current_hot: "contextvars.ContextVar[Optional[HotSpan]]" = contextvars.ContextVar(
+    "fusion_current_hot_span", default=None
+)
+_hot_forced = False
+_TraceAnnotation: Any = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _profiler_tracing() -> bool:
+    """First call only: bind the profiler's own predicate in this one's
+    place (importing jax at module scope would charge every importer of
+    ``diagnostics`` for it)."""
+    global _TraceAnnotation, _profiler_tracing
+    from jax.profiler import TraceAnnotation
+
+    _TraceAnnotation = TraceAnnotation
+    _profiler_tracing = TraceAnnotation.is_enabled
+    return _profiler_tracing()
+
+
+class _NoopSpan:
+    """What a span site gets while the gate is off: one shared object, no
+    clock read, no contextvar."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoopSpan":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        return None
+
+    def set_wave(self, wave: Optional[int]) -> None:
+        return None
+
+
+_NOOP_SPAN = _NoopSpan()
+
+
+class HotSpan:
+    __slots__ = ("name", "wave", "start", "span_id", "parent_id", "_token", "_annotation")
+
+    def __init__(self, name: str, wave: Optional[int], start: Optional[float]):
+        self.name = name
+        self.wave = wave
+        self.start = start
+
+    def set_wave(self, wave: Optional[int]) -> None:
+        """For the span that opens before its wave's seq is minted."""
+        self.wave = wave
+
+    def __enter__(self) -> "HotSpan":
+        parent = _current_hot.get()
+        wave = self.wave
+        if parent is not None:
+            self.parent_id = parent.span_id
+            if wave is None or wave == parent.wave:
+                # the enclosing span's event carries this wave already (or
+                # will, once it learns it): metadata on an event costs the
+                # chip's host several times a bare name (PERF.md §5)
+                self.wave, wave = parent.wave, None
+        else:
+            outer = _current_span.get()
+            self.parent_id = outer.span_id if outer is not None else None
+        self.span_id = next(_span_ids)
+        self._token = _current_hot.set(self)
+        name = HOT_ANNOTATION_PREFIX + self.name
+        if wave is None:
+            self._annotation = _TraceAnnotation(name)
+        else:
+            self._annotation = _TraceAnnotation(name, wave=wave)
+        self._annotation.__enter__()
+        if self.start is None:
+            self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        end = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
+        _current_hot.reset(self._token)
+        _hot_record.append(
+            (self.name, self.start, end, self.span_id, self.parent_id, self.wave)
+        )
+
+
+def hot_spans_on() -> bool:
+    return _hot_forced or _profiler_tracing()
+
+
+def hot_span(name: str, wave: Optional[int] = None, start: Optional[float] = None):
+    """A span of the live loop, as a context manager. ``wave`` defaults to
+    the enclosing hot span's; ``start`` is a ``perf_counter`` reading the
+    caller already took at this line (an accumulator's), reused so that the
+    span and the accumulator start together."""
+    if _hot_forced or _profiler_tracing():
+        return HotSpan(name, wave, start)
+    return _NOOP_SPAN
+
+
+def enable_hot_spans() -> None:
+    """The operator's switch: record hot spans with no profiler session."""
+    global _hot_forced
+    _hot_forced = True
+    if _TraceAnnotation is None:
+        _profiler_tracing()
+
+
+def disable_hot_spans() -> None:
+    """Back to the default: hot spans follow the profiler."""
+    global _hot_forced
+    _hot_forced = False
+
+
+def hot_spans() -> List[HotSpanRecord]:
+    """The record of closed hot spans, oldest first (one C-level copy: other
+    threads may be appending)."""
+    return [HotSpanRecord._make(r) for r in list(_hot_record)]
+
+
+def clear_hot_spans() -> None:
+    _hot_record.clear()

@@ -45,7 +45,7 @@ import numpy as np
 
 from ..diagnostics.flight_recorder import RECORDER
 from ..diagnostics.metrics import WaveProfiler, global_metrics, next_wave_seq
-from ..diagnostics.tracing import CAUSE_PREFIX, current_span, span_cause_id
+from ..diagnostics.tracing import CAUSE_PREFIX, current_span, hot_span, span_cause_id
 from .device_graph import DeviceGraph
 
 if TYPE_CHECKING:
@@ -62,6 +62,12 @@ __all__ = ["TpuGraphBackend", "RowBlock"]
 #: tracing (span_cause_id / find_span_by_cause key on byte-identical
 #: prefixes) — never mint a diverging local copy.
 _CAUSE_PREFIX = CAUSE_PREFIX
+
+#: hot-span name of each batched journal run flush() replays
+_REPLAY_SPAN = {
+    kind: "flush.replay." + kind
+    for kind in ("bump", "edge", "epack", "icasc", "cpack", "invalid")
+}
 
 
 class RowBlock:
@@ -401,36 +407,40 @@ class TpuGraphBackend:
         self, kind, seeds, cause, t0, t1, newly, seq, groups=None,
         fused_depth=None, seq_span=None, dispatches=None, mesh=None,
     ) -> None:
-        if self.profiler.enabled:
-            self.profiler.record_wave(
-                kind,
-                seeds=seeds,
-                newly=newly,
-                device_ms=(t1 - t0) * 1e3,
-                apply_ms=(time.perf_counter() - t1) * 1e3,
-                cause=cause,
-                groups=groups,
-                seq=seq,
-                fused_depth=fused_depth,
-                seq_span=seq_span,
-                dispatches=dispatches,
-                mesh=mesh,
-            )
-            if fused_depth is not None and dispatches:
-                # per-dispatch depth samples feed the engagement histogram
-                per = max(int(round(fused_depth / dispatches)), 1)
-                for _ in range(int(dispatches)):
-                    self.profiler.note_fused_dispatch(per)
-        if RECORDER.enabled:
-            detail = f"{kind}: seeds={seeds} newly={newly}"
-            if fused_depth is not None:
-                detail += f" fused_depth={fused_depth}"
-            RECORDER.note(
-                "wave",
-                cause=cause,
-                wave=seq,
-                detail=detail,
-            )
+        """``t0``/``t1`` are the host clock around the blocking dispatch:
+        ``device_ms`` is dispatch until the readback returned, so device
+        time, transfer and host wait together (see WaveProfiler)."""
+        with hot_span("wave.profile", seq):
+            if self.profiler.enabled:
+                self.profiler.record_wave(
+                    kind,
+                    seeds=seeds,
+                    newly=newly,
+                    device_ms=(t1 - t0) * 1e3,
+                    apply_ms=(time.perf_counter() - t1) * 1e3,
+                    cause=cause,
+                    groups=groups,
+                    seq=seq,
+                    fused_depth=fused_depth,
+                    seq_span=seq_span,
+                    dispatches=dispatches,
+                    mesh=mesh,
+                )
+                if fused_depth is not None and dispatches:
+                    # per-dispatch depth samples feed the engagement histogram
+                    per = max(int(round(fused_depth / dispatches)), 1)
+                    for _ in range(int(dispatches)):
+                        self.profiler.note_fused_dispatch(per)
+            if RECORDER.enabled:
+                detail = f"{kind}: seeds={seeds} newly={newly}"
+                if fused_depth is not None:
+                    detail += f" fused_depth={fused_depth}"
+                RECORDER.note(
+                    "wave",
+                    cause=cause,
+                    wave=seq,
+                    detail=detail,
+                )
 
     # ------------------------------------------------------------------ event feed
     def _on_register(self, computed: "Computed") -> None:
@@ -593,8 +603,14 @@ class TpuGraphBackend:
         if not journal:
             return
         t_flush0 = time.perf_counter()
+        with hot_span("flush", start=t_flush0):
+            self._replay_journal(journal, t_flush0)
+
+    def _replay_journal(self, journal: List[Tuple[str, object]], t_flush0: float) -> None:
+        """:meth:`flush`'s body, for a non-empty journal taken at ``t_flush0``."""
         journal_pre = len(journal)
-        journal = self._coalesce_bump_epack_pairs(journal)
+        with hot_span("flush.coalesce"):
+            journal = self._coalesce_bump_epack_pairs(journal)
         journal_post = len(journal)
         icasc_parts: List[np.ndarray] = []
         icasc_s = 0.0  # embedded wave time: reported on the wave records,
@@ -615,15 +631,17 @@ class TpuGraphBackend:
             icasc_parts.clear()
             cause, wave_seq = self._begin_wave()
             t0 = time.perf_counter()
-            was_clear = nids[~self.graph._h_invalid[nids]]
-            total, newly_ids = self._wave_union([nids.tolist()])
-            newly_ids = newly_ids[~np.isin(newly_ids, nids)]
-            if was_clear.size:
-                self.graph.clear_invalid_ids(was_clear)
-            t1 = time.perf_counter()
-            self._apply_newly(newly_ids)
-            self.device_invalidations += total
-            self._profile_wave("icasc", len(nids), cause, t0, t1, len(newly_ids), wave_seq)
+            with hot_span("flush.icasc", wave_seq, t0):
+                was_clear = nids[~self.graph._h_invalid[nids]]
+                with hot_span("wave.union"):
+                    total, newly_ids = self._wave_union([nids.tolist()])
+                newly_ids = newly_ids[~np.isin(newly_ids, nids)]
+                if was_clear.size:
+                    self.graph.clear_invalid_ids(was_clear)
+                t1 = time.perf_counter()
+                self._apply_newly(newly_ids)
+                self.device_invalidations += total
+                self._profile_wave("icasc", len(nids), cause, t0, t1, len(newly_ids), wave_seq)
             icasc_s += time.perf_counter() - t0
 
         i, n = 0, len(journal)
@@ -648,35 +666,8 @@ class TpuGraphBackend:
                 acc = np.concatenate(icasc_parts)
                 if np.isin(touched, acc).any():
                     run_icasc()
-            if kind == "bump":
-                self.graph.bump_epochs(np.asarray(batch, dtype=np.int32))
-            elif kind == "edge":
-                arr = np.asarray(batch, dtype=np.int32)
-                # dst_epoch defaults to the dependent's CURRENT epoch, which
-                # is correct exactly because earlier bumps already applied
-                self.graph.add_edges(arr[:, 0], arr[:, 1])
-            elif kind == "epack":  # bulk-declared row edges (already nids)
-                self.graph.add_edges(
-                    np.concatenate([p[0] for p in batch]),
-                    np.concatenate([p[1] for p in batch]),
-                )
-            elif kind == "icasc":
-                # host-led table invalidations CASCADE — but interleaved
-                # scalar churn would split them into many batches, and a
-                # union wave per batch is the one per-flush device cost
-                # that matters. All icasc marks of this flush mark their
-                # bits NOW (order vs bumps/refreshes preserved) and expand
-                # in ONE union wave at the END: expansion against the
-                # final structural state is safe — an edge only dies when
-                # its dependent recomputed, and a recomputed dependent is
-                # fresh by construction.
-                nids = np.concatenate(batch)
-                self.graph.mark_invalid(nids)
-                icasc_parts.append(nids)
-            elif kind == "cpack":  # bulk refreshes: consistent again, no bump
-                self.graph.clear_invalid_ids(np.concatenate(batch))
-            else:  # invalid
-                self.graph.mark_invalid(np.asarray(batch, dtype=np.int32))
+            with hot_span(_REPLAY_SPAN[kind]):
+                self._replay_run(kind, batch, icasc_parts)
             i = j
         if icasc_parts:
             run_icasc()
@@ -686,6 +677,38 @@ class TpuGraphBackend:
                 journal_post,
                 (time.perf_counter() - t_flush0 - icasc_s) * 1e3,
             )
+
+    def _replay_run(self, kind: str, batch: list, icasc_parts: List[np.ndarray]) -> None:
+        """One batched same-kind run of the journal against the mirror."""
+        if kind == "bump":
+            self.graph.bump_epochs(np.asarray(batch, dtype=np.int32))
+        elif kind == "edge":
+            arr = np.asarray(batch, dtype=np.int32)
+            # dst_epoch defaults to the dependent's CURRENT epoch, which
+            # is correct exactly because earlier bumps already applied
+            self.graph.add_edges(arr[:, 0], arr[:, 1])
+        elif kind == "epack":  # bulk-declared row edges (already nids)
+            self.graph.add_edges(
+                np.concatenate([p[0] for p in batch]),
+                np.concatenate([p[1] for p in batch]),
+            )
+        elif kind == "icasc":
+            # host-led table invalidations CASCADE — but interleaved
+            # scalar churn would split them into many batches, and a
+            # union wave per batch is the one per-flush device cost
+            # that matters. All icasc marks of this flush mark their
+            # bits NOW (order vs bumps/refreshes preserved) and expand
+            # in ONE union wave at the END: expansion against the
+            # final structural state is safe — an edge only dies when
+            # its dependent recomputed, and a recomputed dependent is
+            # fresh by construction.
+            nids = np.concatenate(batch)
+            self.graph.mark_invalid(nids)
+            icasc_parts.append(nids)
+        elif kind == "cpack":  # bulk refreshes: consistent again, no bump
+            self.graph.clear_invalid_ids(np.concatenate(batch))
+        else:  # invalid
+            self.graph.mark_invalid(np.asarray(batch, dtype=np.int32))
 
     @staticmethod
     def _coalesce_bump_epack_pairs(journal: List[Tuple[str, object]]) -> List[Tuple[str, object]]:
@@ -879,22 +902,25 @@ class TpuGraphBackend:
         lands, its rows and their transitive dependents go stale). The wave
         application marks hit rows stale in bulk and runs the two-tier
         host apply for scalar twins. Returns total newly invalidated."""
-        self.flush()
-        nids = block.base + self._check_rows(block, rows)
-        # NOTE: routing small seeds through the dense frontier BFS
-        # (run_wave_collect) was measured SLOWER at 10M (2.2 s vs 0.77 s)
-        # — per-level full-edge gathers over the pow2-padded edge arrays
-        # lose to one depth-free mirror sweep. The mirror union is the
-        # lone-wave path too.
-        cause, wave_seq = self._begin_wave()
-        t0 = time.perf_counter()
-        total, newly_ids = self._wave_union([nids.tolist()])
-        t1 = time.perf_counter()
-        self._apply_newly(newly_ids)
-        self.waves_run += 1
-        self.device_invalidations += total
-        self._profile_wave("union", len(nids), cause, t0, t1, len(newly_ids), wave_seq)
-        return total
+        with hot_span("cascade") as span:
+            self.flush()
+            nids = block.base + self._check_rows(block, rows)
+            cause, wave_seq = self._begin_wave()
+            # NOTE: routing small seeds through the dense frontier BFS
+            # (run_wave_collect) was measured SLOWER at 10M (2.2 s vs 0.77 s)
+            # — per-level full-edge gathers over the pow2-padded edge arrays
+            # lose to one depth-free mirror sweep. The mirror union is the
+            # lone-wave path too.
+            t0 = time.perf_counter()
+            with hot_span("wave.union", wave_seq, t0):  # its event names the wave
+                total, newly_ids = self._wave_union([nids.tolist()])
+            span.set_wave(wave_seq)
+            t1 = time.perf_counter()
+            self._apply_newly(newly_ids)
+            self.waves_run += 1
+            self.device_invalidations += total
+            self._profile_wave("union", len(nids), cause, t0, t1, len(newly_ids), wave_seq)
+            return total
 
     def cascade_rows_lanes_refresh_chain(
         self, block: RowBlock, bursts, nonblocking: bool = False
@@ -1023,72 +1049,74 @@ class TpuGraphBackend:
         pending-invalid until their next read — identical to the host
         path. Rows stale on the TABLE but not invalid in the graph (no
         such rows arise from wave/icasc flows) refresh on next read."""
-        self.flush()
-        table = block.table
-        fn = table.device_compute_fn
-        if fn is None:
-            raise TypeError(
-                "table has no device loader — declare "
-                "TableBacking(device_batch=...) or use table.refresh()"
-            )
-        if block.n_rows != table.n_rows:
-            raise ValueError(
-                "refresh_block_on_device requires a FULL table bind "
-                f"(block covers {block.n_rows} of {table.n_rows} rows); "
-                "partially bound tables refresh through table.refresh()"
-            )
-        g = self.graph.device_arrays()
-        update_valid = not table._valid_dev_dirty
-        loader_args = (
-            tuple(table.device_loader_args())
-            if table.device_loader_args is not None
-            else ()
-        )
-        prog = block._dev_refresh.get(update_valid)
-        if prog is None:
-            import jax
-            import jax.numpy as jnp
-            from jax import lax
-
-            base, n_rows = block.base, block.n_rows
-
-            @jax.jit
-            def prog(values, valid_dev, g_invalid, *largs):
-                stale = lax.slice_in_dim(g_invalid, base, base + n_rows)
-                ids = jnp.arange(n_rows, dtype=jnp.int32)
-                fresh = fn(ids, *largs)
-                mask = stale.reshape((n_rows,) + (1,) * (values.ndim - 1))
-                values2 = jnp.where(mask, fresh, values)
-                inv2 = lax.dynamic_update_slice_in_dim(
-                    g_invalid, jnp.zeros(n_rows, dtype=g_invalid.dtype), base, 0
+        with hot_span("refresh"):
+            self.flush()
+            table = block.table
+            fn = table.device_compute_fn
+            if fn is None:
+                raise TypeError(
+                    "table has no device loader — declare "
+                    "TableBacking(device_batch=...) or use table.refresh()"
                 )
-                valid2 = (valid_dev | stale) if update_valid else valid_dev
-                return values2, valid2, inv2
+            if block.n_rows != table.n_rows:
+                raise ValueError(
+                    "refresh_block_on_device requires a FULL table bind "
+                    f"(block covers {block.n_rows} of {table.n_rows} rows); "
+                    "partially bound tables refresh through table.refresh()"
+                )
+            g = self.graph.device_arrays()
+            update_valid = not table._valid_dev_dirty
+            loader_args = (
+                tuple(table.device_loader_args())
+                if table.device_loader_args is not None
+                else ()
+            )
+            prog = block._dev_refresh.get(update_valid)
+            if prog is None:
+                import jax
+                import jax.numpy as jnp
+                from jax import lax
 
-            block._dev_refresh[update_valid] = prog
-        # valid_mask (not the raw array) applies any deferred small
-        # updates first; the update_valid=False variant ignores validity
-        valid_in = table.valid_mask if update_valid else table._valid_dev
-        values2, valid2, inv2 = prog(
-            table._values, valid_in, g.invalid, *loader_args
-        )
-        table._values = values2
-        if update_valid:
-            table._valid_dev = valid2
-        self.graph._g = g._replace(invalid=inv2)
-        # host bookkeeping from the host invalid mirror — no device readback
-        dg = self.graph
-        cleared = dg._h_invalid[block.base : block.end()].copy()
-        n_cleared = int(np.count_nonzero(cleared))
-        if n_cleared == 0:
-            return 0
-        dg._h_invalid[block.base : block.end()] = False
-        dg.invalid_version += 1
-        # non-backend on_refresh subscribers still get the refreshed ids
-        # inside the shared tail; the backend's own hook is skipped — its
-        # job (clearing the device invalid bits) was just done in-program
-        _finish_block_refresh_bookkeeping(table, cleared)
-        return n_cleared
+                base, n_rows = block.base, block.n_rows
+
+                @jax.jit
+                def prog(values, valid_dev, g_invalid, *largs):
+                    stale = lax.slice_in_dim(g_invalid, base, base + n_rows)
+                    ids = jnp.arange(n_rows, dtype=jnp.int32)
+                    fresh = fn(ids, *largs)
+                    mask = stale.reshape((n_rows,) + (1,) * (values.ndim - 1))
+                    values2 = jnp.where(mask, fresh, values)
+                    inv2 = lax.dynamic_update_slice_in_dim(
+                        g_invalid, jnp.zeros(n_rows, dtype=g_invalid.dtype), base, 0
+                    )
+                    valid2 = (valid_dev | stale) if update_valid else valid_dev
+                    return values2, valid2, inv2
+
+                block._dev_refresh[update_valid] = prog
+            # valid_mask (not the raw array) applies any deferred small
+            # updates first; the update_valid=False variant ignores validity
+            valid_in = table.valid_mask if update_valid else table._valid_dev
+            with hot_span("refresh.dispatch"):
+                values2, valid2, inv2 = prog(
+                    table._values, valid_in, g.invalid, *loader_args
+                )
+            table._values = values2
+            if update_valid:
+                table._valid_dev = valid2
+            self.graph._g = g._replace(invalid=inv2)
+            # host bookkeeping from the host invalid mirror — no device readback
+            dg = self.graph
+            cleared = dg._h_invalid[block.base : block.end()].copy()
+            n_cleared = int(np.count_nonzero(cleared))
+            if n_cleared == 0:
+                return 0
+            dg._h_invalid[block.base : block.end()] = False
+            dg.invalid_version += 1
+            # non-backend on_refresh subscribers still get the refreshed ids
+            # inside the shared tail; the backend's own hook is skipped — its
+            # job (clearing the device invalid bits) was just done in-program
+            _finish_block_refresh_bookkeeping(table, cleared)
+            return n_cleared
 
     def warm_block_on_device(self, block: RowBlock) -> int:
         """Load EVERY row of a bound table through its DEVICE loader in one
@@ -1340,9 +1368,10 @@ class TpuGraphBackend:
         prev_wave = RECORDER.current_wave
         RECORDER.current_wave = self.last_wave_seq
         try:
-            if isinstance(newly, np.ndarray) and newly.dtype == np.bool_:
-                return self._apply_newly_mask(newly)
-            self._apply_newly_ids(newly)
+            with hot_span("wave.apply", self.last_wave_seq, self.last_wave_applied_ts):
+                if isinstance(newly, np.ndarray) and newly.dtype == np.bool_:
+                    return self._apply_newly_mask(newly)
+                self._apply_newly_ids(newly)
         finally:
             RECORDER.current_wave = prev_wave
 

@@ -20,6 +20,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..diagnostics.tracing import hot_span
 from ..ops.wave import (
     GraphArrays,
     run_wave,
@@ -646,17 +647,21 @@ class DeviceGraph:
             return False
         if m["validated_at"] == self._struct_version:
             return True
-        if self._mirror_deltas is not None:
-            return self._try_patch_mirror(m)
-        if m["fp"] is None:
-            # patched mirrors shed their fingerprint (it describes the
-            # build-time edge sequence, not the patched state): once the
-            # delta log broke, only a rebuild revalidates
-            m["missed_at"] = self._struct_version
-            return False
-        return check_structure_cache(
-            m, self._struct_version, lambda: self._live_edge_fingerprint()[2]
-        )
+        # past the O(1) answers there is work to time: a patch or an
+        # O(edges) fingerprint
+        with hot_span("mirror.validate"):
+            if self._mirror_deltas is not None:
+                with hot_span("mirror.patch"):
+                    return self._try_patch_mirror(m)
+            if m["fp"] is None:
+                # patched mirrors shed their fingerprint (it describes the
+                # build-time edge sequence, not the patched state): once the
+                # delta log broke, only a rebuild revalidates
+                m["missed_at"] = self._struct_version
+                return False
+            return check_structure_cache(
+                m, self._struct_version, lambda: self._live_edge_fingerprint()[2]
+            )
 
     def _break_mirror_deltas(self) -> bool:
         self._mirror_deltas = None
@@ -1480,28 +1485,35 @@ class DeviceGraph:
         from ..ops.ell_wave import ell_live_union_step
 
         jnp = self._jnp
-        g = self.device_arrays()
-        ids = np.full(self.LAT_SEED_MAX, lat["n_tot"], dtype=np.int32)
-        ids[: len(flat_ids)] = np.asarray(flat_ids, dtype=np.int32)
-        step = ell_live_union_step(
-            lat["n_tot"], lat["n_real"], self.n_cap, self.LAT_LCAP, self.LAT_CAP
-        )
-        g_invalid2, count, acc, over = step(
-            lat["ell_dst"], lat["ell_epoch"], g.node_epoch, g.invalid,
-            jnp.asarray(ids),
-        )
-        count, acc, over = jax.device_get((count, acc, over))
-        if bool(over):
-            return None
-        self._g = g._replace(invalid=g_invalid2)
-        self.mirror_bursts += 1
-        self.lat_waves += 1
-        count = int(count)
-        # acc is sorted ascending: real ids (< n_real) form the prefix
-        newly = acc[:count].astype(np.int32)
-        if count:
-            self.invalid_version += 1
-            self._h_invalid[newly] = True
+        # two spans, not four (the topo path's stage and commit have their
+        # own): a lone edit is a couple of milliseconds and passes here every
+        # time, and a recorded span costs microseconds (PERF.md §5)
+        with hot_span("lat.dispatch"):
+            g = self.device_arrays()
+            ids = np.full(self.LAT_SEED_MAX, lat["n_tot"], dtype=np.int32)
+            ids[: len(flat_ids)] = np.asarray(flat_ids, dtype=np.int32)
+            step = ell_live_union_step(
+                lat["n_tot"], lat["n_real"], self.n_cap, self.LAT_LCAP, self.LAT_CAP
+            )
+            g_invalid2, count, acc, over = step(
+                lat["ell_dst"], lat["ell_epoch"], g.node_epoch, g.invalid,
+                jnp.asarray(ids),
+            )
+        with hot_span("lat.readback"):
+            # here the host waits on the program; the host mirror's commit
+            # after it is a hundredth of the wait
+            count, acc, over = jax.device_get((count, acc, over))
+            if bool(over):
+                return None
+            self._g = g._replace(invalid=g_invalid2)
+            self.mirror_bursts += 1
+            self.lat_waves += 1
+            count = int(count)
+            # acc is sorted ascending: real ids (< n_real) form the prefix
+            newly = acc[:count].astype(np.int32)
+            if count:
+                self.invalid_version += 1
+                self._h_invalid[newly] = True
         return count, newly
 
     LAT_CHAIN_OUT_CAP = 65536
@@ -1595,45 +1607,50 @@ class DeviceGraph:
         jnp = self._jnp
         m = self._topo_mirror
         n_tot = m["n_tot"]
-        flat = np.asarray(
-            [int(i) for s in seed_id_lists for i in s], dtype=np.int64
-        )
-        new_ids = m["inv_perm"][flat] if len(flat) else np.empty(0, np.int64)
-        width = max(256, _round_up_pow2(max(len(new_ids), 1)))  # shared program
-        ids = np.full(width, n_tot, dtype=np.int32)  # pad = null row
-        ids[: len(new_ids)] = new_ids.astype(np.int32)
-        g = self.device_arrays()
-        garrays = m["garrays"]
-        passes = m.get("passes", 1)
-        if passes <= self.FUSED_PASS_MAX:
-            # steady state AND lightly patched mirrors: ONE dispatch + one
-            # readback (fewer dispatches, fewer host round trips);
-            # one fused program per pass count ≤ FUSED_PASS_MAX,
-            # each compiled once per level layout and persisted — heavier
-            # violation loads fall to the split pipeline's host loop,
-            # which never recompiles at any pass count
-            from ..ops.topo_wave import topo_mirror_fused_union_step
+        with hot_span("topo.stage"):
+            flat = np.asarray(
+                [int(i) for s in seed_id_lists for i in s], dtype=np.int64
+            )
+            new_ids = m["inv_perm"][flat] if len(flat) else np.empty(0, np.int64)
+            width = max(256, _round_up_pow2(max(len(new_ids), 1)))  # shared program
+            ids = np.full(width, n_tot, dtype=np.int32)  # pad = null row
+            ids[: len(new_ids)] = new_ids.astype(np.int32)
+            g = self.device_arrays()
+            garrays = m["garrays"]
+            passes = m.get("passes", 1)
+            ids_dev = jnp.asarray(ids)
+        with hot_span("topo.dispatch"):
+            if passes <= self.FUSED_PASS_MAX:
+                # steady state AND lightly patched mirrors: ONE dispatch + one
+                # readback (fewer dispatches, fewer host round trips);
+                # one fused program per pass count ≤ FUSED_PASS_MAX,
+                # each compiled once per level layout and persisted — heavier
+                # violation loads fall to the split pipeline's host loop,
+                # which never recompiles at any pass count
+                from ..ops.topo_wave import topo_mirror_fused_union_step
 
-            self._count_adaptive(passes)
-            g_invalid2, count, out_ids, overflow = topo_mirror_fused_union_step(
-                m["level_starts"], m["cap"], n_tot, passes
-            )(garrays, m["node_epoch0"], m["perm_clipped"], g.invalid, jnp.asarray(ids))
-        else:
-            node_epoch, seed_bits = topo_mirror_gate_step(n_tot)(
-                garrays.is_real, m["node_epoch0"], m["perm_clipped"], g.invalid,
-                jnp.asarray(ids),
-            )
-            state = run_topo_sweep_passes(
-                m["level_starts"], garrays, seed_bits, node_epoch, passes
-            )
-            g_invalid2, count, out_ids, overflow = topo_mirror_finish_step(
-                m["cap"], n_tot
-            )(garrays.is_real, m["perm_clipped"], g.invalid, state.invalid_bits)
-        count, out_ids, overflow = jax.device_get((count, out_ids, overflow))
-        self._g = g._replace(invalid=g_invalid2)
-        self.mirror_bursts += 1
-        count = int(count)
-        return count, self._patch_host_invalid(count, out_ids, bool(overflow))
+                self._count_adaptive(passes)
+                g_invalid2, count, out_ids, overflow = topo_mirror_fused_union_step(
+                    m["level_starts"], m["cap"], n_tot, passes
+                )(garrays, m["node_epoch0"], m["perm_clipped"], g.invalid, ids_dev)
+            else:
+                node_epoch, seed_bits = topo_mirror_gate_step(n_tot)(
+                    garrays.is_real, m["node_epoch0"], m["perm_clipped"], g.invalid,
+                    ids_dev,
+                )
+                state = run_topo_sweep_passes(
+                    m["level_starts"], garrays, seed_bits, node_epoch, passes
+                )
+                g_invalid2, count, out_ids, overflow = topo_mirror_finish_step(
+                    m["cap"], n_tot
+                )(garrays.is_real, m["perm_clipped"], g.invalid, state.invalid_bits)
+        with hot_span("topo.readback"):  # here the host waits on the sweep
+            count, out_ids, overflow = jax.device_get((count, out_ids, overflow))
+        with hot_span("topo.commit"):
+            self._g = g._replace(invalid=g_invalid2)
+            self.mirror_bursts += 1
+            count = int(count)
+            return count, self._patch_host_invalid(count, out_ids, bool(overflow))
 
     #: chain stages fused per dispatch (run_waves_lanes_chain): deep chains
     #: split into this many stages per compiled scan — a bounded program

@@ -80,6 +80,7 @@ from typing import TYPE_CHECKING, Deque, List, Optional, Sequence
 import numpy as np
 
 from ..diagnostics.metrics import global_metrics
+from ..diagnostics.tracing import hot_span
 
 if TYPE_CHECKING:
     from .backend import RowBlock, TpuGraphBackend
@@ -180,12 +181,14 @@ class SuperRoundTicket:
         # the host stall (everything else in harvest is host apply work
         # that _could_ overlap the next super-round's device execution)
         t0 = time.perf_counter()
-        lane_counts, packed = jax.device_get((lc_d, pk_d))
+        with hot_span("superround.wait", self.seqs[0], t0):
+            lane_counts, packed = jax.device_get((lc_d, pk_d))
         stall = time.perf_counter() - t0
         prog.stall_s += stall
         prog._record_stall(stall, self.cause)
         inner.pending["batches"][0] = (lane_counts, packed, sizes)
-        per_burst = inner.harvest()
+        with hot_span("superround.apply", self.seqs[0]):
+            per_burst = inner.harvest()
         if prog._live_refresh is inner.refresh:
             prog._live_refresh = None
         prog.cleared_total += inner.cleared_total
@@ -331,6 +334,12 @@ class SuperRoundProgram:
         if self._disposed:
             raise RuntimeError("super-round program is disposed")
         t0 = time.perf_counter()
+        with hot_span("superround.stage", start=t0):
+            staged = self._stage(bursts)
+        self.stage_s += time.perf_counter() - t0
+        return staged
+
+    def _stage(self, bursts: Sequence[Sequence[Sequence[int]]]) -> StagedSeeds:
         backend = self.backend
         block = self.block
         routed = backend.mesh_routing_active()
@@ -363,7 +372,6 @@ class SuperRoundProgram:
             self._pack_routed(staged)
         else:
             self._pack_lanes(staged)
-        self.stage_s += time.perf_counter() - t0
         return staged
 
     def _pack_lanes(self, staged: StagedSeeds) -> None:
@@ -433,6 +441,12 @@ class SuperRoundProgram:
         Falls back, counted, per the module contract."""
         if self._disposed:
             raise RuntimeError("super-round program is disposed")
+        with hot_span("superround.dispatch") as span:
+            ticket = self._dispatch(staged)
+            span.set_wave(ticket.seqs[0])
+            return ticket
+
+    def _dispatch(self, staged: StagedSeeds) -> SuperRoundTicket:
         backend = self.backend
         if backend._journal:
             # flush() with a chain in flight would read and clear invalid

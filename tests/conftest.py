@@ -28,13 +28,15 @@ def _isolate_span_state():
     the next test's ``recent_spans()``, a listener a test forgot to remove
     fires forever, and one test's invalidation events pollute the next
     test's ``explain()``. Clear both rings and snapshot/restore the
-    listeners + recorder gate around every test (ISSUE 3/4 satellites)."""
+    listeners + recorder gate around every test (ISSUE 3/4 satellites); the
+    hot-path span record and its switch likewise."""
     from stl_fusion_tpu.diagnostics import tracing
     from stl_fusion_tpu.diagnostics.flight_recorder import RECORDER
     from stl_fusion_tpu.diagnostics.mesh_telemetry import global_mesh_trace
 
     trace_store = global_mesh_trace()
     tracing.clear_recent()
+    tracing.clear_hot_spans()
     RECORDER.clear()
     trace_store.clear()
     listeners_before = list(tracing._listeners)
@@ -43,6 +45,8 @@ def _isolate_span_state():
     yield
     tracing._listeners[:] = listeners_before
     tracing.clear_recent()
+    tracing.disable_hot_spans()
+    tracing.clear_hot_spans()
     RECORDER.enabled = recorder_enabled_before
     RECORDER.clear()
     trace_store.enabled = trace_enabled_before

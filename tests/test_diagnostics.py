@@ -1,19 +1,42 @@
-"""Tracing spans, CommandTracer filter, and batched EntityResolver tests
-(SURVEY §5.1 tracing; §2.3 CommandTracer; §2.6 DbEntityResolver)."""
+"""Tracing spans (the always-on kind and the live loop's hot-path kind),
+CommandTracer filter, and batched EntityResolver tests (SURVEY §5.1 tracing;
+§2.3 CommandTracer; §2.6 DbEntityResolver)."""
 import asyncio
+import glob
+from collections import Counter
 from dataclasses import dataclass
 
+import numpy as np
 import pytest
 
 from stl_fusion_tpu.commands import attach_command_tracer, command_handler
-from stl_fusion_tpu.core import FusionHub, set_default_hub
+from stl_fusion_tpu.core import (
+    ComputeService,
+    FusionHub,
+    TableBacking,
+    compute_method,
+    memo_table_of,
+    set_default_hub,
+)
 from stl_fusion_tpu.diagnostics import (
     add_listener,
     current_span,
     get_activity_source,
     recent_spans,
     remove_listener,
+    tracing,
 )
+from stl_fusion_tpu.diagnostics.tracing import (
+    enable_hot_spans,
+    find_span_by_cause,
+    hot_span,
+    hot_spans,
+    hot_spans_on,
+    span_cause_id,
+)
+from stl_fusion_tpu.graph import TpuGraphBackend
+from stl_fusion_tpu.graph.device_graph import DeviceGraph
+from stl_fusion_tpu.graph.synthetic import power_law_dag
 from stl_fusion_tpu.oplog import EntityResolver
 
 
@@ -65,6 +88,301 @@ class TestTracing:
 @dataclass(frozen=True)
 class Ping:
     n: int
+
+
+class AnnotationSpy:
+    """Stands in for ``jax.profiler.TraceAnnotation``: what was opened and
+    what was closed, by name."""
+
+    opened: list = []
+    closed: list = []
+
+    def __init__(self, name, **metadata):
+        self.name = name
+        self.metadata = metadata
+
+    def __enter__(self):
+        AnnotationSpy.opened.append((self.name, self.metadata))
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        AnnotationSpy.closed.append(self.name)
+
+    @staticmethod
+    def is_enabled():
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    hot_spans_on()  # binds the profiler's predicate and class; then swap
+    AnnotationSpy.opened, AnnotationSpy.closed = [], []
+    monkeypatch.setattr(tracing, "_TraceAnnotation", AnnotationSpy)
+    return AnnotationSpy
+
+
+class TestHotSpans:
+    def test_gate_off_is_one_shared_noop_and_records_nothing(self, annotations):
+        assert not hot_spans_on()
+        a, b = hot_span("cascade"), hot_span("lat.dispatch", 7, 1.0)
+        assert a is b
+        with a as span:
+            span.set_wave(3)
+            with b:
+                pass
+        assert hot_spans() == [] and annotations.opened == []
+
+    def test_gate_on_nests_with_parent_and_wave_seq(self, annotations):
+        enable_hot_spans()
+        assert hot_spans_on()
+        with hot_span("cascade") as outer:
+            with hot_span("flush") as early:
+                early.set_wave(41)
+            outer.set_wave(41)
+            with hot_span("wave.union", start=123.5):
+                with hot_span("lat.dispatch"):
+                    pass
+            with hot_span("wave.apply", 42):
+                pass
+        rec = {r.name: r for r in hot_spans()}
+        assert [r.name for r in hot_spans()] == [
+            "flush", "lat.dispatch", "wave.union", "wave.apply", "cascade"]
+        assert rec["cascade"].parent_id is None
+        assert rec["wave.union"].parent_id == rec["cascade"].span_id
+        assert rec["lat.dispatch"].parent_id == rec["wave.union"].span_id
+        # a child inherits the wave its parent holds when it opens; its own wins
+        assert [rec[n].wave for n in ("cascade", "flush", "wave.union",
+                                      "lat.dispatch", "wave.apply")] == [41, 41, 41, 41, 42]
+        assert rec["wave.union"].start == 123.5  # the caller's own reading
+        assert all(r.end >= r.start for r in rec.values() if r.name != "wave.union")
+        assert rec["cascade"].start <= rec["flush"].start
+        assert rec["wave.apply"].end <= rec["cascade"].end
+        # the event carries the wave only where the enclosing event does not
+        assert dict(annotations.opened) == {
+            "fusion:cascade": {}, "fusion:flush": {}, "fusion:wave.union": {},
+            "fusion:lat.dispatch": {}, "fusion:wave.apply": {"wave": 42}}
+        assert sorted(annotations.closed) == sorted(n for n, _ in annotations.opened)
+        tracing.disable_hot_spans()
+        assert hot_span("cascade") is hot_span("flush")  # the no-op again
+
+    def test_raising_body_still_closes_span_and_annotation(self, annotations):
+        enable_hot_spans()
+        with pytest.raises(ValueError):
+            with hot_span("cascade"):
+                with hot_span("lat.readback", 5):
+                    raise ValueError("device lost")
+        assert [r.name for r in hot_spans()] == ["lat.readback", "cascade"]
+        assert annotations.closed == ["fusion:lat.readback", "fusion:cascade"]
+        with hot_span("flush"):
+            pass
+        assert hot_spans()[-1].parent_id is None  # nothing left open
+
+    @pytest.mark.parametrize("hot", [False, True])
+    @pytest.mark.parametrize("source,name,tags", [
+        ("stl_fusion_tpu.commands", "run:Ping", {"top_level": True}),
+        ("oplog", "replay", {"index": 12, "agent": "a1"}),
+    ])
+    def test_always_on_spans_are_as_before(self, annotations, hot, source, name, tags):
+        """Ring, listeners, ``current_span`` and cause ids of the spans the
+        commander and the op-log reader open do not depend on the gate, and
+        a hot span never stands in for one."""
+        if hot:
+            enable_hot_spans()
+        seen = []
+        add_listener(seen.append)
+        try:
+            with get_activity_source(source).span(name, **tags) as span:
+                with hot_span("cascade"):
+                    assert current_span() is span
+                    assert tracing.current_cause_id() == span_cause_id(span)
+        finally:
+            remove_listener(seen.append)
+        assert seen == [span] and recent_spans(source=source, name=name) == [span]
+        assert span.tags == tags and span.parent_id is None
+        assert find_span_by_cause(span_cause_id(span)) is span
+        assert [r.parent_id for r in hot_spans()] == ([span.span_id] if hot else [])
+
+
+N_ROWS = 600
+DAG_SRC, DAG_DST = power_law_dag(N_ROWS, avg_degree=3, seed=7)
+
+
+class SpanDag(ComputeService):
+    def __init__(self, hub=None):
+        super().__init__(hub)
+        self.base = np.arange(N_ROWS, dtype=np.float32)
+
+    def load(self, ids):
+        return self.base[np.asarray(ids, dtype=np.int64)]
+
+    def load_dev(self, ids):
+        import jax.numpy as jnp
+
+        return jnp.asarray(self.base)[ids]
+
+    @compute_method(table=TableBacking(rows=N_ROWS, batch="load", device_batch="load_dev"))
+    async def node(self, i: int) -> float:
+        return float(self.base[i])
+
+
+def make_stack():
+    hub = FusionHub()
+    backend = TpuGraphBackend(hub, node_capacity=N_ROWS + 8, edge_capacity=len(DAG_SRC) + 512)
+    svc = SpanDag(hub)
+    hub.add_service(svc, "dag")
+    table = memo_table_of(svc.node)
+    block = backend.bind_table_rows(table)
+    backend.declare_row_edges(block, DAG_SRC, block, DAG_DST)
+    backend.warm_block_on_device(block)
+    backend.flush()
+    backend.graph.build_topo_mirror()
+    return backend, table, block
+
+
+def act_lat(backend, table, block):
+    backend.cascade_rows_batch(block, [N_ROWS - 1])
+    return backend.last_wave_seq
+
+
+def act_overflow(backend, table, block):
+    backend.cascade_rows_batch(block, [0])  # a closure beyond LAT_CAP
+    return backend.last_wave_seq
+
+
+def act_flush_icasc(backend, table, block):
+    table.invalidate([N_ROWS - 2])  # journals an icasc entry
+    backend.flush()
+    return backend.last_wave_seq
+
+
+def act_superround(backend, table, block):
+    program = backend.enable_super_rounds(block, depth=2)
+    staged = program.stage([[[5, 6], [7]], [[8], [9, 10]]])
+    ticket = program.dispatch(staged)
+    ticket.harvest()
+    program.dispose()
+    return ticket.seqs[0]
+
+
+def act_refresh(backend, table, block):
+    backend.refresh_block_on_device(block)
+    return None
+
+
+def act_patch(backend, table, block):
+    # a declared edge from a lower level to a higher: the mirror patches
+    levels = backend.graph.mirror_levels(np.arange(N_ROWS))
+    u, v = int(np.argmin(levels)), int(np.argmax(levels))
+    backend.declare_row_edges(block, [u], block, [v])
+    backend.cascade_rows_batch(block, [N_ROWS - 1])
+    return backend.last_wave_seq
+
+
+SPAN_SITES = {
+    # act, spans recorded exactly once under the act's wave seq, parent of each
+    "lat": (act_lat, {
+        "cascade": None, "wave.union": "cascade", "lat.dispatch": "wave.union",
+        "lat.readback": "wave.union", "wave.apply": "cascade",
+        "wave.profile": "cascade"}),
+    "overflow": (act_overflow, {
+        "cascade": None, "wave.union": "cascade", "lat.dispatch": "wave.union",
+        "lat.readback": "wave.union", "topo.stage": "wave.union",
+        "topo.dispatch": "wave.union", "topo.readback": "wave.union",
+        "topo.commit": "wave.union", "wave.apply": "cascade"}),
+    "flush_icasc": (act_flush_icasc, {
+        "flush.icasc": "flush", "wave.union": "flush.icasc",
+        "lat.dispatch": "wave.union", "lat.readback": "wave.union",
+        "wave.apply": "flush.icasc", "wave.profile": "flush.icasc"}),
+    "superround": (act_superround, {
+        "superround.dispatch": None, "superround.wait": None,
+        "superround.apply": None, "wave.profile": "superround.apply"}),
+    "refresh": (act_refresh, {}),
+    "patch": (act_patch, {
+        "cascade": None, "wave.union": "cascade",
+        "mirror.validate": "wave.union", "mirror.patch": "mirror.validate",
+        "lat.dispatch": "wave.union"}),
+}
+# recorded once with no wave of their own (they open before a seq is minted)
+SEQLESS = {
+    "flush_icasc": {"flush": None, "flush.coalesce": "flush", "flush.replay.icasc": "flush"},
+    "superround": {"superround.stage": None},
+    "refresh": {"refresh": None, "refresh.dispatch": "refresh"},
+    "patch": {"flush": "cascade", "flush.coalesce": "flush", "flush.replay.epack": "flush"},
+}
+
+
+@pytest.mark.parametrize("gate", ["on", "off"])
+@pytest.mark.parametrize("site", sorted(SPAN_SITES))
+def test_span_sites_of_the_live_loop(site, gate, monkeypatch, annotations):
+    if site == "overflow":
+        monkeypatch.setattr(DeviceGraph, "LAT_CAP", 32)
+    backend, table, block = make_stack()
+    if site == "refresh":
+        backend.cascade_rows_batch(block, [N_ROWS - 1])  # something to refresh
+    act, with_seq = SPAN_SITES[site]
+    if gate == "off":
+        # an untraced run: the record stays empty, no annotation is opened
+        act(backend, table, block)
+        assert hot_spans() == [] and annotations.opened == []
+        return
+    enable_hot_spans()
+    seq = act(backend, table, block)
+    record = hot_spans()
+    by_id = {r.span_id: r for r in record}
+    names = Counter(r.name for r in record)
+    for expected, waves in ((with_seq, {seq}), (SEQLESS.get(site, {}), {None})):
+        for name, parent in expected.items():
+            assert names[name] == 1, (name, names)
+            r = next(r for r in record if r.name == name)
+            assert r.wave in waves, (name, r.wave, seq)
+            assert (by_id[r.parent_id].name if r.parent_id else None) == parent, name
+    if site == "lat":
+        assert set(names) == set(with_seq)  # nothing else on a lone edit's path
+        # one event of the edit names its wave: the first to know it
+        assert [(n, m) for n, m in annotations.opened if m] == [
+            ("fusion:wave.union", {"wave": seq})]
+    if site == "superround":
+        assert names["wave.apply"] == 2  # one per round, inside superround.apply
+        apply = next(r for r in record if r.name == "superround.apply")
+        assert all(by_id[r.parent_id] is apply for r in record if r.name == "wave.apply")
+    assert {n for n, _ in annotations.opened} == {"fusion:" + n for n in names}
+    assert len(annotations.closed) == len(annotations.opened) == len(record)
+
+
+def test_profiler_trace_turns_the_spans_on_and_holds_them(tmp_path):
+    """Taking a ``jax.profiler`` trace is the switch: no call, no option.
+    The ``fusion:*`` events sit on a host line of the trace, nested as the
+    record has them, with the wave seq as a stat."""
+    import jax
+    from jax.profiler import ProfileData
+
+    backend, table, block = make_stack()
+    backend.cascade_rows_batch(block, [N_ROWS - 3])
+    assert hot_spans() == [] and not hot_spans_on()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        assert hot_spans_on()
+        backend.cascade_rows_batch(block, [N_ROWS - 1])
+    finally:
+        jax.profiler.stop_trace()
+    assert not hot_spans_on()
+    seq = backend.last_wave_seq
+    assert {r.wave for r in hot_spans() if r.name == "lat.dispatch"} == {seq}
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            events = [e for e in line.events if e.name.startswith("fusion:")]
+            if events:
+                assert plane.name.startswith("/host:")
+                found.update({e.name: e for e in events})
+    assert {"fusion:" + r.name for r in hot_spans()} == set(found)
+    outer, inner = found["fusion:cascade"], found["fusion:lat.dispatch"]
+    assert outer.start_ns <= inner.start_ns
+    assert inner.start_ns + inner.duration_ns <= outer.start_ns + outer.duration_ns
+    assert dict(found["fusion:wave.union"].stats)["wave"] == seq
 
 
 class TestCommandTracer:
