@@ -350,6 +350,7 @@ class TpuGraphBackend:
             "fusion_graph_journal_depth": len(self._journal),
             "fusion_waves_run_total": self.waves_run,
             "fusion_device_invalidations_total": self.device_invalidations,
+            "fusion_sweep_packed_dispatches_total": self.graph.sweep_packed_dispatches,
         }
 
     def _begin_wave(self) -> str:

@@ -188,6 +188,10 @@ class DeviceGraph:
         self.invalid_version = 0
         self.mirror_bursts = 0  # observability: bursts served by the mirror
         self.lat_waves = 0  # observability: unions served by the lat mirror
+        #: sweep programs dispatched over the lane-dense topo state (a fused
+        #: burst, chain batch or super-round is one; the split pipeline one
+        #: per pass)
+        self.sweep_packed_dispatches = 0
         #: shape of the last lane-burst execution: {"depth": logical
         #: stages, "dispatches": physical device dispatches} — the backend
         #: reads it to stamp fused-depth identity on profiler records
@@ -1630,6 +1634,7 @@ class DeviceGraph:
                 from ..ops.topo_wave import topo_mirror_fused_union_step
 
                 self._count_adaptive(passes)
+                self.sweep_packed_dispatches += 1
                 g_invalid2, count, out_ids, overflow = topo_mirror_fused_union_step(
                     m["level_starts"], m["cap"], n_tot, passes
                 )(garrays, m["node_epoch0"], m["perm_clipped"], g.invalid, ids_dev)
@@ -1638,6 +1643,7 @@ class DeviceGraph:
                     garrays.is_real, m["node_epoch0"], m["perm_clipped"], g.invalid,
                     ids_dev,
                 )
+                self.sweep_packed_dispatches += passes
                 state = run_topo_sweep_passes(
                     m["level_starts"], garrays, seed_bits, node_epoch, passes
                 )
@@ -1741,6 +1747,7 @@ class DeviceGraph:
                 group_base += len(s)
             mats = np.stack(parts)
             g = self.device_arrays()
+            self.sweep_packed_dispatches += 1
             if refresh is None:
                 from ..ops.topo_wave import topo_mirror_fused_lanes_chain_step
 
@@ -1851,6 +1858,7 @@ class DeviceGraph:
             )
         g = self.device_arrays()
         prog = self._refresh_chain_program(m, refresh, words, passes)
+        self.sweep_packed_dispatches += 1
         (
             g_inv2, values2, valid2, lane_counts_d, packed_d,
         ) = prog(
@@ -1995,6 +2003,7 @@ class DeviceGraph:
                 from ..ops.topo_wave import topo_mirror_fused_lanes_step
 
                 self._count_adaptive(passes)
+                self.sweep_packed_dispatches += 1
                 g_invalid2, lane_counts, union_count, packed = (
                     topo_mirror_fused_lanes_step(
                         m["level_starts"], n_tot, words, passes
@@ -2006,6 +2015,7 @@ class DeviceGraph:
                     garrays.is_real, m["node_epoch0"], m["perm_clipped"], g.invalid,
                     jnp.asarray(mat),
                 )
+                self.sweep_packed_dispatches += passes
                 state = run_topo_sweep_passes(
                     m["level_starts"], garrays, seed_bits, node_epoch, passes
                 )

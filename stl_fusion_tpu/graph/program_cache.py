@@ -26,6 +26,7 @@ __all__ = [
     "program_cache_dir",
     "program_cache_stats",
     "time_program_warm",
+    "note_program_shape",
     "program_warm_report",
     "reset_program_warms",
 ]
@@ -80,8 +81,23 @@ def program_cache_dir():
 
 
 #: per-program warm records: name -> {"key", "warm_s", "cache_hit",
-#: "new_entries"} (insertion-ordered; the bench cold_start block reports it)
+#: "new_entries"} plus the shape facts noted while the warm traced its
+#: programs (insertion-ordered; the bench cold_start block reports it)
 _PROGRAM_WARMS: dict = {}
+#: fact -> distinct values, in the order programs noted them
+_SHAPE_NOTES: dict = {}
+
+
+def note_program_shape(**facts) -> None:
+    """Called from a program's body while it is TRACED, with what its
+    shapes decided and no argument chose (the topo sweep's
+    ``nodes_per_row``). A :class:`time_program_warm` record carries the
+    distinct values noted inside its window: ``"nodes_per_row": [8, 1]``
+    for a warm that traced a 16-word sweep and a one-word one."""
+    for fact, value in facts.items():
+        seen = _SHAPE_NOTES.setdefault(fact, [])
+        if value not in seen:
+            seen.append(value)
 
 
 class time_program_warm:
@@ -118,6 +134,7 @@ class time_program_warm:
         import time
 
         self._entries0 = self._entries()
+        _SHAPE_NOTES.clear()
         self._t0 = time.perf_counter()
         return self
 
@@ -140,6 +157,7 @@ class time_program_warm:
             # min-compile-time persistence floor — either way, not a cold
             # multi-second XLA compile)
             "cache_hit": (new <= 0) if cache_present else None,
+            **{fact: list(seen) for fact, seen in _SHAPE_NOTES.items()},
         }
         return False
 

@@ -16,8 +16,18 @@ strictly better schedule:
    all in already-finalized earlier levels — OR-fold, and write the level's
    contiguous slice. After one pass over the table, ``invalid`` holds the
    full transitive closure of all 32 packed waves, no matter where their
-   seeds sat. Total gathered words = n·k, not n·k·depth: depth× less HBM
-   traffic than the dense pull kernel (the bench DAG runs ~30 levels).
+   seeds sat. Total gathered rows = n·k, not n·k·depth: depth× fewer
+   fetches than the dense pull kernel (the 10 M-node bench DAG runs 86
+   levels).
+3. **Lane-dense state.** The chip is bound by the NUMBER of fetches, not
+   by their bytes (v5e, PERF.md §5: 7.7 ns for a 4-byte fetch, 15 ns for
+   a whole 512-byte row, 36 ns for sixteen words strided across a state
+   laid out with the nodes along the lanes, which is how XLA:TPU lays
+   ``[n, 16]`` out). So inside the sweep, from 8 words a node on, the
+   bit state is ``int32[ceil(n / P), 128]``, ``P = 128 // words`` nodes to
+   a 128-lane row (:func:`_row_geometry`): every in-edge fetch is one
+   whole-row gather, and no array in the sweep has a minor dimension the
+   tiling would pad.
 
 Level boundaries are STATIC (baked into the compiled program — they only
 change when the graph's level structure changes), while the table contents
@@ -224,9 +234,12 @@ def topo_graph_arrays(graph: TopoGraph) -> TopoGraphArrays:
 
 
 def topo_init_state(n_tot: int, words: int = 1) -> TopoState:
-    """``words`` packs ``32*words`` independent waves per sweep: the random
-    row access that bounds the kernel fetches a full HBM transaction either
-    way, so wider rows are nearly free throughput (32 B rows = 8 words)."""
+    """``words`` packs ``32*words`` independent waves per sweep. The sweep
+    pays per fetched index, not per byte, once its state is lane-dense
+    (:func:`_row_geometry`): 16 words cost 19.5 ns an index on the v5e
+    against 7.7 ns for one word, for sixteen times the waves (PERF.md §5).
+    This is the state as it crosses a program boundary; the sweep packs it
+    on entry."""
     import jax.numpy as jnp
 
     if 32 * (n_tot + 1) >= 2**31:
@@ -254,19 +267,151 @@ def topo_seeds_to_bits(graph: TopoGraph, seed_ids_per_wave, words: int = 1) -> n
     return bits
 
 
+#: lanes of one vector row of the chip: the sweep state's minor dimension
+_LANES = 128
+#: rows of one level fetched at a time: the fetched block is
+#: ``rows x k x 512`` bytes before the fold (under 1 GB at k = 6)
+_FETCH_ROWS = 1 << 18
+
+
+def _row_geometry(words: int) -> Tuple[int, int]:
+    """``(P, Wp)``: nodes per state row and words per node slot. The sweep
+    state is ``int32[ceil((n_tot + 1) / P), P * Wp]``: node ``i`` owns lanes
+    ``(i % P) * Wp ... + Wp`` of row ``i // P``. From 8 words on a row is
+    the chip's 128 lanes, ``P = 128 // Wp`` nodes to it; ``words`` that does
+    not divide 128 rounds up to the next power of two. 128 words or more
+    are whole rows already, and under 8 words a node stays a row of its own
+    (``P`` = 1, ``Wp = words``)."""
+    if words >= _LANES or words < 8:
+        # XLA:TPU lays [n, W] out with the nodes along the lanes, and
+        # fetching W strided words costs 7.7 / 7.0 / 16.0 ns an index at
+        # W = 1 / 2 / 4 on the v5e, against 12.2 / 11.3 / 20.4 ns for the
+        # 512-byte row that would hold them; from 8 words on the row wins
+        # (22.8 -> 19.5 ns, and 36.2 -> 19.5 at 16). PERF.md §5.
+        return 1, words
+    wp = 1 << (words - 1).bit_length()
+    return _LANES // wp, wp
+
+
+def _or_over(x, axis: int):
+    import jax.numpy as jnp
+    from jax import lax
+
+    return lax.reduce(x, jnp.int32(0), lax.bitwise_or, (axis,))
+
+
+def _pack_rows(bits):
+    """``int32[n_tot+1]`` or ``[n_tot+1, W]`` -> the lane-dense
+    ``[R, P * Wp]`` sweep state (:func:`_row_geometry`)."""
+    import jax.numpy as jnp
+
+    if bits.ndim == 1:
+        bits = bits[:, None]
+    n, words = bits.shape
+    P, wp = _row_geometry(words)
+    rows = -(-n // P)
+    return jnp.pad(bits, ((0, rows * P - n), (0, wp - words))).reshape(rows, P * wp)
+
+
+def _unpack_rows(packed, n_tot: int, words: int):
+    """The inverse of :func:`_pack_rows`: ``[n_tot+1, words]``."""
+    wp = _row_geometry(words)[1]
+    return packed.reshape(-1, wp)[: n_tot + 1, :words]
+
+
+def _clear_null_row(packed, n_tot: int, words: int):
+    """The null row's words are always 0 (dead edges and pads read it)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    P, wp = _row_geometry(words)
+    return lax.dynamic_update_slice(
+        packed, jnp.zeros((1, wp), packed.dtype), (n_tot // P, (n_tot % P) * wp)
+    )
+
+
+def _sweep_packed(
+    level_starts, garrays: TopoGraphArrays, node_epoch, packed, words: int,
+    start_level: int,
+):
+    """One pass over the levels, ascending, on the lane-dense state: every
+    fetch reads only finalized rows. A level's in-edge fetch is a gather of
+    WHOLE 512-byte state rows at ``eff // P`` (the embedding-lookup form);
+    the ``Wp`` lanes of group ``eff % P`` are then selected by a mask, OR-ed
+    over the ``k`` slots, folded over the groups by lane rotations and
+    placed in the reading node's own group. Nothing in it has a minor
+    dimension other than the row's lanes. With ``P`` = 1 the fetched row
+    is the node's own words and the select and the fold fall away.
+
+    ``start_level=1`` skips level 0 (no in-edges at build time by
+    definition); multi-pass sweeps over PATCHED mirrors start at 0 — a
+    patched edge into a level-0 row (any edge into level 0 is a level
+    violation) fires from the previous pass's finalized state."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ..graph.program_cache import note_program_shape
+
+    in_src, edge_epoch, _is_real = garrays
+    n_tot = in_src.shape[0] - 1
+    k = in_src.shape[1]
+    P, wp = _row_geometry(words)
+    lanes = P * wp
+    note_program_shape(nodes_per_row=P)
+    shift = P.bit_length() - 1
+    lane_group = jnp.arange(lanes, dtype=jnp.int32) // wp
+    own_group = jnp.arange(P, dtype=jnp.int32)[:, None] == lane_group[None, :]
+
+    for l in range(start_level, len(level_starts) - 1):
+        a, b = level_starts[l], level_starts[l + 1]
+        # static sub-slices bound the fetched block; Python slices, not a
+        # fori_loop: a loop's view of in_src[n_tot+1, k] is padded to 128
+        # lanes (see topo_mirror_superround_step)
+        for c in range(a, b, _FETCH_ROWS):
+            d = min(c + _FETCH_ROWS, b)
+            rows = lax.slice(in_src, (c, 0), (d, k))
+            epochs = lax.slice(edge_epoch, (c, 0), (d, k))
+            own = lax.slice(node_epoch, (c,), (d,))
+            # dead edges (captured epoch != dependent's current epoch) read
+            # the null row, whose words are always 0 (version-consistent
+            # edges, Computed.cs:213-215). Slot-major: [k, d-c] is how the
+            # tables lie on the chip (the nodes along the lanes), and the
+            # fetched block then folds over its MAJOR axis, slab by slab
+            eff = jnp.where(epochs == own[:, None], rows, n_tot).T
+            f = packed[eff >> shift]  # (k, d-c, lanes): whole-row gather
+            if P > 1:
+                hit = (eff & (P - 1))[:, :, None] == lane_group
+                f = jnp.where(hit, f, 0)
+            fire = _or_over(f, 0)  # (d-c, lanes)
+            if P > 1:
+                # every group <- OR of all groups, then keep the own one
+                s = lanes // 2
+                while s >= wp:
+                    fire = fire | jnp.roll(fire, s, axis=1)
+                    s //= 2
+                # a boundary that is no multiple of P: widen to whole rows,
+                # the lanes outside [c, d) fire nothing
+                fire = jnp.pad(fire, ((c % P, -d % P), (0, 0)))
+                fire = fire.reshape(-1, P, lanes)
+                fire = _or_over(jnp.where(own_group, fire, 0), 1)
+            r0 = c // P
+            cur = lax.slice(packed, (r0, 0), (r0 + fire.shape[0], lanes))
+            packed = lax.dynamic_update_slice(packed, cur | fire, (r0, 0))
+    return packed
+
+
 def _topo_sweep_impl(
     level_starts, garrays: TopoGraphArrays, seed_bits, state: TopoState,
     start_level: int = 1,
 ):
+    """The sweep as a program of its own: ``[n_tot+1]`` / ``[n_tot+1, W]``
+    bits cross the program boundary, packed on entry and unpacked on exit
+    (the fused programs below never leave the packed form)."""
     import jax.numpy as jnp
     from jax import lax
 
-    in_src, edge_epoch, is_real = garrays
-    n_tot = in_src.shape[0] - 1
-    k = in_src.shape[1]
-
+    n_tot = garrays.in_src.shape[0] - 1
     node_epoch, invalid = state.node_epoch, state.invalid_bits
-    # normalize to [n_tot+1, W]: W uint32 lanes = 32*W packed waves per pass
     squeeze = invalid.ndim == 1
     if squeeze:
         invalid = invalid[:, None]
@@ -280,42 +425,18 @@ def _topo_sweep_impl(
             f"seed_bits width {seed_bits.shape[1]} != state width {W}; "
             f"pass words= consistently to topo_seeds_to_bits/build_topo_wave32"
         )
-    invalid_before = invalid
-    invalid = (invalid | seed_bits).at[n_tot].set(0)
-
-    # one pass, levels ascending: every gather reads only finalized rows.
-    # start_level=1 skips level 0 (no in-edges at build time by definition);
-    # multi-pass sweeps over PATCHED mirrors start at 0 — a patched edge
-    # into a level-0 row (any edge into level 0 is a level violation) fires
-    # from the previous pass's finalized state
-    for l in range(start_level, len(level_starts) - 1):
-        a, b = level_starts[l], level_starts[l + 1]
-        if a == b:
-            continue
-        rows = lax.slice(in_src, (a, 0), (b, k))
-        epochs = lax.slice(edge_epoch, (a, 0), (b, k))
-        own = lax.slice(node_epoch, (a,), (b,))
-        # dead edges (captured epoch != dependent's current epoch) read the
-        # null row, whose word is always 0 (version-consistent edges,
-        # Computed.cs:213-215)
-        eff = jnp.where(epochs == own[:, None], rows, n_tot)
-        f = invalid[eff]  # (b-a, k, W) gather from earlier levels
-        fire = f[:, 0]
-        for j in range(1, k):
-            fire = fire | f[:, j]
-        cur = lax.slice(invalid, (a, 0), (b, W))
-        invalid = lax.dynamic_update_slice(invalid, cur | fire, (a, 0))
-
+    packed = _clear_null_row(_pack_rows(invalid | seed_bits), n_tot, W)
+    packed = _sweep_packed(level_starts, garrays, node_epoch, packed, W, start_level)
+    after = _unpack_rows(packed, n_tot, W)
     newly = lax.population_count(
-        jnp.where(is_real[:, None], invalid & ~invalid_before, 0)
+        jnp.where(garrays.is_real[:, None], after & ~invalid, 0)
     )
     # per-WORD counts: one word's count is ≤ 32*n (int32-safe); the total
     # across many packed waves can exceed int32, so callers sum in int64
     counts = newly.sum(axis=0, dtype=jnp.int32)
     if squeeze:
-        invalid = invalid[:, 0]
-        return TopoState(node_epoch, invalid), counts[0]
-    return TopoState(node_epoch, invalid), counts
+        return TopoState(node_epoch, after[:, 0]), counts[0]
+    return TopoState(node_epoch, after), counts
 
 
 @functools.lru_cache(maxsize=8)
@@ -340,13 +461,7 @@ def topo_mirror_gate_step(n_tot: int):
 
     @jax.jit
     def gate(is_real, node_epoch0, perm_clipped, g_invalid, seed_new_ids):
-        blocked = (
-            jnp.where(is_real, g_invalid[perm_clipped], False)
-            .astype(jnp.int32)
-            .at[n_tot]
-            .set(0)
-        )
-        node_epoch = jnp.where(blocked.astype(bool), -3, node_epoch0)
+        node_epoch = _gate_epochs(n_tot, is_real, node_epoch0, perm_clipped, g_invalid)
         # union seeds CONDUCT even when already invalid (see ops/wave.py
         # run_waves_union: an uncascaded columnar mark's declared dependents
         # exist only on device); blocked rows still can't RECEIVE (epoch -3)
@@ -407,31 +522,39 @@ def run_topo_sweep_passes(level_starts, garrays, seed_bits, node_epoch, passes: 
     return state
 
 
-def _sweep_adaptive(level_starts, garrays, seed_bits, state):
-    """Adaptive pass mode (``passes <= 0``, ISSUE 17): one seeded sweep,
-    then extra sweeps under a device-side ``lax.while_loop`` until the
-    invalid bits reach a FIXED POINT. The bits are monotone under OR, so
+def _sweep_schedule(
+    level_starts, garrays, node_epoch, packed, words: int, passes: int
+):
+    """The fused programs' pass schedule over a SEEDED packed state:
+    ``passes`` sweeps (a patched mirror's level-violating edges need one
+    extra pass each), or, for ``passes <= 0`` (adaptive, ISSUE 17), one
+    sweep and then extra sweeps under a device-side ``lax.while_loop``
+    until the bits reach a FIXED POINT. The bits are monotone under OR, so
     termination is guaranteed and the fixed point equals what any fixed
     pass count ≥ the true violation depth computes — the burst stops
     exactly when quiescent instead of paying a worst-case pass schedule
     on every dispatch (the fused-chain analogue of the routed plane's
-    counted quiescence check)."""
+    counted quiescence check). The state is lane-dense, so the loop's
+    carry has no padding to grow."""
     import jax.numpy as jnp
     from jax import lax
 
-    state, _ = _topo_sweep_impl(level_starts, garrays, seed_bits, state, 0)
-    zero_sb = jnp.zeros_like(seed_bits)
+    def sweep(st):
+        return _sweep_packed(level_starts, garrays, node_epoch, st, words, 0)
 
-    def cond(carry):
-        return carry[1]
+    if passes > 0:
+        for _ in range(passes):
+            packed = sweep(packed)
+        return packed
 
     def body(carry):
-        st, _changed = carry
-        st2, _ = _topo_sweep_impl(level_starts, garrays, zero_sb, st, 0)
-        return st2, (st2.invalid_bits != st.invalid_bits).any()
+        st2 = sweep(carry[0])
+        return st2, (st2 != carry[0]).any()
 
-    state, _ = lax.while_loop(cond, body, (state, jnp.array(True)))
-    return state
+    packed, _ = lax.while_loop(
+        lambda carry: carry[1], body, (sweep(packed), jnp.array(True))
+    )
+    return packed
 
 
 def _pack_bool_bits(mask):
@@ -443,30 +566,85 @@ def _pack_bool_bits(mask):
     return pack_bool_bits(mask)
 
 
-def _lane_counts_blocked(newly_bits, W: int, block: int = 1 << 15):
-    """Per-lane popcounts of [rows, W] packed bits in ONE pass over HBM.
+def _lane_counts_blocked(newly_packed, words: int, block: int = 1 << 12):
+    """Per-lane popcounts of the packed ``[R, P * Wp]`` bits in ONE pass
+    over HBM.
 
     The obvious ``stack([((bits[:, w] >> b) & 1).sum() ...])`` emits 32·W
     separate strided reductions which XLA does NOT fuse at scale — at 10M
     rows × W=16 that re-reads the 700 MB bit array hundreds of times
-    (~30 s/burst measured). Here a fori_loop unpacks one [block, W, 32]
-    tile at a time and accumulates [W, 32] partials: total traffic = one
-    read of the bits + a 64 MB transient."""
+    (~30 s/burst measured). Here a fori_loop unpacks one [32, block, 128]
+    tile at a time (the bit index leads, so every vector is a full 128-lane
+    row) and accumulates [32, 128] partials; the ``P`` node groups of a row
+    fold at the end: total traffic = one read of the bits + a 64 MB
+    transient."""
     import jax.numpy as jnp
     from jax import lax
 
-    rows = newly_bits.shape[0]
+    rows, lanes = newly_packed.shape
+    P, wp = _row_geometry(words)
     nb = -(-rows // block)
-    padded = jnp.pad(newly_bits, ((0, nb * block - rows), (0, 0)))
-    shifts = jnp.arange(32, dtype=jnp.int32)[None, None, :]
+    padded = jnp.pad(newly_packed, ((0, nb * block - rows), (0, 0)))
+    shifts = jnp.arange(32, dtype=jnp.int32)[:, None, None]
 
     def body(i, acc):
-        blk = lax.dynamic_slice(padded, (i * block, 0), (block, W))
-        bits = (blk[:, :, None] >> shifts) & 1
-        return acc + bits.sum(axis=0, dtype=jnp.int32)
+        blk = lax.dynamic_slice(padded, (i * block, 0), (block, lanes))
+        bits = (blk[None, :, :] >> shifts) & 1
+        return acc + bits.sum(axis=1, dtype=jnp.int32)
 
-    acc = lax.fori_loop(0, nb, body, jnp.zeros((W, 32), jnp.int32))
-    return acc.reshape(W * 32)  # lane l = word l//32, bit l%32 — stack order
+    acc = lax.fori_loop(0, nb, body, jnp.zeros((32, lanes), jnp.int32))
+    per_word = acc.reshape(32, P, wp).sum(axis=1)[:, :words]  # [bit, word]
+    return per_word.T.reshape(words * 32)  # lane l = word l//32, bit l%32
+
+
+def _lanes_finish(packed, n_tot: int, words: int, is_real, perm_clipped, g_invalid):
+    """The lane bursts' shared epilogue on the packed final bits: per-lane
+    closure popcounts and the newly-union scattered back into the dense
+    invalid array. A conducting already-invalid seed is not NEWLY in any
+    lane (same rule as the union finish). Returns ``(g_invalid2,
+    lane_counts int32[32*words], newly_dense bool[dense])``."""
+    import jax.numpy as jnp
+
+    P, wp = _row_geometry(words)
+    rows = packed.shape[0]
+    keep = is_real & ~g_invalid[perm_clipped]
+    keep_rows = jnp.pad(keep, (0, rows * P - n_tot - 1)).reshape(rows, P)
+    newly_bits = jnp.where(jnp.repeat(keep_rows, wp, axis=1), packed, 0)
+    lane_counts = _lane_counts_blocked(newly_bits, words)
+    union = (newly_bits != 0).reshape(rows, P, wp).any(axis=2)
+    union = union.reshape(rows * P)[: n_tot + 1]
+    oob = g_invalid.shape[0]
+    newly_dense = (
+        jnp.zeros_like(g_invalid)
+        .at[jnp.where(union, perm_clipped, oob)]
+        .set(True, mode="drop")
+    )
+    return g_invalid | newly_dense, lane_counts, newly_dense
+
+
+def _gate_epochs(n_tot: int, is_real, node_epoch0, perm_clipped, g_invalid):
+    """Dense-BFS gate through the sweep's own epoch machinery (see
+    :func:`topo_mirror_gate_step`): an already-invalid row gets epoch -3,
+    so none of its in-edges version-match."""
+    import jax.numpy as jnp
+
+    blocked = jnp.where(is_real, g_invalid[perm_clipped], False).at[n_tot].set(False)
+    return jnp.where(blocked, -3, node_epoch0)
+
+
+def _lane_seed_words(seed_new_ids, words: int, slot_words: int):
+    """``(flat, vals)`` of the lane seeds' scatter-add: group g seeds word
+    ``g // 32`` bit ``g % 32`` of its ids; ``flat`` is the row-major offset
+    ``id * slot_words + word`` (of ``[n_tot+1, W]`` with ``slot_words = W``,
+    of the packed state with ``slot_words = Wp``)."""
+    import jax.numpy as jnp
+
+    lanes = jnp.arange(32 * words, dtype=jnp.int32)
+    # lane 31 wraps negative: same bit pattern
+    bit_of = jnp.left_shift(jnp.int32(1), lanes % 32)
+    flat = seed_new_ids * slot_words + (lanes // 32)[:, None]
+    vals = jnp.broadcast_to(bit_of[:, None], seed_new_ids.shape)
+    return flat.ravel(), vals.ravel()
 
 
 @functools.lru_cache(maxsize=8)
@@ -487,25 +665,15 @@ def topo_mirror_fused_union_step(
     @jax.jit
     def burst(garrays: TopoGraphArrays, node_epoch0, perm_clipped, g_invalid, seed_new_ids):
         is_real = garrays.is_real
-        blocked = (
-            jnp.where(is_real, g_invalid[perm_clipped], False)
-            .astype(jnp.int32)
-            .at[n_tot]
-            .set(0)
-        )
-        node_epoch = jnp.where(blocked.astype(bool), -3, node_epoch0)
+        node_epoch = _gate_epochs(n_tot, is_real, node_epoch0, perm_clipped, g_invalid)
         seed_bits = (
             jnp.zeros(n_tot + 1, jnp.int32).at[seed_new_ids].set(1).at[n_tot].set(0)
         )
-        state = TopoState(node_epoch, jnp.zeros(n_tot + 1, dtype=jnp.int32))
-        if passes <= 0:
-            state = _sweep_adaptive(level_starts, garrays, seed_bits, state)
-        else:
-            sb = seed_bits
-            for _ in range(passes):
-                state, _ = _topo_sweep_impl(level_starts, garrays, sb, state, 0)
-                sb = jnp.zeros_like(seed_bits)  # only the first pass seeds
-        newly = state.invalid_bits.astype(bool) & is_real & ~g_invalid[perm_clipped]
+        packed = _sweep_schedule(
+            level_starts, garrays, node_epoch, _pack_rows(seed_bits), 1, passes
+        )
+        final_bits = _unpack_rows(packed, n_tot, 1)[:, 0]
+        newly = final_bits.astype(bool) & is_real & ~g_invalid[perm_clipped]
         count = newly.sum(dtype=jnp.int32)
         pos = jnp.cumsum(newly.astype(jnp.int32)) - 1
         scatter_pos = jnp.where(newly & (pos < cap), pos, cap)
@@ -561,49 +729,18 @@ def _lanes_stage_body(
     newly accounting. Returns (g_invalid2, lane_counts, newly_dense)."""
     import jax.numpy as jnp
 
-    L = 32 * W
     is_real = garrays.is_real
-    blocked = (
-        jnp.where(is_real, g_invalid[perm_clipped], False)
-        .astype(jnp.int32)
-        .at[n_tot]
-        .set(0)
-    )
-    node_epoch = jnp.where(blocked.astype(bool), -3, node_epoch0)
-    lanes = jnp.arange(L, dtype=jnp.int32)
-    word_of = lanes // 32
-    bit_of = jnp.left_shift(jnp.int32(1), lanes % 32)
-    flat = seed_new_ids * W + word_of[:, None]
-    vals = jnp.broadcast_to(bit_of[:, None], seed_new_ids.shape)
-    seed_bits = (
-        jnp.zeros((n_tot + 1) * W, jnp.int32)
-        .at[flat.ravel()]
-        .add(vals.ravel())
-        .reshape(n_tot + 1, W)
-        .at[n_tot]
-        .set(0)
-    )
-    state = TopoState(node_epoch, jnp.zeros((n_tot + 1, W), dtype=jnp.int32))
-    if passes <= 0:
-        state = _sweep_adaptive(level_starts, garrays, seed_bits, state)
-    else:
-        sb = seed_bits
-        for _ in range(passes):
-            state, _ = _topo_sweep_impl(level_starts, garrays, sb, state, 0)
-            sb = jnp.zeros_like(seed_bits)  # only the first pass seeds
-    newly_bits = jnp.where(
-        is_real[:, None] & ~g_invalid[perm_clipped][:, None],
-        state.invalid_bits, 0,
-    )
-    lane_counts = _lane_counts_blocked(newly_bits, W)
-    union = (newly_bits != 0).any(axis=1)
-    oob = g_invalid.shape[0]
-    newly_dense = (
-        jnp.zeros_like(g_invalid)
-        .at[jnp.where(union, perm_clipped, oob)]
-        .set(True, mode="drop")
-    )
-    return g_invalid | newly_dense, lane_counts, newly_dense
+    node_epoch = _gate_epochs(n_tot, is_real, node_epoch0, perm_clipped, g_invalid)
+    # the seeds scatter straight into the packed state: id * Wp + word is
+    # its row-major offset, so the reshape is a bitcast
+    P, wp = _row_geometry(W)
+    rows = -(-(n_tot + 1) // P)
+    flat, vals = _lane_seed_words(seed_new_ids, W, wp)
+    packed = jnp.zeros(rows * P * wp, jnp.int32).at[flat].add(vals)
+    # pad seeds name the null row
+    packed = _clear_null_row(packed.reshape(rows, P * wp), n_tot, W)
+    packed = _sweep_schedule(level_starts, garrays, node_epoch, packed, W, passes)
+    return _lanes_finish(packed, n_tot, W, is_real, perm_clipped, g_invalid)
 
 
 @functools.lru_cache(maxsize=8)
@@ -708,12 +845,13 @@ def topo_mirror_superround_step(
             )
 
         # unroll=True: as a while loop, XLA:TPU lays the loop's view of
-        # the [n_tot, k] mirror tables and the [n_tot, words] sweep state
-        # out row-major with the minor dim padded to 128 lanes (21x and 8x
-        # expansion): three 5.25 GB temps at 10 M nodes, a compile-time
-        # HBM OOM on a 16 GB v5e at any depth > 1. Unrolled, the rounds
-        # compile as straight-line code with the compact layouts the
-        # single-round program gets (measured on the chip, PERF.md).
+        # the [n_tot, k] mirror tables out row-major with the minor dim
+        # padded to 128 lanes (21x: 5.25 GB temps at 10 M nodes, a
+        # compile-time HBM OOM on a 16 GB v5e at any depth > 1, PR 21).
+        # Unrolled, the rounds compile as straight-line code and the
+        # tables keep their compact layout, the nodes along the lanes.
+        # The sweep state no longer has a layout to lose: it is lane-dense
+        # (:func:`_row_geometry`) in or out of a loop.
         (inv_f, values_f, valid_f), (lane_counts, packed) = lax.scan(
             round_step, (g_invalid, values, valid_dev), seed_mats,
             unroll=True,
@@ -738,26 +876,16 @@ def topo_mirror_gate_lanes_step(n_tot: int, words: int):
     import jax.numpy as jnp
 
     W = words
-    L = 32 * W
 
     @jax.jit
     def gate(is_real, node_epoch0, perm_clipped, g_invalid, seed_new_ids):
-        blocked = (
-            jnp.where(is_real, g_invalid[perm_clipped], False)
-            .astype(jnp.int32)
-            .at[n_tot]
-            .set(0)
-        )
-        node_epoch = jnp.where(blocked.astype(bool), -3, node_epoch0)
-        lanes = jnp.arange(L, dtype=jnp.int32)
-        word_of = lanes // 32
-        bit_of = jnp.left_shift(jnp.int32(1), lanes % 32)  # lane 31 wraps negative: same bit pattern
-        flat = seed_new_ids * W + word_of[:, None]  # row-major [n_tot+1, W] index
-        vals = jnp.broadcast_to(bit_of[:, None], seed_new_ids.shape)
+        node_epoch = _gate_epochs(n_tot, is_real, node_epoch0, perm_clipped, g_invalid)
+        # within-lane unique ⇒ add ≡ or (disjoint bits across lanes)
+        flat, vals = _lane_seed_words(seed_new_ids, W, W)
         seed_bits = (
             jnp.zeros((n_tot + 1) * W, jnp.int32)
-            .at[flat.ravel()]
-            .add(vals.ravel())  # within-lane unique ⇒ add ≡ or (disjoint bits across lanes)
+            .at[flat]
+            .add(vals)
             .reshape(n_tot + 1, W)
             .at[n_tot]
             .set(0)
@@ -784,21 +912,10 @@ def topo_mirror_finish_lanes_step(n_tot: int, words: int):
 
     @jax.jit
     def finish(is_real, perm_clipped, g_invalid, final_bits):
-        # ~pre-invalid: a conducting already-invalid seed is not NEWLY in
-        # any lane (same rule as the union finish)
-        newly_bits = jnp.where(
-            is_real[:, None] & ~g_invalid[perm_clipped][:, None], final_bits, 0
+        g_invalid2, lane_counts, newly_dense = _lanes_finish(
+            _pack_rows(final_bits), n_tot, W, is_real, perm_clipped, g_invalid
         )
-        lane_counts = _lane_counts_blocked(newly_bits, W)  # one-pass popcounts
-        union = (newly_bits != 0).any(axis=1)
-        union_count = union.sum(dtype=jnp.int32)
-        oob = g_invalid.shape[0]
-        newly_dense = (
-            jnp.zeros_like(g_invalid)
-            .at[jnp.where(union, perm_clipped, oob)]
-            .set(True, mode="drop")
-        )
-        g_invalid2 = g_invalid | newly_dense
+        union_count = newly_dense.sum(dtype=jnp.int32)
         return g_invalid2, lane_counts, union_count, _pack_bool_bits(newly_dense)
 
     return finish

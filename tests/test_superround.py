@@ -446,3 +446,99 @@ async def test_routed_superround_zero_host_relay_reentries():
         assert table_a.stale_count() == 0
     finally:
         set_default_hub(old)
+
+
+# ------------------------------------------------------- lane-dense sweep state
+def wide_bursts(k, groups=260, seeds=2):
+    """Rounds of more than 256 groups: 16 words a node, 8 nodes a state row."""
+    rng = np.random.default_rng(20261001)
+    return [
+        [rng.choice(N, size=seeds, replace=False).tolist() for _ in range(groups)]
+        for _ in range(k)
+    ]
+
+
+async def test_depth3_superround_at_16_words_matches_sequential_pairs():
+    """A depth-3 super-round at ``words=16`` (the benchmark's geometry: the
+    sweep state packs 8 nodes into each 128-lane row) ≡ three sequential
+    (lane burst → refresh) pairs: per-group counts, invalid mask, memo
+    columns, fence sets."""
+    bursts = wide_bursts(3)
+
+    hub_a, b_a, _s, table_a, blk_a = make_stack()
+    old = set_default_hub(hub_a)
+    try:
+        fences_a = fence_collector(b_a)
+        prog = b_a.enable_super_rounds(blk_a, depth=3, max_words=16)
+        staged = prog.stage(bursts)
+        assert staged.words == 16
+        per_burst = prog.dispatch(staged).harvest()
+        assert prog.superrounds_dispatched == 1
+        assert prog.eager_rounds == 0 and prog.faults == 0
+        assert b_a.graph.sweep_packed_dispatches == 1
+
+        hub_b, b_b, _s2, table_b, blk_b = make_stack()
+        set_default_hub(hub_b)
+        fences_b = fence_collector(b_b)
+        for i, groups in enumerate(bursts):
+            counts = b_b.cascade_rows_lanes(blk_b, groups)
+            assert per_burst[i].tolist() == counts.tolist(), i
+            assert int(counts.sum()) > 0
+            b_b.refresh_block_on_device(blk_b)
+        assert b_b.graph.sweep_packed_dispatches == 3
+
+        assert np.array_equal(b_a.graph.invalid_mask(), b_b.graph.invalid_mask())
+        assert np.array_equal(
+            np.asarray(table_a._values), np.asarray(table_b._values)
+        )
+        assert table_a.stale_count() == table_b.stale_count()
+        assert [ids for _seq, ids in fences_a] == [ids for _seq, ids in fences_b]
+    finally:
+        set_default_hub(old)
+
+
+async def test_sweep_packed_dispatches_counts_every_sweep_program_call():
+    """``DeviceGraph.sweep_packed_dispatches`` = sweep programs dispatched:
+    one per fused union, lane burst, chain batch or super-round, one per pass
+    on the split pipeline, none for a lat-served union; exported as
+    ``fusion_sweep_packed_dispatches_total`` and named in the warm report by
+    its ``nodes_per_row``."""
+    from stl_fusion_tpu.graph.program_cache import (
+        program_warm_report,
+        time_program_warm,
+    )
+
+    hub, backend, _s, _table, block = make_stack()
+    old = set_default_hub(hub)
+    try:
+        g = backend.graph
+        assert g.sweep_packed_dispatches == 0
+        rng = np.random.default_rng(5)
+        g.run_waves_union([[int(rng.integers(N))]])  # lat-served: no sweep
+        assert g.lat_waves == 1 and g.sweep_packed_dispatches == 0
+        with time_program_warm("sweep-test-union"):
+            g.run_waves_union([rng.choice(N, size=300, replace=False).tolist()])
+        assert g.sweep_packed_dispatches == 1  # past LAT_SEED_MAX: fused union
+        g.run_waves_lanes(round_bursts(1)[0])
+        assert g.sweep_packed_dispatches == 2
+        with time_program_warm("sweep-test-chain"):
+            g.run_waves_lanes_chain(wide_bursts(g.FUSE_CHAIN_MAX + 2))
+        assert g.sweep_packed_dispatches == 4  # ceil(10 / FUSE_CHAIN_MAX) batches
+        prog = backend.enable_super_rounds(block, depth=2)
+        prog.dispatch(prog.stage(round_bursts(2))).harvest()
+        assert g.sweep_packed_dispatches == 5
+        # a mirror carrying more passes than the fused programs serve runs
+        # the split pipeline: one sweep program call per pass
+        g._topo_mirror["passes"] = g.FUSED_PASS_MAX + 1
+        g.run_waves_lanes(round_bursts(1)[0])
+        assert g.sweep_packed_dispatches == 5 + g.FUSED_PASS_MAX + 1
+        dispatched = g.sweep_packed_dispatches
+        assert (
+            backend._collect_metrics()["fusion_sweep_packed_dispatches_total"]
+            == dispatched
+        )
+        report = program_warm_report()
+        assert report["sweep-test-union"]["nodes_per_row"] == [1]  # one word
+        assert report["sweep-test-chain"]["nodes_per_row"] == [8]  # 16 words
+    finally:
+        set_default_hub(old)

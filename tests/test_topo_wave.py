@@ -5,6 +5,7 @@ the level renumbering round-trips ids and that the native Kahn level pass
 agrees with the numpy relaxation.
 """
 import numpy as np
+import pytest
 
 from stl_fusion_tpu.graph.synthetic import power_law_dag
 from stl_fusion_tpu.ops.topo_wave import (
@@ -214,3 +215,143 @@ def test_empty_graph_builds_trivially():
     dg.build_topo_mirror()  # no nodes yet: must not raise
     counts, union_mask = dg.run_waves_lanes([[]])
     assert counts.tolist() == [0] and not union_mask.any()
+
+
+# ------------------------------------------------- lane-dense (packed) sweep
+def _gated_closure(adj, seeds, pre_invalid):
+    """Dense-BFS closure of one lane group: seeds conduct even when already
+    invalid, any other already-invalid node neither fires nor conducts."""
+    seen = set(int(s) for s in seeds)
+    stack = list(seen)
+    while stack:
+        u = stack.pop()
+        for v in adj.get(u, ()):
+            if v not in seen and v not in pre_invalid:
+                seen.add(v)
+                stack.append(v)
+    return seen - pre_invalid
+
+
+def _patched_mirror(n, quantize, n_late, rng):
+    """A mirror built from a random DAG less ``n_late`` edges, which are then
+    spliced into free slack slots the way the live patcher does. Every late
+    edge runs from a node of a level at or above its target's level (a level
+    violation: one extra sweep pass each), and no two of them chain."""
+    pairs = sorted({(int(a), int(b)) for a, b in zip(
+        rng.integers(0, n - 1, 3 * n), rng.integers(1, n, 3 * n)) if a < b})
+    src = np.array([p[0] for p in pairs], dtype=np.int32)
+    dst = np.array([p[1] for p in pairs], dtype=np.int32)
+    graph = build_topo_graph(src, dst, n, k=4, quantize=quantize, slack=2)
+    starts = np.asarray(graph.level_starts)
+    level_of = lambda old: int(np.searchsorted(starts, graph.inv_perm[old], "right")) - 1
+    in_src = graph.in_src.copy()
+    late, used = [], set()
+    for u in rng.permutation(n):
+        if len(late) == n_late:
+            break
+        u = int(u)
+        # a LATER node (so the edge keeps the DAG acyclic: ids ascend along
+        # every edge) that sits in a level no higher than u's
+        cands = [v for v in range(u + 1, n)
+                 if level_of(v) <= level_of(u) and v not in used and u not in used]
+        if not cands:
+            continue
+        v = int(cands[0])
+        row = int(graph.inv_perm[v])
+        free = np.nonzero(in_src[row] == graph.n_tot)[0]
+        in_src[row, free[0]] = graph.inv_perm[u]
+        late.append((u, v))
+        used.update((u, v))
+    assert len(late) == n_late
+    all_src = np.concatenate([src, np.array([p[0] for p in late], dtype=np.int32)])
+    all_dst = np.concatenate([dst, np.array([p[1] for p in late], dtype=np.int32)])
+    return graph, in_src, all_src, all_dst
+
+
+@pytest.mark.parametrize("passes", [1, 2, 0])
+@pytest.mark.parametrize("quantize", [True, False])
+@pytest.mark.parametrize("words", [1, 2, 8, 16, 32])
+def test_packed_lane_burst_matches_oracle(words, quantize, passes):
+    """The lane-dense sweep (``128 // Wp`` nodes to a state row from 8 words
+    on, one node a row below) against the host closure of every lane group:
+    at every width, with level boundaries
+    that are and are not multiples of the nodes per row, on a patched mirror
+    at a fixed pass count and adaptive, with pre-invalid nodes gating, and
+    with the null row inside a state row it shares with real nodes. The split
+    pipeline (bits packed on entry, unpacked on exit) must agree."""
+    import jax.numpy as jnp
+
+    from stl_fusion_tpu.ops.topo_wave import (
+        TopoGraphArrays,
+        _row_geometry,
+        run_topo_sweep_passes,
+        topo_mirror_finish_lanes_step,
+        topo_mirror_fused_lanes_step,
+        topo_mirror_gate_lanes_step,
+    )
+
+    rng = np.random.default_rng([words, quantize, passes])
+    n_late = {1: 0, 2: 1, 0: 3}[passes]
+    for n in range(301, 340):
+        graph, in_src, src, dst = _patched_mirror(n, quantize, n_late, rng)
+        if quantize or graph.n_tot % 2:
+            break  # an odd row count: the null row shares its state row
+    n_tot = graph.n_tot
+    P = _row_geometry(words)[0]
+    if not quantize and P > 1:
+        assert n_tot % P, "the null row must share a state row with real nodes"
+        assert any(s % P for s in graph.level_starts), "no unaligned boundary"
+
+    adj = {}
+    for s, d in zip(src, dst):
+        adj.setdefault(int(s), []).append(int(d))
+    pre_invalid = set(rng.choice(n, size=6, replace=False).tolist())
+    n_groups = 32 * words - 3  # the last lanes stay empty
+    groups = [rng.choice(n, size=2, replace=False).tolist() for _ in range(n_groups)]
+    seed_mat = np.full((32 * words, 2), n_tot, dtype=np.int32)
+    for g, seeds in enumerate(groups):
+        seed_mat[g] = graph.inv_perm[seeds]
+
+    garrays = TopoGraphArrays(
+        jnp.asarray(in_src),
+        jnp.where(jnp.asarray(in_src) != n_tot, 0, -1).astype(jnp.int32),
+        jnp.asarray(graph.is_real),
+    )
+    node_epoch0 = jnp.zeros(n_tot + 1, dtype=jnp.int32).at[n_tot].set(-2)
+    perm_clipped = jnp.asarray(np.clip(graph.perm, 0, n).astype(np.int32))
+    g_invalid = np.zeros(n + 1, dtype=bool)
+    g_invalid[list(pre_invalid)] = True
+    g_invalid = jnp.asarray(g_invalid)
+
+    g_invalid2, lane_counts, union_count, packed = topo_mirror_fused_lanes_step(
+        graph.level_starts, n_tot, words, passes
+    )(garrays, node_epoch0, perm_clipped, g_invalid, jnp.asarray(seed_mat))
+
+    closures = [_gated_closure(adj, seeds, pre_invalid) for seeds in groups]
+    lane_counts = np.asarray(lane_counts)
+    assert lane_counts.shape == (32 * words,)
+    assert lane_counts[:n_groups].tolist() == [len(c) for c in closures]
+    assert not lane_counts[n_groups:].any()
+    union = set().union(*closures)
+    assert int(union_count) == len(union)
+    got = np.unpackbits(
+        np.asarray(packed).view(np.uint8), count=n + 1, bitorder="little"
+    ).astype(bool)
+    assert set(np.nonzero(got)[0].tolist()) == union
+    assert set(np.nonzero(np.asarray(g_invalid2))[0].tolist()) == union | pre_invalid
+
+    if passes == 2:  # the split pipeline: [n_tot+1, W] bits between programs
+        node_epoch, seed_bits = topo_mirror_gate_lanes_step(n_tot, words)(
+            garrays.is_real, node_epoch0, perm_clipped, g_invalid, jnp.asarray(seed_mat)
+        )
+        assert seed_bits.shape == (n_tot + 1, words)
+        state = run_topo_sweep_passes(
+            graph.level_starts, garrays, seed_bits, node_epoch, passes
+        )
+        assert state.invalid_bits.shape == (n_tot + 1, words)
+        split = topo_mirror_finish_lanes_step(n_tot, words)(
+            garrays.is_real, perm_clipped, g_invalid, state.invalid_bits
+        )
+        assert np.array_equal(np.asarray(split[1]), lane_counts)
+        assert np.array_equal(np.asarray(split[0]), np.asarray(g_invalid2))
+        assert np.array_equal(np.asarray(split[3]), np.asarray(packed))
