@@ -437,8 +437,9 @@ async def serve_leg(args, out: dict) -> None:
         # ---- (c) writes end to end, observed by an RPC client
         note("serve: writes through the commander to an RPC client...")
         with time_program_warm("wave_chain", key=(n, MAX_WORDS, 2)):
-            # the pipeline's fused chain program at the depth the two
-            # command waves below will ride
+            # the pipeline's path for the two command waves below: small
+            # waves ride the lat program the lone leg compiled, so this
+            # warms nothing new unless the lat mirror declines them
             pipe.submit_rows(block, [n - 1])
             pipe.submit_rows(block, [n - 2])
             pipe.drain()
@@ -472,7 +473,7 @@ async def serve_leg(args, out: dict) -> None:
         commander = ClusterCommander(
             hub.commander, member_id="m0", log_store=log_store
         )
-        fused0 = pipe.stats()["fused_dispatches"]
+        routed0 = pipe.stats()
         t0 = time.perf_counter()
         deltas = {writers[0]: 7.0, writers[1]: 11.0}
         for i, (w, delta) in enumerate(deltas.items()):
@@ -492,13 +493,23 @@ async def serve_leg(args, out: dict) -> None:
         journaled = [log_store.contains(f"smoke-op-{i}") for i in range(len(deltas))]
         if not all(journaled):
             write_bad.append(f"op-log is missing a write: {journaled}")
-        if pipe.stats()["fused_dispatches"] == fused0:
-            write_bad.append("the command waves never rode a fused dispatch")
+        routed = pipe.stats()
+        lat_served = (routed["lat_waves"] + routed["lat_overflow_waves"]
+                      - routed0["lat_waves"] - routed0["lat_overflow_waves"])
+        if routed["eager_waves"] != routed0["eager_waves"] or not (
+            lat_served == len(deltas)
+            or routed["fused_dispatches"] > routed0["fused_dispatches"]
+        ):
+            write_bad.append(
+                "the command waves rode neither the lat mirror nor a fused "
+                f"dispatch (before {routed0}, after {routed})"
+            )
         if write_bad:
             problems.append("serve: write leg: " + "; ".join(write_bad))
         out["write"] = {
             "commands": len(deltas), "subscribed_keys": len(keys),
             "journaled": all(journaled),
+            "waves_lat_served": lat_served,
             "command_to_all_visible_ms": round(visible_ms, 2),
             "client_equals_store": not write_bad,
         }

@@ -691,6 +691,7 @@ async def main() -> None:
         await settle(0.01)
         pipe.drain()  # start from an empty pipeline
         fused_before = pipe.stats()["fused_dispatches"]
+        lat_before = pipe.stats()["lat_waves"] + pipe.stats()["lat_overflow_waves"]
         probe_carts = subscribed[: min(6, len(subscribed))] or [0, 1]
         for j, c in enumerate(probe_carts):
             val = await client_cc.call(CartAdd(int(c), 1), operation_id=f"op-probe-{j}")
@@ -702,10 +703,18 @@ async def main() -> None:
         pipe.drain()
         cluster.reconcile()
         fused_delta = pipe.stats()["fused_dispatches"] - fused_before
-        require(fused_delta > 0, "probe waves never fused into a chain")
+        lat_delta = (
+            pipe.stats()["lat_waves"] + pipe.stats()["lat_overflow_waves"] - lat_before
+        )
+        # small command waves ride the lat kernel, the rest one fused chain
+        require(
+            fused_delta > 0 or lat_delta == len(probe_carts),
+            "probe waves neither fused into a chain nor rode the lat mirror",
+        )
         drain_on.set()
         results["fusion"] = {"probe_waves": len(probe_carts),
-                             "fused_dispatches": int(fused_delta)}
+                             "fused_dispatches": int(fused_delta),
+                             "lat_waves": int(lat_delta)}
 
         # ================================================== final audits
         note("final oracle + exposition audit...")

@@ -39,6 +39,7 @@ from typing import TYPE_CHECKING, Any, Optional
 from ..core.context import try_capture
 from ..diagnostics.flight_recorder import RECORDER, call_key
 from ..diagnostics.metrics import global_metrics
+from ..diagnostics.tracing import hot_span
 from ..utils.errors import ExceptionInfo
 from ..utils.ltag import LTag
 from ..utils.serialization import dumps, loads
@@ -243,6 +244,10 @@ class RpcInboundComputeCall(RpcInboundCall):
         self._invalidation_pushed = False
 
     async def _run(self) -> None:
+        with hot_span("rpc.compute_call"):  # a client's read or re-read, served
+            await self._serve()
+
+    async def _serve(self) -> None:
         try:
             computed = await self._capture_target()
         except asyncio.CancelledError:

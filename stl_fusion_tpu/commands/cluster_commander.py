@@ -36,6 +36,7 @@ cluster-native:
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
 import time
 import uuid
@@ -43,7 +44,7 @@ from dataclasses import dataclass
 from typing import Any, List, Optional, Tuple
 
 from ..diagnostics.metrics import global_metrics
-from ..diagnostics.tracing import get_activity_source, span_cause_id
+from ..diagnostics.tracing import get_activity_source, hot_span, span_cause_id
 from ..utils.collections import RecentlySeenMap
 from ..utils.serialization import wire_type
 from .rpc_bridge import COMMANDER_SERVICE
@@ -164,9 +165,12 @@ class ClusterCommander:
         (or supplied by a client that wants its own idempotency token) and
         pinned across every retry — that constant is what makes the whole
         retry ladder exactly-once."""
+        with hot_span("cmd.call"):
+            return await self._call(command, operation_id or uuid.uuid4().hex)
+
+    async def _call(self, command: Any, op_id: str) -> Any:
         from ..cluster.shard_map import ShardMovedError
 
-        op_id = operation_id or uuid.uuid4().hex
         attempts = 0
         while True:
             try:
@@ -318,24 +322,27 @@ class ClusterCommander:
         ) as span:
             cause = span_cause_id(span)
             global_mesh_trace().note_command(cause, label)
-            with pinned_operation_scope(operation_id, cause):
-                if pipeline is not None:
-                    # completion's invalidation replay COLLECTS its hits
-                    # instead of cascading host-side; the collected seeds
-                    # ride the nonblocking pipeline below and fuse into
-                    # whatever chain/super-round is already in flight
-                    with batch_cascade_scope(groups.append):
-                        result = await self.commander.call(command)
-                else:
-                    result = await self.commander.call(command)
+            # with a pipeline, completion's invalidation replay COLLECTS its
+            # hits instead of cascading host-side; the collected seeds ride
+            # the nonblocking pipeline below (a small wave through the lat
+            # kernel, anything else fused into whatever chain/super-round is
+            # already in flight)
+            collect = (
+                batch_cascade_scope(groups.append)
+                if pipeline is not None
+                else contextlib.nullcontext()
+            )
+            with pinned_operation_scope(operation_id, cause), collect, hot_span("cmd.execute"):
+                result = await self.commander.call(command)
         self._memo.try_add(operation_id, (result,))
         global_metrics().counter(
             "fusion_cmd_local_total",
             "commands applied on this member (owner-local executions)",
         ).inc()
-        seeds = [c for g in groups if g for c in g]
-        if pipeline is not None and seeds:
-            ticket = pipeline.submit(seeds)
+        with hot_span("cmd.submit"):
+            seeds = [c for g in groups if g for c in g]
+            ticket = pipeline.submit(seeds) if pipeline is not None and seeds else None
+        if ticket is not None:
             self._pending.append((ticket, operation_id, label, t0))
         else:
             # host-side cascade already applied: the write is visible now
@@ -379,10 +386,11 @@ class ClusterCommander:
         """The write-path barrier: flush + harvest the nonblocking pipeline
         (which also drains any resident super-round) and reconcile every
         command ticket. Returns the newly-invalidated count."""
-        pipeline = self._pipeline()
-        newly = pipeline.drain() if pipeline is not None else 0
-        self.reconcile()
-        return newly
+        with hot_span("cmd.drain"):
+            pipeline = self._pipeline()
+            newly = pipeline.drain() if pipeline is not None else 0
+            self.reconcile()
+            return newly
 
 
 class ClusterCommanderFacade:

@@ -1048,8 +1048,13 @@ class TpuGraphBackend:
         holds invalid in this block: values recomputed, rows valid again
         with NO epoch bump (declared topology survives), scalar twins stay
         pending-invalid until their next read — identical to the host
-        path. Rows stale on the TABLE but not invalid in the graph (no
-        such rows arise from wave/icasc flows) refresh on next read."""
+        path. A row re-read through its scalar twin after a wave (a client's
+        re-read of a written row) leaves the graph's invalid set but stays
+        stale on the table, by design: the scalar recompute never writes
+        the columnar cache, and this refresh recomputes exactly the rows
+        the graph holds invalid, so ``valid_mask`` shows that row stale
+        until the table's own next read of it (``read_batch``,
+        ``table.refresh``) recomputes it."""
         with hot_span("refresh"):
             self.flush()
             table = block.table

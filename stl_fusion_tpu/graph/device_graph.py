@@ -637,6 +637,25 @@ class DeviceGraph:
         self._h_invalid |= newly
         return int(count), np.nonzero(newly)[0].astype(np.int32)
 
+    def lat_serves(self, seed_id_lists: Sequence[Sequence[int]]) -> bool:
+        """Would :meth:`run_waves_union` send each of these waves, alone, to
+        the lat mirror right now? The mirror valid (a pending structural
+        delta is patched in here, as any wave would), a lat mirror built,
+        every wave of 1..``LAT_SEED_MAX`` seeds, every seed an id the mirror
+        knows. A wave so admitted may still overflow the lat caps; it then
+        runs on the topo union, as a lone edit does."""
+        if not self._mirror_valid():
+            return False
+        m = self._topo_mirror
+        if m.get("lat") is None:
+            return False
+        m_nodes = m["n_nodes"]
+        return all(
+            0 < len(s) <= self.LAT_SEED_MAX
+            and all(0 <= int(i) < m_nodes for i in s)
+            for s in seed_id_lists
+        )
+
     # ------------------------------------------------------------------ topo mirror
     def _mirror_valid(self) -> bool:
         """Is the cached mirror usable RIGHT NOW? O(1) on a topology the
@@ -916,9 +935,15 @@ class DeviceGraph:
     ):
         """Vectorized lat-mirror half of an add-delta: one new out-slot per
         (u, v, epoch) triple, duplicates dropped, free slots assigned by
-        within-row rank. A full out-row (or unknown node) breaks ONLY the
-        lat mirror — lone waves fall back to the topo sweep while lane
-        bursts keep patching. Returns the lat dict, or None once broken."""
+        within-row rank. A slot is free when it is a pad or DEAD: its real
+        dependent has been bumped past the slot's captured epoch, so it can
+        never fire again (a bump leaves the lat tables alone, and a row
+        whose dependent is recaptured over and over, a written row that
+        its subscribers keep re-reading, would otherwise fill up with dead
+        slots within a few commands). A full out-row (or unknown node)
+        breaks ONLY the lat mirror — lone waves fall back to the topo sweep
+        while lane bursts keep patching. Returns the lat dict, or None once
+        broken."""
         if u64.size == 0:
             return lat
         if int(u64.max()) >= lat["n_real"] or int(v64.max()) >= lat["n_real"]:
@@ -942,7 +967,10 @@ class DeviceGraph:
         grp_start = np.ones(len(u), dtype=bool)
         grp_start[1:] = u[1:] != u[:-1]
         rank = idx - np.maximum.accumulate(np.where(grp_start, idx, 0))
-        free_cum = (hd[u] == ln_tot).cumsum(axis=1)
+        hd_u = hd[u]
+        real = hd_u < lat["n_real"]
+        dead = real & (self._h_node_epoch[np.where(real, hd_u, 0)] != he[u])
+        free_cum = ((hd_u == ln_tot) | dead).cumsum(axis=1)
         need = rank + 1
         if (free_cum[:, -1] < need).any():
             m["lat"] = None  # out-row full: lone waves fall back to the sweep

@@ -48,6 +48,22 @@ its apply carry that seq, and the profiler record notes ``fused_depth`` —
   and ``chain_faults`` counts the incident;
 - while a watchdog is degraded (``mode == "host"``) dispatches run the
   host loop directly and count toward its recovery window.
+
+**Small waves ride the lat kernel** (not a fallback; counted as
+``lat_waves`` / ``lat_overflow_waves``). A fused chain is one whole topo
+sweep per wave whatever its closure: 0.8 s on the 10 M-node DAG for a
+command that touches a handful of rows. So an accumulation the lat mirror
+can serve (the mirror valid, every wave of 1..``LAT_SEED_MAX`` seeds the
+mirror knows, no mesh routing, the watchdog in device mode, no super-round
+in flight) goes wave by wave through the same entry a lone edit takes
+(``TpuGraphBackend._wave_union``: the O(closure) lat program, the topo
+union where a closure overflows its caps), in seq order, each wave applied
+under its own seq. That path BLOCKS for its waves (a couple of
+milliseconds each): there is nothing left in flight to overlap with, and a
+command's visibility waits on exactly this readback. Everything else keeps
+the chain: larger seed sets, an invalid mirror, a routed mesh, and any
+accumulation that arrives while a super-round is in flight, which queues
+behind it on the device instead of stalling the host on its readback.
 """
 from __future__ import annotations
 
@@ -57,6 +73,7 @@ from collections import deque
 from typing import TYPE_CHECKING, Deque, List, Optional, Sequence
 
 from ..diagnostics.metrics import global_metrics
+from ..diagnostics.tracing import hot_span
 
 if TYPE_CHECKING:
     from ..core.computed import Computed
@@ -117,6 +134,8 @@ class WavePipeline:
         self.fused_dispatches = 0
         self.eager_waves = 0  # waves served by per-wave fallback dispatch
         self.chain_faults = 0  # chains contained to the split host loop
+        self.lat_waves = 0  # small waves the lat mirror served
+        self.lat_overflow_waves = 0  # small waves that overflowed into the topo union
         self.harvests = 0
         self.overlap_harvests = 0  # harvests applied with a chain in flight
         self.apply_s_total = 0.0
@@ -131,6 +150,8 @@ class WavePipeline:
             "fusion_pipeline_dispatches_total": self.fused_dispatches,
             "fusion_pipeline_eager_waves_total": self.eager_waves,
             "fusion_pipeline_chain_faults_total": self.chain_faults,
+            "fusion_pipeline_lat_waves_total": self.lat_waves,
+            "fusion_pipeline_lat_overflow_waves_total": self.lat_overflow_waves,
             "fusion_pipeline_pending_waves": len(self._pending),
             "fusion_pipeline_inflight_chains": len(self._inflight),
             "fusion_pipeline_overlap_occupancy": self.overlap_occupancy(),
@@ -183,6 +204,10 @@ class WavePipeline:
         i.e. applying wave N-1's masks while wave N runs on device."""
         if not self._pending:
             return
+        with hot_span("pipeline.dispatch"):
+            self._dispatch()
+
+    def _dispatch(self) -> None:
         waves, self._pending = self._pending, []
         backend = self.backend
         if backend._journal:
@@ -212,6 +237,9 @@ class WavePipeline:
                 wd._check_injected()
         except Exception as e:  # noqa: BLE001
             self._on_chain_fault(e, waves, seqs, cause)
+            return
+        if self._lat_serves(waves):
+            self._run_lat(waves, seqs, cause)
             return
         try:
             if backend.mesh_routing_active():
@@ -271,6 +299,10 @@ class WavePipeline:
 
     # ------------------------------------------------------------------ harvest
     def _harvest(self, ticket: dict) -> None:
+        with hot_span("pipeline.harvest", ticket["seqs"][0]):
+            self._harvest_chain(ticket)
+
+    def _harvest_chain(self, ticket: dict) -> None:
         backend = self.backend
         waves: List[WaveTicket] = ticket["waves"]
         seqs = ticket["seqs"]
@@ -312,6 +344,58 @@ class WavePipeline:
             dispatches=ticket["pending"]["dispatches"],
         )
         self.fused_dispatches += ticket["pending"]["dispatches"]
+
+    # ------------------------------------------------------------------ small waves
+    def _lat_serves(self, waves: List[WaveTicket]) -> bool:
+        """Would the lat mirror serve every wave of this accumulation right
+        now (module docstring)? Read from what the program can see: seed
+        counts, the mirror's state, the mesh, the other plane."""
+        backend = self.backend
+        if backend.mesh_routing_active():
+            return False
+        sr = backend.super_rounds
+        if sr is not None and not sr._disposed and sr._inflight:
+            return False
+        return backend.graph.lat_serves([w.seeds for w in waves])
+
+    def _run_lat(self, waves: List[WaveTicket], seqs, cause) -> None:
+        """The small-wave path: each wave through the lone edit's entry
+        (lat program, topo union on overflow, the watchdog around both), in
+        seq order and applied under its own seq. Blocking."""
+        backend = self.backend
+        dg = backend.graph
+        self.harvest_inflight()  # an earlier chain's waves apply first
+        backend.last_cause_id = cause
+        total = 0
+        t0 = time.perf_counter()
+        try:
+            for i, wave in enumerate(waves):
+                backend.last_wave_seq = seqs[i]
+                served = dg.lat_waves
+                count, ids = backend._wave_union([wave.seeds])
+                if dg.lat_waves != served:
+                    self.lat_waves += 1
+                else:
+                    self.lat_overflow_waves += 1
+                t_apply0 = time.perf_counter()
+                with hot_span("pipeline.harvest", seqs[i], t_apply0):
+                    backend._apply_newly(ids)
+                    wave.cause = cause
+                    wave._resolve(int(count), seqs[i])
+                self.apply_s_total += time.perf_counter() - t_apply0
+                total += int(count)
+        except Exception as e:  # noqa: BLE001 — no watchdog contained it
+            self._on_chain_fault(e, waves[i:], seqs[i:], cause)  # wave i and after
+            return
+        finally:
+            backend.last_wave_seq = seqs[0]
+        t1 = time.perf_counter()
+        backend.waves_run += len(waves)
+        backend.device_invalidations += total
+        backend._profile_wave(
+            "pipeline_lat", sum(len(w.seeds) for w in waves), cause, t0, t1,
+            total, seqs[0], groups=len(waves), seq_span=(seqs[0], seqs[-1]),
+        )
 
     # ------------------------------------------------------------------ fallbacks
     def _run_eager(self, waves, seqs, cause) -> None:
@@ -407,6 +491,8 @@ class WavePipeline:
             "fused_dispatches": self.fused_dispatches,
             "eager_waves": self.eager_waves,
             "chain_faults": self.chain_faults,
+            "lat_waves": self.lat_waves,
+            "lat_overflow_waves": self.lat_overflow_waves,
             "harvests": self.harvests,
             "overlap_harvests": self.overlap_harvests,
             "pending_waves": len(self._pending),

@@ -41,6 +41,7 @@ import contextlib
 import contextvars
 
 from ..core.context import invalidating, is_invalidating
+from ..diagnostics.tracing import hot_span
 from ..utils.collections import RecentlySeenMap
 from ..utils.errors import TransientError
 from .operation import AgentInfo, Completion, Operation
@@ -183,9 +184,11 @@ def attach_operations(commander: "Commander") -> OperationsHost:
         # actual transaction commit — don't overwrite it
         if operation.commit_time is None:
             operation.commit_time = time.time()
-        for listener in list(host.commit_listeners):
-            await listener(operation)
-        await host.notify_completed(operation, is_local=True)
+        with hot_span("cmd.journal"):  # the op-log append: durable before completion
+            for listener in list(host.commit_listeners):
+                await listener(operation)
+        with hot_span("cmd.complete"):  # completion: the invalidation replay
+            await host.notify_completed(operation, is_local=True)
         return result
 
     # -------------------------------------------------------- NestedCommandLogger

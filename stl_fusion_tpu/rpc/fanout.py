@@ -45,6 +45,7 @@ import numpy as np
 
 from ..diagnostics.flight_recorder import RECORDER
 from ..diagnostics.hotkeys import global_hotkeys
+from ..diagnostics.tracing import hot_span
 from ..utils.serialization import dumps
 from .message import CALL_TYPE_COMPUTE, COMPUTE_SYSTEM_SERVICE, RpcMessage
 
@@ -176,6 +177,10 @@ class ComputeFanoutIndex:
             # leave delivery to the per-key invalidation handlers (the
             # pushed-flag is never set, so nothing is lost)
             return
+        with hot_span("fanout.newly"):
+            self._drain_newly(newly)
+
+    def _drain_newly(self, newly) -> None:
         self.waves_seen += 1
         # the wave's identity + apply timestamp: stamped into every posted
         # entry so the client fence links back to this wave and the e2e

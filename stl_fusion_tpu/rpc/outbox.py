@@ -40,6 +40,7 @@ import time
 from collections import deque
 from typing import TYPE_CHECKING, Deque, Dict, Optional, Tuple
 
+from ..diagnostics.tracing import hot_span
 from ..utils.serialization import dumps
 from .message import CALL_TYPE_COMPUTE, COMPUTE_SYSTEM_SERVICE, RpcMessage
 
@@ -202,30 +203,37 @@ class PeerOutbox:
                         # draining now would interleave with it. Its
                         # finally-block re-kicks us once it clears.
                         break
-                    # snapshot length: entries appended mid-tick go next
-                    # tick, so a hot FIFO can never starve the batch flush
-                    for _ in range(len(self._fifo)):
-                        message, future = self._fifo.popleft()
-                        self._in_flight = True
-                        try:
-                            await peer._send_now(message)
-                        except asyncio.CancelledError:
-                            if future is not None and not future.done():
-                                future.cancel()
-                            raise
-                        except BaseException as e:  # noqa: BLE001
-                            if future is not None and not future.done():
-                                future.set_exception(e)
-                            else:  # pragma: no cover — all entries carry futures
-                                log.debug("outbox %s: dropped send: %s", peer.ref, e)
-                        else:
-                            self.messages_sent += 1
-                            if future is not None and not future.done():
-                                future.set_result(None)
-                        finally:
-                            self._in_flight = False
-                    if self._pending_inval:
-                        await self._flush_invalidations()
+                    if self._pending_since is not None:
+                        # how long the oldest invalidation sat coalescing
+                        # before this tick took it: closed at once, its
+                        # start is the post's own clock reading
+                        with hot_span("outbox.wait", start=self._pending_since):
+                            pass
+                    with hot_span("outbox.drain"):  # one tick
+                        # snapshot length: entries appended mid-tick go next
+                        # tick, so a hot FIFO can never starve the batch flush
+                        for _ in range(len(self._fifo)):
+                            message, future = self._fifo.popleft()
+                            self._in_flight = True
+                            try:
+                                await peer._send_now(message)
+                            except asyncio.CancelledError:
+                                if future is not None and not future.done():
+                                    future.cancel()
+                                raise
+                            except BaseException as e:  # noqa: BLE001
+                                if future is not None and not future.done():
+                                    future.set_exception(e)
+                                else:  # pragma: no cover — all entries carry futures
+                                    log.debug("outbox %s: dropped send: %s", peer.ref, e)
+                            else:
+                                self.messages_sent += 1
+                                if future is not None and not future.done():
+                                    future.set_result(None)
+                            finally:
+                                self._in_flight = False
+                        if self._pending_inval:
+                            await self._flush_invalidations()
         except asyncio.CancelledError:
             raise
         except Exception:  # noqa: BLE001 — the drain must never die silently

@@ -339,7 +339,10 @@ async def test_command_wave_fuses_into_pipeline_and_explain_names_the_command():
     cc.drain()  # the barrier: dispatch + harvest + reconcile tickets
     assert target.is_invalidated
     assert seed_node.is_invalidated
-    assert pipe.stats()["eager_waves"] == 0  # the fused path served it
+    assert pipe.stats()["eager_waves"] == 0  # no fallback served it
+    # one row's wave is a small wave: the lat mirror served it, no chain
+    assert pipe.stats()["lat_waves"] == 1
+    assert pipe.stats()["fused_dispatches"] == 0
 
     cause = getattr(target, "invalidation_cause", None) or target._invalidation_cause
     label = global_mesh_trace().command_for(cause)

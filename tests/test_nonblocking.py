@@ -69,7 +69,11 @@ class Dag(ComputeService):
         return float(self.base[i])
 
 
-def make_stack(warm_device=False, build_mirror=True):
+def make_stack(warm_device=False, build_mirror=True, lat=False):
+    """``lat=False`` drops the lat mirror (the state a broken delta log
+    leaves): the pipeline then keeps every accumulation on the fused chain,
+    which is what this suite is about. The small-wave path that a lat
+    mirror opens has its own suite, tests/test_pipeline_lat.py."""
     hub = FusionHub()
     backend = TpuGraphBackend(hub, node_capacity=N + 8, edge_capacity=len(SRC) + 512)
     svc = Dag(hub)
@@ -84,6 +88,8 @@ def make_stack(warm_device=False, build_mirror=True):
     backend.flush()
     if build_mirror:
         backend.graph.build_topo_mirror()
+        if not lat:
+            backend.graph._topo_mirror["lat"] = None
     return hub, backend, svc, table, block
 
 
@@ -500,6 +506,7 @@ def _make_three_chains():
     table.read_batch(np.arange(CHAIN_N))
     backend.flush()
     backend.graph.build_topo_mirror()
+    backend.graph._topo_mirror["lat"] = None  # keep the waves on the chain
     return hub, backend, svc, table, block
 
 
