@@ -106,11 +106,13 @@ def _fused_bump():
 @functools.lru_cache(maxsize=1)
 def _fused_triple_scatter():
     """One jitted scatter updating the three edge arrays of an incremental
-    append (src, dst, epoch): one dispatch instead of three eager ones
-    (paid per scalar-churn flush)."""
+    append (src, dst, epoch) IN PLACE: the arrays are donated (see
+    ops/bitops.py::fused_pair_scatter), so an append writes its slots
+    instead of copying three ``e_cap``-long arrays (~0.4 GB at 2^25). One
+    dispatch instead of three eager ones (paid per scalar-churn flush)."""
     import jax
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
     def scat(t1, t2, t3, rows, v1, v2, v3):
         return t1.at[rows].set(v1), t2.at[rows].set(v2), t3.at[rows].set(v3)
 
@@ -837,8 +839,9 @@ class DeviceGraph:
         if changed_parts and lat is not None and lat_changed_parts:
             # BOTH mirrors changed (the common churn shape: every added
             # edge touches a topo in-row and a lat out-row): ONE fused
-            # dispatch instead of two — the two scatters, not the
-            # host-side numpy, were nearly all of mirror_patch_ms
+            # dispatch instead of two. Each scatter patches its donated
+            # tables in place; what the host pays here is the staging of
+            # the rows and the program call
             self._scatter_mirror_and_lat_rows(
                 m, np.unique(np.concatenate(changed_parts)), n_tot,
                 lat, np.unique(np.concatenate(lat_changed_parts)),
@@ -1351,9 +1354,16 @@ class DeviceGraph:
 
         Epochs must come from the SAME moment as the edge snapshot the ELL
         was built from — for a sync build that is the live state; an async
-        install passes the zero-copy device/host epoch snapshots captured
-        at rebuild start (jax arrays are immutable, so holding the array
-        object IS the snapshot). Nodes bumped after the snapshot then show
+        install passes the device/host epoch snapshots captured at rebuild
+        start, each a copy. Holding a jax array object is a snapshot only
+        while no program donates it. The mirror paths leave ``node_epoch``
+        and ``invalid`` alone (an epoch bump and a mirror wave return new
+        arrays); the dense BFS programs (ops/wave.py) donate the whole
+        GraphArrays; and the row scatters donate the tables they patch (the
+        mirror's ``in_src`` / ``edge_epoch``, the lat ``ell_dst`` /
+        ``ell_epoch``, the three edge arrays), so nothing may keep one of
+        those across a patch or an ``add_edges``. Nodes bumped after the
+        snapshot then show
         an epoch mismatch at kernel time — exactly the captured-at-epoch
         death rule, with no catch-up patching needed for bumps."""
         from ..ops.ell_wave import ell_live_epoch_init
@@ -1437,10 +1447,13 @@ class DeviceGraph:
                 and self._topo_mirror["lat"]["n_real"] == self.n_nodes
             ),
             "error": None,
-            # zero-copy epoch snapshots for the lat mirror: jax arrays are
-            # immutable, so holding the current object IS the snapshot; the
-            # host array mutates in place, so it needs a real copy
-            "node_epoch_dev": self.device_arrays().node_epoch,
+            # epoch snapshots for the lat mirror, each a copy of its own:
+            # the host array mutates in place, and the dense BFS programs
+            # (ops/wave.py), which serve the waves whenever the patch log
+            # breaks mid-rebuild, donate the live device array
+            "node_epoch_dev": self._jnp.array(
+                self.device_arrays().node_epoch, copy=True
+            ),
             "h_node_epoch": self._h_node_epoch.copy(),
         }
 
