@@ -27,11 +27,11 @@ Legs (each ends in an oracle comparison; any divergence fails the run):
   (``interpret=False``) on a bit vector of the serve leg's size, against
   numpy.
 - **mesh** (only when JAX shows >= 4 TPU devices; never a virtual pool of its
-  own making): perf/mesh_path.py's routed legs in this process: static
-  routed graph at 10 M nodes per chip, ``exchange="a2a"``, wave 0 equal to
-  the host BFS mask; live hub + ``enable_mesh_routing`` + fused chains + a
-  mid-burst reshard at >= 1 M nodes; every array's shards verified on the
-  mesh's distinct devices.
+  own making): perf/mesh_path.py's routed legs in this process (the one
+  import from ``perf/``): static routed graph at 10 M nodes per chip,
+  ``exchange="a2a"``, wave 0 equal to the host BFS mask; live hub +
+  ``enable_mesh_routing`` + fused chains + a mid-burst reshard at >= 1 M
+  nodes; every array's shards verified on the mesh's distinct devices.
 
 Every counted fallback on that path (watchdog faults/fallbacks, super-round
 eager rounds/faults/restages/forced harvests, pipeline eager waves/chain
@@ -169,10 +169,11 @@ class HostGraph:
 
 # --------------------------------------------------------------------- service
 def make_dag_service(n: int):
-    """perf/live_path.py's table-backed DAG service (row i's value derives
-    from a base array, the store; device loader with the base table in
-    HBM), plus the one write this smoke issues."""
-    from live_path import make_dag_service as make_dag_table
+    """The table-backed DAG service the benchmark's cells measure
+    (``benchmarks/deployments/table_dag.py``: row i's value derives from a
+    base array, the store; device loader with the base table in HBM), plus
+    the one write this smoke issues."""
+    from deployments.table_dag import make_service as make_dag_table
 
     from stl_fusion_tpu.commands import command_handler
     from stl_fusion_tpu.core import is_invalidating
@@ -202,10 +203,35 @@ def make_dag_service(n: int):
     return DagTable, Bump
 
 
+class Observer:
+    """Counts client-observed invalidations with SYNC callbacks (the
+    callback runs inside the node's invalidation: the moment a client
+    reader would see staleness)."""
+
+    def __init__(self):
+        self.remaining = 0
+        self.event = asyncio.Event()
+
+    def arm(self, count: int) -> None:
+        self.remaining = count
+        self.event.clear()
+
+    def hit(self, _c=None) -> None:
+        self.remaining -= 1
+        if self.remaining <= 0:
+            self.event.set()
+
+
+async def settle(seconds: float = 0.05) -> None:
+    """Let queued tasks (watch registrations, outbox drains) run."""
+    deadline = time.perf_counter() + seconds
+    while time.perf_counter() < deadline:
+        await asyncio.sleep(0.005)
+
+
 # ------------------------------------------------------------------- serve leg
 async def serve_leg(args, out: dict) -> None:
     import jax
-    from fanout_path import Observer, settle
 
     from stl_fusion_tpu.client import compute_client, install_compute_call_type
     from stl_fusion_tpu.commands import ClusterCommander
@@ -585,7 +611,6 @@ def mesh_leg(args, out: dict) -> None:
         MESH_WAVES="2",
         MESH_SEEDS=str(max(per_chip * n_dev // 800, 8)),
         MESH_LIVE_NODES=str(live_nodes),
-        MESH_LAT_SAMPLES="4" if args.cpu_dry_run else "24",
     )
     rec: dict = {"violations": []}
     t0 = time.perf_counter()
@@ -604,8 +629,7 @@ def mesh_leg(args, out: dict) -> None:
         )},
         "live": {k: lv.get(k) for k in (
             "nodes", "members", "routed_waves", "reshard_moves",
-            "oracle_divergence", "shard_devices", "wave_chain_ms_p50",
-            "wave_chain_ms_p99", "wave_chain_rejects",
+            "oracle_divergence", "shard_devices",
         )},
         "violations": rec["violations"],
     }
@@ -654,8 +678,9 @@ def main(argv=None) -> int:
         note(f"chip_smoke: JAX found no TPU ({device}); nothing was built. "
              "A CPU dry run has to be asked for with --cpu-dry-run.")
         return 2
-    # the package, and the perf scripts whose harness pieces the legs reuse
-    sys.path[:0] = [HERE, os.path.join(HERE, "perf")]
+    # the package; benchmarks/ for the table service its cells measure;
+    # perf/ for the mesh leg's routed legs (perf/mesh_path.py)
+    sys.path[:0] = [HERE, os.path.join(HERE, "benchmarks"), os.path.join(HERE, "perf")]
     import jax.numpy as jnp
 
     from stl_fusion_tpu.graph import enable_program_cache, program_cache_stats

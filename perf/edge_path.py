@@ -70,7 +70,7 @@ amortizes):
   ZERO batch frames (the block was the fence AND the value) and every
   fence must be a block hit.
 - reported: ``upstream_rpcs_per_burst``, ``block_hit_ratio``,
-  ``reread_batch_size`` (bench.py `edge` record fields).
+  ``reread_batch_size``.
 - EDGE_SMOKE additionally drives a WebSocket consumer when the optional
   ``websockets`` package is installed (the WS load leg).
 - **EDGE_ACCEPT_PLANE** (``send_fds`` default / ``reuseport``) selects
@@ -119,9 +119,8 @@ from stl_fusion_tpu.rpc import RpcHub, RpcTestTransport  # noqa: E402
 
 def make_dag_service(n: int):
     class DagTable(ComputeService):
-        """The benchmark DAG as a table-backed service (fanout_path's
-        shape): row values derive from a base array; device loader serves
-        warms/refreshes."""
+        """The benchmark DAG as a table-backed service: row values derive
+        from a base array; device loader serves warms/refreshes."""
 
         def __init__(self, hub=None):
             super().__init__(hub)

@@ -17,8 +17,7 @@ Two legs, one JSON line on stdout (full record on stderr):
    WavePipeline dispatches fused chains THROUGH the routed mesh path,
    a mid-burst reshard (kill one member) MOVES device shards with
    zero oracle-divergent reads, and the fan-out's member-relay scope
-   proves the frontier never re-entered through per-key host RPC. Chain-difference
-   sampling yields the wave_chain p50/p99 for intra-host shards.
+   proves the frontier never re-entered through per-key host RPC.
 
 GATES (exit 1 — the tier1 mesh smoke rides them):
 - wave 0 oracle divergence, or any reshard-raced wave divergence;
@@ -53,8 +52,8 @@ GATES (exit 1 — the tier1 mesh smoke rides them):
 Env: MESH_NODES, MESH_WAVES (2), MESH_SEEDS (100_000), MESH_EXCHANGE
 (a2a; the live leg rides it too — "hier" + MESH_HOSTS emulates the host
 axis in-process), MESH_HOSTS (1), MESH_LIVE_NODES (20_000), MESH_MEMBERS
-(4), MESH_SHARDS (256), MESH_LAT_SAMPLES (24), MESH_SKIP_STATIC=1
-(smoke: live leg only), MESH_SKIP_LIVE=1, MESH_MULTIHOST (0) + the
+(4), MESH_SHARDS (256), MESH_SKIP_STATIC=1 (smoke: live leg only),
+MESH_SKIP_LIVE=1, MESH_MULTIHOST (0) + the
 MESH_MH_* knobs of perf/mesh_multihost.py, MESH_ASYNC (0),
 MESH_ASYNC_DEPTH (4), MESH_AB_NODES (120_000), MESH_AB_WAVES (3),
 MESH_AB_SEEDS (64).
@@ -441,36 +440,6 @@ async def run_live(mesh, out: dict) -> None:
                     divergence += 1
         burst_s = time.time() - t0
 
-        # --- chain-difference wave_chain latency (intra-host shards)
-        n_samp = int(os.environ.get("MESH_LAT_SAMPLES", 24))
-        r_short, r_long = 2, 10
-        shallow = lambda k: [
-            [int(ns - 1 - x)] for x in rng.choice(ns // 50, size=k, replace=False)
-        ]
-        entry = backend.routed_mirror()
-        g = entry["graph"]
-        # compile both shapes untimed
-        for r in (r_short, r_long):
-            p = g.dispatch_union_chain(shallow(r))
-            g.harvest_union_chain(p)
-        samples = []
-        for _ in range(n_samp):
-            t0 = time.perf_counter()
-            g.harvest_union_chain(g.dispatch_union_chain(shallow(r_short)))
-            t_s = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            g.harvest_union_chain(g.dispatch_union_chain(shallow(r_long)))
-            t_l = time.perf_counter() - t0
-            samples.append((t_l - t_s) / (r_long - r_short) * 1e3)
-        arr = np.asarray(samples)
-        pos = arr[arr > 0]
-        rejects = int((arr <= 0).sum())
-        # the latency chains advanced the routed invalid state outside the
-        # backend's bookkeeping; reset BOTH sides and the oracle's memory
-        backend.graph.clear_invalid()
-        entry.pop("invalid_version", None)
-        seen = set()
-
         # --- mid-burst reshard: kill m{last} -> device shards MOVE
         new_map = smap.with_members(members[:-1])
         moves = backend.apply_mesh_reshard(new_map)
@@ -537,9 +506,6 @@ async def run_live(mesh, out: dict) -> None:
             "pipeline": stats,
             "routed_waves": routed_waves,
             "exchange_levels": levels_total,
-            "wave_chain_ms_p50": round(float(np.percentile(pos, 50)), 3) if len(pos) else None,
-            "wave_chain_ms_p99": round(float(np.percentile(pos, 99)), 3) if len(pos) else None,
-            "wave_chain_rejects": rejects,
             "reshard_moves": int(moves),
             "reshard_epoch": new_map.epoch,
             "shard_devices": shard_devices,
@@ -558,8 +524,8 @@ async def run_live(mesh, out: dict) -> None:
 
 
 def main() -> None:
-    # the mesh leg needs its own virtual device pool; the caller (bench.py
-    # / CI) sets XLA_FLAGS before python starts — assert, don't silently
+    # the mesh leg needs its own virtual device pool; the caller (CI)
+    # sets XLA_FLAGS before python starts — assert, don't silently
     # measure a 1-device "mesh"
     import asyncio
 

@@ -20,8 +20,7 @@ Design rules, matching the metrics registry's:
   mid-``explain()``, and bare counter read-modify-writes would undercount.
   No I/O, no registry hop. Feeding sites additionally guard with
   ``if RECORDER.enabled:`` so a disabled recorder costs one attribute
-  read — the same gate discipline as ``WaveProfiler.enabled``
-  (``LIVE_RECORDER=0`` is the live-path A/B knob).
+  read — the same gate discipline as ``WaveProfiler.enabled``.
 - **Bounded memory**: the ring holds ``capacity`` events (default 4096);
   a 100k-event storm keeps the newest 4096 and exact per-kind counters.
   Totals survive eviction, so the summary stays whole-run honest.

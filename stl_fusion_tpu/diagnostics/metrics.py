@@ -20,9 +20,8 @@ Design rules, in tension and resolved as follows:
   floor and a ceiling): a flapping peer or a 10M-wave storm can record
   forever without growing memory, and p50/p99 estimates come from the
   cumulative bucket counts — the system reports its own latency
-  distribution instead of leaving it to a bespoke harness
-  (perf/fanout_path.py measured delivery p50/p99 from the outside; the
-  ``fusion_e2e_delivery_ms`` histogram is the same number measured from
+  distribution instead of leaving it to a bespoke harness (the
+  ``fusion_e2e_delivery_ms`` histogram is delivery latency measured from
   the inside).
 - **Values summed across collectors**: many live RpcHubs (tests, one hub
   per client) report the same metric name; the scrape shows the process
@@ -31,7 +30,7 @@ Design rules, in tension and resolved as follows:
 ``WaveProfiler`` is the per-wave timeline recorder ``TpuGraphBackend``
 drives: a ring buffer of wave records (seed count, newly size, device vs
 host milliseconds, journal depth pre/post coalescing, cause id) queryable
-via ``FusionMonitor.report()["waves"]`` and dumped by bench.py — the
+via ``FusionMonitor.report()["waves"]`` and ``GET /trace`` — the
 per-stage pipeline telemetry the streaming-dataflow papers (PAPERS.md)
 lean on to find fusion-boundary stalls.
 """
@@ -232,8 +231,7 @@ class Histogram:
     def checkpoint(self) -> tuple:
         """Opaque marker for :meth:`since` — snapshot-and-diff lets a
         harness report THIS phase's distribution out of a histogram other
-        phases also record into (perf/fanout_path.py separates its A/B
-        modes this way)."""
+        phases also record into."""
         return (list(self.buckets), self.count, self.sum)
 
     def since(self, checkpoint: tuple) -> dict:

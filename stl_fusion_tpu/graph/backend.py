@@ -174,8 +174,8 @@ def _finish_block_refresh_bookkeeping(table, cleared: np.ndarray) -> None:
 
 
 class _RefreshChainTicket:
-    """In-flight burst→refresh chain (``cascade_rows_lanes_refresh_chain``
-    with ``nonblocking=True``): the dispatches are enqueued; ``harvest()``
+    """In-flight burst→refresh chain (what ``SuperRoundProgram`` enqueues
+    for a lanes super-round): the dispatches are enqueued; ``harvest()``
     blocks on the results and runs the two-tier host apply per logical
     wave. ``dispatched_at`` lets the caller account the overlap window
     (host work done between dispatch and harvest ran concurrently with the
@@ -189,7 +189,7 @@ class _RefreshChainTicket:
 
     def __init__(self, backend, block, n_bursts, stage_burst, stages, refresh,
                  pending, cause, seqs, pre_block_invalid, dispatched_at,
-                 update_valid, kind: str = "lanes_refresh_chain"):
+                 update_valid, kind: str):
         self.backend = backend
         self.block = block
         self.n_bursts = n_bursts
@@ -923,66 +923,12 @@ class TpuGraphBackend:
             self._profile_wave("union", len(nids), cause, t0, t1, len(newly_ids), wave_seq)
             return total
 
-    def cascade_rows_lanes_refresh_chain(
-        self, block: RowBlock, bursts, nonblocking: bool = False
-    ):
-        """K consecutive rounds of (lane burst → columnar device refresh)
-        in ONE fused dispatch chain — the nonblocking live-loop composition
-        (ISSUE 7 tentpole): burst ``i`` cascades, the block's stale rows
-        recompute through the table's DEVICE loader, and burst ``i+1`` then
-        cascades against a consistent block, all device-side with zero host
-        round trips between rounds (before this, every round paid a host
-        round trip per dispatch plus a serialized host apply).
-
-        ``bursts`` is a list of row-group lists; each burst's semantics are
-        exactly :meth:`cascade_rows_lanes` followed by
-        :meth:`refresh_block_on_device`. Per-logical-wave identity is kept:
-        each stage carries its own wave seq (recorder events during that
-        stage's host apply stamp it) and the profiler record spans the
-        chain with ``fused_depth``. Returns one int64 newly-count array per
-        burst — or, with ``nonblocking=True``, a ticket whose
-        ``harvest()`` returns them later: the chain is ENQUEUED and the
-        caller overlaps host work (churn prep, the previous chain's fence
-        fan-out) with its device execution. Until harvest, journal APPENDS
-        are safe but ``flush()`` and reads of the host invalid mirror are
-        not — harvest first. Requires a full-table bind with a device
-        loader and a fusible mirror (callers fall back to the sequential
-        pair)."""
-        self.flush()
-        # one stage per burst chunk; stage→burst mapping folds counts back
-        stages: List[List[List[int]]] = []
-        stage_burst: List[int] = []
-        for bi, groups in enumerate(bursts):
-            seed_lists = [
-                (block.base + self._check_rows(block, g)).tolist()
-                for g in groups
-            ]
-            for c0 in range(0, max(len(seed_lists), 1), self._LANES_CHUNK):
-                stages.append(seed_lists[c0 : c0 + self._LANES_CHUNK])
-                stage_burst.append(bi)
-        refresh = self._block_refresh_state(block)
-        update_valid = refresh["update_valid"]
-        dg = self.graph
-        pre_block_invalid = dg._h_invalid[block.base : block.end()].copy()
-        cause, seqs = self._begin_wave_span(len(stages))
-        t0 = time.perf_counter()
-        pending = dg.dispatch_waves_lanes_chain(stages, refresh=refresh)
-        ticket = _RefreshChainTicket(
-            self, block, len(bursts), stage_burst, stages, refresh, pending,
-            cause, seqs, pre_block_invalid, t0, update_valid,
-        )
-        if nonblocking:
-            return ticket
-        return ticket.harvest()
-
     def _block_refresh_state(self, block: RowBlock) -> dict:
-        """The device-refresh runtime state the fused chain / super-round
-        programs thread through their loop carry (memo values, validity,
-        loader args) — ONE construction shared by
-        :meth:`cascade_rows_lanes_refresh_chain` and
-        ``graph/superround.py`` so the table contract can never drift.
-        Raises for tables without a device loader or partial binds
-        (callers fall back to the sequential pair)."""
+        """The device-refresh runtime state the resident super-round
+        program (``graph/superround.py``) threads through its loop carry
+        (memo values, validity, loader args). Raises for tables without a
+        device loader or partial binds (callers fall back to the
+        sequential pair)."""
         table = block.table
         fn = table.device_compute_fn
         if fn is None:
@@ -1463,8 +1409,8 @@ class TpuGraphBackend:
             edge_dst_epoch=dg._h_edge_dst_epoch[:m].copy(),
             exchange=exchange,
             node_epoch=dg._h_node_epoch,
-            # device-authoritative: run_wave_frontier(sync_host=False) leaves
-            # the host _h_invalid stale; invalid_mask() reads the device copy
+            # device-authoritative: the host _h_invalid may trail the device
+            # lane; invalid_mask() reads the device copy
             invalid=dg.invalid_mask(),
         )
 
@@ -1566,8 +1512,8 @@ class TpuGraphBackend:
             # mirror): dense state is authoritative — full sync, once. The
             # host mirror catches up from the same device read, so the
             # overflow mask-diff below never compares against a stale
-            # _h_invalid (run_wave_frontier(sync_host=False) leaves it
-            # stale, but it also bumps invalid_version → lands here)
+            # _h_invalid (whatever left it stale also bumped
+            # invalid_version → lands here)
             mask = dg.invalid_mask()
             dg._h_invalid[: dg.n_nodes] = mask
             sharded.set_invalid(mask)

@@ -220,11 +220,6 @@ class DeviceGraph:
         self.adaptive_passes = False
         self.adaptive_stages = 0
         self.mirror_patch_s = 0.0  # cumulative patch time
-        # patch-time breakdown (ISSUE 7 satellite: "mirror_patch_ms" used
-        # to be one figure with no way to tell numpy bookkeeping from
-        # device dispatches — record both halves)
-        self.mirror_patch_host_s = 0.0  # numpy slot/level bookkeeping
-        self.mirror_patch_device_s = 0.0  # device row-scatter dispatches
         # auxiliary structural-delta subscribers (the backend's MESH
         # mirrors, VERDICT r4 #4): each gets the same ordered delta stream
         # the topo mirror consumes; an overflowing or broken log marks
@@ -740,7 +735,6 @@ class DeviceGraph:
             m["validated_at"] = self._struct_version
             return True
         t0 = _time.perf_counter()
-        dev_s0 = self.mirror_patch_device_s
         h = m["h_in_src"]
         inv_perm = m["inv_perm"]
         n_tot = m["n_tot"]
@@ -835,7 +829,6 @@ class DeviceGraph:
                 h[rv, slot] = ru
                 changed_parts.append(rv)
                 mutated = True
-        t_dev0 = _time.perf_counter()
         if changed_parts and lat is not None and lat_changed_parts:
             # BOTH mirrors changed (the common churn shape: every added
             # edge touches a topo in-row and a lat out-row): ONE fused
@@ -854,7 +847,6 @@ class DeviceGraph:
             self._scatter_lat_rows(
                 lat, np.unique(np.concatenate(lat_changed_parts))
             )
-        self.mirror_patch_device_s += _time.perf_counter() - t_dev0
         if n_viol != int(m.get("n_viol", 0)):
             # pass counts ≤ FUSED_PASS_MAX each key one fused one-dispatch
             # program (compiled once per level layout, persisted — the
@@ -869,13 +861,7 @@ class DeviceGraph:
         m["validated_at"] = self._struct_version
         m["fp"] = None  # build-time fingerprint no longer describes the tables
         self.mirror_patches += 1
-        dt = _time.perf_counter() - t0
-        self.mirror_patch_s += dt
-        # host half = everything that was not the device scatter window
-        # (slot ranking, dedup, level checks — all numpy)
-        self.mirror_patch_host_s += max(
-            dt - (self.mirror_patch_device_s - dev_s0), 0.0
-        )
+        self.mirror_patch_s += _time.perf_counter() - t0
         return True
 
     @staticmethod
@@ -1567,8 +1553,7 @@ class DeviceGraph:
         """M independent union waves SEQUENCED in one dispatch on the lat
         mirror — wave ``i`` sees waves ``< i``'s commits, so final state
         and per-wave counts equal M :meth:`run_waves_union` calls (the
-        burst-of-lone-invalidations shape; also what lets the live bench
-        time per-wave latency by chain difference). Per-wave capacity
+        burst-of-lone-invalidations shape). Per-wave capacity
         overflows re-run on the topo sweep AFTER the chain (their counts
         then reflect that execution order). Without a valid lat mirror the
         whole call degrades to a host loop. Returns (counts int64[M],
@@ -1709,7 +1694,6 @@ class DeviceGraph:
         self,
         stage_groups: Sequence[Sequence[Sequence[int]]],
         max_words: int = 16,
-        refresh: Optional[dict] = None,
     ) -> dict:
         """ENQUEUE ``depth`` consecutive lane bursts as
         ``ceil(depth/FUSE_CHAIN_MAX)`` chained device dispatches WITHOUT
@@ -1720,20 +1704,11 @@ class DeviceGraph:
         :meth:`harvest_waves_lanes_chain` blocks on the results and applies
         them to the host mirror.
 
-        ``refresh`` folds a columnar device refresh into EVERY stage (the
-        churn-recompute composition the live loop runs): after a stage's
-        sweep, the block's invalid rows recompute through the table's
-        device loader and their invalid bits clear, so the next stage
-        cascades against a consistent block — K rounds of (burst →
-        refresh) in one dispatch. Keys:
-        ``{"base", "n_rows", "fn", "largs", "values", "valid_dev",
-        "update_valid", "cache"}`` (``cache`` holds the compiled chain
-        programs across calls — RowBlock._dev_refresh).
-
         Requires a fusible mirror (valid, ``passes <= FUSED_PASS_MAX``);
         raises RuntimeError otherwise — callers fall back to the split
         per-burst path. Returns the pending-handles dict for harvest."""
         from ..ops.pull_wave import pack_lane_matrix
+        from ..ops.topo_wave import topo_mirror_fused_lanes_chain_step
 
         jnp = self._jnp
         m = self.build_topo_mirror()
@@ -1789,28 +1764,13 @@ class DeviceGraph:
             mats = np.stack(parts)
             g = self.device_arrays()
             self.sweep_packed_dispatches += 1
-            if refresh is None:
-                from ..ops.topo_wave import topo_mirror_fused_lanes_chain_step
-
-                chain = topo_mirror_fused_lanes_chain_step(
-                    m["level_starts"], n_tot, words, passes, len(batch)
-                )
-                g_inv2, lane_counts_d, packed_d = chain(
-                    m["garrays"], m["node_epoch0"], m["perm_clipped"],
-                    g.invalid, jnp.asarray(mats),
-                )
-            else:
-                chain = self._refresh_chain_program(m, refresh, words, passes)
-                (
-                    g_inv2, values2, valid2, lane_counts_d, packed_d,
-                ) = chain(
-                    refresh["values"], refresh["valid_dev"],
-                    m["garrays"], m["node_epoch0"], m["perm_clipped"],
-                    g.invalid, jnp.asarray(mats), *refresh["largs"],
-                )
-                # thread the table state into the next batch's dispatch
-                refresh["values"] = values2
-                refresh["valid_dev"] = valid2
+            chain = topo_mirror_fused_lanes_chain_step(
+                m["level_starts"], n_tot, words, passes, len(batch)
+            )
+            g_inv2, lane_counts_d, packed_d = chain(
+                m["garrays"], m["node_epoch0"], m["perm_clipped"],
+                g.invalid, jnp.asarray(mats),
+            )
             # commit the device handle NOW so the next batch (or the next
             # chain the caller enqueues) chains device-side
             self._g = g._replace(invalid=g_inv2)
@@ -1822,7 +1782,7 @@ class DeviceGraph:
         }
         return {
             "batches": batches,
-            "refresh": refresh,
+            "refresh": None,
             "depth": len(stage_groups),
             "dispatches": len(batches),
         }
@@ -1831,9 +1791,8 @@ class DeviceGraph:
         """Build (or reuse) the jitted burst→refresh scan for one block —
         the loop-carried composition of ``run_waves_lanes`` +
         ``refresh_block_on_device`` (ops/topo_wave.py::
-        topo_mirror_superround_step; the chain path and the resident
-        super-round program share the ONE definition, so the two can never
-        drift). Cached in the caller-owned ``refresh["cache"]`` dict keyed
+        topo_mirror_superround_step), the resident super-round's program.
+        Cached in the caller-owned ``refresh["cache"]`` dict keyed
         on everything that shapes the program (level layout included: a
         re-level must never serve a stale chain; depth is NOT a key — jit
         re-traces per seed-tensor shape, one program object per
@@ -2086,16 +2045,6 @@ class DeviceGraph:
         n_chunks = max(-(-B // chunk_size), 1)
         self.last_lanes_info = {"depth": n_chunks, "dispatches": n_chunks}
         return counts, union_mask
-
-    def run_wave_frontier(self, seed_frontier, sync_host: bool = False) -> int:
-        """Wave from a prebuilt boolean frontier (bench hot path — host copy
-        of invalid state stays stale unless sync_host)."""
-        g = self.device_arrays()
-        self.invalid_version += 1
-        self._g, count = run_wave(seed_frontier, g)
-        if sync_host:
-            self._sync_invalid_back()
-        return int(count)
 
     def _sync_invalid_back(self) -> None:
         """After a device wave, the device invalid lane is newer — pull it

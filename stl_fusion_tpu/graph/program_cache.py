@@ -2,8 +2,8 @@
 
 The cold-start budget's biggest line items are compiles, not data. XLA
 already ships a persistent compilation cache; this module is the ONE place
-the project wires it (bench.py, chip_smoke.py, every perf/*.py script and
-any serving process call :func:`enable_program_cache`; nothing else
+the project wires it (benchmarks/run.py, chip_smoke.py, every perf/*.py
+script and any serving process call :func:`enable_program_cache`; nothing else
 touches ``jax_compilation_cache_dir``), plus the restart-warmth telemetry:
 :func:`program_cache_stats` counts cached executables so the warm-rejoin
 path (cluster/rejoin.py, DURABILITY.md) can report whether a restart
@@ -109,8 +109,8 @@ class time_program_warm:
     read as the same warm. ``cache_hit`` is judged from the persistent
     cache dir: a warm that added NO new executables (and the cache is
     enabled) was served from disk/in-process. Records land in
-    :func:`program_warm_report`; live_path.py folds them into the bench
-    ``cold_start`` block.
+    :func:`program_warm_report`; the benchmark's ``program_warm_s`` is
+    their sum.
 
     Usage::
 

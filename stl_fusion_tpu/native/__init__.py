@@ -4,8 +4,8 @@ The performance-critical host-side pieces of the framework — where the
 reference leans on the .NET runtime's optimized primitives, this build uses
 C++ compiled on first use (g++ is in the image; no pip/pybind needed):
 
-- ``graphpack``: the dual-ELL graph packer feeding the hybrid invalidation
-  kernel (counting-sort degree bounding; ~10x the numpy path at 10M nodes).
+- ``graphpack``: the ELL graph packer and topo leveller feeding the mirror
+  builders (counting-sort degree bounding; ~10x the numpy path at 10M nodes).
 
 Every native entry point has a numpy fallback — ``load_graphpack()``
 returning None means "use the Python path", never a hard failure.
@@ -24,7 +24,6 @@ log = logging.getLogger("stl_fusion_tpu")
 __all__ = [
     "load_graphpack",
     "native_build_ell",
-    "native_build_hybrid_tables",
     "native_topo_levels",
 ]
 
@@ -81,17 +80,8 @@ def load_graphpack():
             log.warning("graphpack load failed: %s", e)
             _lib_failed = True
             return None
-        lib.gp_build_hybrid.restype = ctypes.c_void_p
-        lib.gp_build_hybrid.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int, ctypes.c_int,
-        ]
         lib.gp_n_tot.restype = ctypes.c_int64
         lib.gp_n_tot.argtypes = [ctypes.c_void_p]
-        lib.gp_n_edges.restype = ctypes.c_int64
-        lib.gp_n_edges.argtypes = [ctypes.c_void_p]
-        lib.gp_fill.restype = ctypes.c_int32
-        lib.gp_fill.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
         lib.gp_free.restype = None
         lib.gp_free.argtypes = [ctypes.c_void_p]
         lib.gp_topo_levels.restype = ctypes.c_int32
@@ -132,37 +122,6 @@ def native_topo_levels(in_src, n: int, k: int):
         # relaxation's full non-convergence loop before failing anyway.
         raise ValueError(f"dependency graph contains a cycle (gp_topo_levels rc={rc})")
     return level
-
-
-def native_build_hybrid_tables(src, dst, n_nodes: int, k_in: int, k_out: int):
-    """(in_src, out_dst, n_tot) via the native packer, or None → fallback."""
-    import numpy as np
-
-    lib = load_graphpack()
-    if lib is None:
-        return None
-    src = np.ascontiguousarray(src, dtype=np.int32)
-    dst = np.ascontiguousarray(dst, dtype=np.int32)
-    handle = lib.gp_build_hybrid(
-        src.ctypes.data_as(ctypes.c_void_p),
-        dst.ctypes.data_as(ctypes.c_void_p),
-        len(src), n_nodes, k_in, k_out,
-    )
-    try:
-        n_tot = lib.gp_n_tot(handle)
-        in_src = np.empty((n_tot + 1, k_in), dtype=np.int32)
-        out_dst = np.empty((n_tot + 1, k_out), dtype=np.int32)
-        rc = lib.gp_fill(
-            handle,
-            in_src.ctypes.data_as(ctypes.c_void_p),
-            out_dst.ctypes.data_as(ctypes.c_void_p),
-        )
-        if rc != 0:
-            log.error("graphpack degree bound violated (rc=%d); using numpy path", rc)
-            return None
-        return in_src, out_dst, int(n_tot)
-    finally:
-        lib.gp_free(handle)
 
 
 def native_build_ell(src, dst, n_nodes: int, k: int):

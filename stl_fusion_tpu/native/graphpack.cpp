@@ -1,21 +1,16 @@
-// graphpack — native dual-ELL graph packer for the hybrid invalidation kernel.
+// graphpack — native ELL graph packer and topo leveller for the wave kernels.
 //
-// C++ counterpart of stl_fusion_tpu/ops/hybrid_wave.py::build_hybrid_graph
-// (which is itself the TPU-shaped replacement for the reference's
-// ComputedRegistry edge store — SURVEY §2.1). The Python/numpy path costs
-// multiple argsort+unique passes over the 30M-edge list; this packer uses
-// counting sorts (O(E+N) per round) and runs the whole two-phase
-// degree-bounding + table-packing pipeline in a few hundred ms at 10M nodes.
+// C++ counterpart of stl_fusion_tpu/ops/ell_wave.py::build_ell and of
+// ops/topo_wave.py's level pass (themselves the TPU-shaped replacement for
+// the reference's ComputedRegistry edge store — SURVEY §2.1). The
+// Python/numpy path costs multiple argsort+unique passes over the 30M-edge
+// list; this packer uses counting sorts (O(E+N) per round).
 //
-// Pipeline (identical contract to the numpy path; virtual-id NUMBERING may
-// differ, reachability semantics are equal — tests cross-check both):
-//   phase 1: bound OUT-degree at k_out with virtual forwarding trees
-//            (hub fan-out spread over log_k levels)
-//   phase 2: bound IN-degree at k_in with virtual OR-collector trees
-//   phase 3: pack in-ELL (n_tot+1, k_in) and out-ELL (n_tot+1, k_out),
-//            pad slots pointing at the null row n_tot.
+// Contract identical to the numpy path; virtual-id NUMBERING may differ,
+// reachability semantics are equal — tests cross-check both.
 //
-// C ABI (ctypes): gp_build_hybrid / gp_n_tot / gp_fill / gp_free.
+// C ABI (ctypes): gp_build_ell / gp_n_tot / gp_fill_out / gp_free /
+// gp_topo_levels.
 #include <cstdint>
 #include <cstring>
 #include <vector>
@@ -29,7 +24,6 @@ struct EdgeList {
 
 struct Handle {
   int64_t n_tot = 0;
-  int k_in = 0, k_out = 0;
   EdgeList edges;  // final bounded edge list
 };
 
@@ -104,50 +98,7 @@ void bound_degree(EdgeList& cur, int64_t& n_tot, int k, bool bound_src,
 
 extern "C" {
 
-void* gp_build_hybrid(const int32_t* src, const int32_t* dst, int64_t m,
-                      int64_t n_nodes, int k_in, int k_out) {
-  Handle* h = new Handle();
-  h->k_in = k_in;
-  h->k_out = k_out;
-  h->n_tot = n_nodes;
-
-  EdgeList cur;
-  cur.src.assign(src, src + m);
-  cur.dst.assign(dst, dst + m);
-
-  EdgeList after_out;
-  bound_degree(cur, h->n_tot, k_out, /*bound_src=*/true, after_out);
-  bound_degree(after_out, h->n_tot, k_in, /*bound_src=*/false, h->edges);
-  return h;
-}
-
 int64_t gp_n_tot(void* handle) { return static_cast<Handle*>(handle)->n_tot; }
-
-int64_t gp_n_edges(void* handle) {
-  return static_cast<int64_t>(static_cast<Handle*>(handle)->edges.src.size());
-}
-
-// Fill caller-allocated tables: in_src[(n_tot+1)*k_in], out_dst[(n_tot+1)*k_out].
-// Returns 0 on success, -1 if a degree bound was violated (internal bug).
-int32_t gp_fill(void* handle, int32_t* in_src, int32_t* out_dst) {
-  Handle* h = static_cast<Handle*>(handle);
-  const int64_t n_tot = h->n_tot;
-  const int64_t rows = n_tot + 1;
-  const int32_t pad = static_cast<int32_t>(n_tot);
-  std::fill(in_src, in_src + rows * h->k_in, pad);
-  std::fill(out_dst, out_dst + rows * h->k_out, pad);
-
-  std::vector<int32_t> in_slot(static_cast<size_t>(rows), 0);
-  std::vector<int32_t> out_slot(static_cast<size_t>(rows), 0);
-  const size_t m = h->edges.src.size();
-  for (size_t e = 0; e < m; e++) {
-    int64_t s = h->edges.src[e], d = h->edges.dst[e];
-    if (out_slot[s] >= h->k_out || in_slot[d] >= h->k_in) return -1;
-    out_dst[s * h->k_out + out_slot[s]++] = static_cast<int32_t>(d);
-    in_src[d * h->k_in + in_slot[d]++] = static_cast<int32_t>(s);
-  }
-  return 0;
-}
 
 void gp_free(void* handle) { delete static_cast<Handle*>(handle); }
 
@@ -158,8 +109,6 @@ void gp_free(void* handle) { delete static_cast<Handle*>(handle); }
 void* gp_build_ell(const int32_t* src, const int32_t* dst, int64_t m,
                    int64_t n_nodes, int k, int32_t bound_src_flag) {
   Handle* h = new Handle();
-  h->k_in = k;
-  h->k_out = k;
   h->n_tot = n_nodes;
   EdgeList cur;
   cur.src.assign(src, src + m);

@@ -236,10 +236,6 @@ class ShardedDeviceGraph:
         self.g, count = self._wave(self.seeds_to_frontier(seed_ids), self.g)
         return int(count)
 
-    def run_wave_frontier(self, frontier: jax.Array) -> int:
-        self.g, count = self._wave(frontier, self.g)
-        return int(count)
-
     def run_wave_collect(
         self, seed_ids: Sequence[int], cap: int = 65536
     ) -> Tuple[int, np.ndarray, bool]:

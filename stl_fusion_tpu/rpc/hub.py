@@ -81,7 +81,7 @@ class RpcHub:
         #: each peer's outbox into one ``$sys-c.invalidate_batch`` frame per
         #: drain tick (version-deduped). False: the original one-frame-per-
         #: key ``$sys-c.invalidate`` path — kept for wire compat with old
-        #: clients and as the A/B baseline (perf/fanout_path.py). Clients
+        #: clients. Clients
         #: always understand BOTH frame kinds regardless of this flag.
         self.coalesce_invalidations: bool = True
         #: optional ComputeFanoutIndex (rpc/fanout.py): lets a device

@@ -6,8 +6,7 @@ as K sequential waves (checked against both a sequential twin backend and
 the resilience host-BFS oracle), including under seeded chaos
 (drop/dup/reorder on the client link) and with a mid-chain injected wave
 fault degrading to the split host path — plus the WavePipeline's
-accumulate/dispatch/drain lifecycle, the refresh-folded chain
-(burst→device-refresh rounds fused into one dispatch), per-logical-wave
+accumulate/dispatch/drain lifecycle, per-logical-wave
 identity through ``explain()`` end-to-end over ``$sys-d`` with the wire
 codec on, and the overlap drain counters.
 """
@@ -69,7 +68,7 @@ class Dag(ComputeService):
         return float(self.base[i])
 
 
-def make_stack(warm_device=False, build_mirror=True, lat=False):
+def make_stack(build_mirror=True, lat=False):
     """``lat=False`` drops the lat mirror (the state a broken delta log
     leaves): the pipeline then keeps every accumulation on the fused chain,
     which is what this suite is about. The small-wave path that a lat
@@ -81,10 +80,7 @@ def make_stack(warm_device=False, build_mirror=True, lat=False):
     table = memo_table_of(svc.node)
     block = backend.bind_table_rows(table)
     backend.declare_row_edges(block, SRC, block, DST)
-    if warm_device:
-        backend.warm_block_on_device(block)
-    else:
-        table.read_batch(np.arange(N))
+    table.read_batch(np.arange(N))
     backend.flush()
     if build_mirror:
         backend.graph.build_topo_mirror()
@@ -234,61 +230,6 @@ async def test_journal_entry_with_inflight_chain_forces_harvest_first():
         np.asarray(backend.graph.invalid_mask()),
         np.asarray(b2.graph.invalid_mask()),
     )
-
-
-# ---------------------------------------------------------------- refresh chain
-
-
-async def test_refresh_chain_matches_sequential_burst_refresh_rounds():
-    """cascade_rows_lanes_refresh_chain ≡ K rounds of (cascade_rows_lanes →
-    refresh_block_on_device): identical per-burst counts, table values,
-    staleness, and a fully-consistent end state."""
-    import jax
-
-    rng = np.random.default_rng(11)
-    bursts = [
-        [rng.choice(N, size=4, replace=False).tolist() for _ in range(40)]
-        for _ in range(4)
-    ]
-    _hub1, b1, _s1, t1, blk1 = make_stack(warm_device=True)
-    ref = []
-    for burst in bursts:
-        ref.append(b1.cascade_rows_lanes(blk1, burst))
-        b1.refresh_block_on_device(blk1)
-
-    _hub2, b2, _s2, t2, blk2 = make_stack(warm_device=True)
-    got = b2.cascade_rows_lanes_refresh_chain(blk2, bursts)
-    for i in range(len(bursts)):
-        assert np.array_equal(ref[i], got[i]), i
-    assert t2.stale_count() == 0
-    assert not b2.graph._h_invalid.any()
-    assert not np.asarray(b2.graph.invalid_mask()).any()
-    v1 = np.asarray(jax.device_get(t1._values))
-    v2 = np.asarray(jax.device_get(t2._values))
-    assert np.allclose(v1, v2)
-    rec = b2.profiler.recent()[-1]
-    assert rec["kind"] == "lanes_refresh_chain" and rec["fused_depth"] == 4
-
-
-async def test_refresh_chain_nonblocking_ticket_overlap_window():
-    """The nonblocking ticket: dispatch returns immediately, harvest
-    applies later, and a second harvest is refused (state consumed)."""
-    rng = np.random.default_rng(13)
-    bursts = [
-        [rng.choice(N, size=4, replace=False).tolist() for _ in range(20)]
-        for _ in range(2)
-    ]
-    _hub, backend, _svc, table, block = make_stack(warm_device=True)
-    ticket = backend.cascade_rows_lanes_refresh_chain(
-        block, bursts, nonblocking=True
-    )
-    assert not ticket.done
-    per_burst = ticket.harvest()
-    assert ticket.done and len(per_burst) == 2
-    assert ticket.cleared_total > 0
-    assert table.stale_count() == 0
-    with pytest.raises(RuntimeError):
-        ticket.harvest()
 
 
 # ---------------------------------------------------------------- fault path

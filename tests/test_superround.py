@@ -177,6 +177,10 @@ async def test_double_buffered_staging_overlaps_inflight_superround():
         prog.drain()
         assert t2.done and prog.harvests == 2
         assert prog.occupancy() >= 0.0 and prog.stats()["wall_s"] > 0
+        # the chain ticket under a super-round is consumed by its harvest
+        assert t2.inner.done and t2.inner.cleared_total > 0
+        with pytest.raises(RuntimeError):
+            t2.inner.harvest()
 
         hub_b, b_b, _s2, table_b, blk_b = make_stack()
         set_default_hub(hub_b)

@@ -1,6 +1,6 @@
 """Topo-ordered single-sweep 32-wave kernel vs host BFS oracle (ops/topo_wave.py).
 
-Same oracle strategy as test_pull_wave/test_hybrid_wave, plus checks that
+Same oracle strategy as test_pull_wave, plus checks that
 the level renumbering round-trips ids and that the native Kahn level pass
 agrees with the numpy relaxation.
 """
@@ -145,23 +145,40 @@ def test_native_levels_match_numpy():
     assert np.array_equal(lv_nat, lv_np)
 
 
-def test_agrees_with_hybrid_kernel():
-    from stl_fusion_tpu.ops.hybrid_wave import build_hybrid_graph, build_hybrid_wave32
-    from stl_fusion_tpu.ops.pull_wave import seeds_to_bits
-
+def test_agrees_with_dense_kernel():
+    """The sweep's 32 lanes against 32 waves of the plain dense BFS
+    (ops/wave.py, the level-synchronous reference kernel) on the same graph
+    and seeds: every lane's closure and the total count are equal."""
     import jax.numpy as jnp
 
-    src, dst = power_law_dag(2500, avg_degree=3.0, seed=8)
+    from stl_fusion_tpu.ops.wave import GraphArrays, run_wave, seeds_to_frontier
+
+    n = 2500
+    src, dst = power_law_dag(n, avg_degree=3.0, seed=8)
     rng = np.random.default_rng(5)
-    seed_lists = [rng.choice(2500, size=10, replace=False) for _ in range(32)]
+    seed_lists = [rng.choice(n, size=10, replace=False) for _ in range(32)]
 
-    tg = build_topo_graph(src, dst, 2500)
+    tg = build_topo_graph(src, dst, n)
     inv_t, c_t = run_waves(tg, seed_lists)
+    lanes = inv_t[: tg.n_tot].astype(np.int64)
+    real = np.asarray(tg.is_real[: tg.n_tot], dtype=bool)
 
-    hg = build_hybrid_graph(src, dst, 2500)
-    h_state0, h_wave = build_hybrid_wave32(hg, tail_cap=64)
-    h_state, c_h = h_wave(jnp.asarray(seeds_to_bits(hg.n_tot, seed_lists)), h_state0)
-    assert c_t == int(c_h)
+    total = 0
+    for w, seeds in enumerate(seed_lists):
+        g = GraphArrays(  # run_wave donates its graph: a fresh one per wave
+            edge_src=jnp.asarray(src, dtype=jnp.int32),
+            edge_dst=jnp.asarray(dst, dtype=jnp.int32),
+            edge_dst_epoch=jnp.zeros(len(src), dtype=jnp.int32),
+            node_epoch=jnp.zeros(n + 1, dtype=jnp.int32).at[n].set(-2),
+            invalid=jnp.zeros(n + 1, dtype=jnp.bool_),
+        )
+        frontier = seeds_to_frontier(n, jnp.asarray(seeds, dtype=jnp.int32))
+        g, count = run_wave(frontier, g)
+        got = np.zeros(n, dtype=bool)
+        got[tg.perm[np.nonzero((lanes & (1 << w)).astype(bool) & real)[0]]] = True
+        assert np.array_equal(got, np.asarray(g.invalid[:n])), w
+        total += int(count)
+    assert c_t == total
 
 
 def test_multiword_packing_matches_oracle():
