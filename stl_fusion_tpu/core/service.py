@@ -270,6 +270,7 @@ class ComputeMethodDef:
             method_def = self
 
             def on_invalidate(ids) -> None:
+                backend = hub.graph_backend
                 for i in ids:
                     args = method_def.args_for_row(int(i), table)
                     if args is None:
@@ -277,8 +278,16 @@ class ComputeMethodDef:
                     node = registry.get(
                         ComputeMethodInput(method_def, service, args, function)
                     )
-                    if node is not None:
-                        node.invalidate()
+                    if node is None:
+                        continue
+                    if backend is not None and backend.is_wave_echo(node._backend_nid):
+                        # the backend is applying a device wave to this
+                        # key's node and the mark is its own handler's: the
+                        # registry may already hold the NEXT version (a
+                        # displaced computed materializes after the swap),
+                        # which that wave never targeted
+                        continue
+                    node.invalidate()
 
             table.on_invalidate.append(on_invalidate)
             store[key] = table

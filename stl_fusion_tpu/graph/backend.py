@@ -279,10 +279,15 @@ class TpuGraphBackend:
         self._pending = np.zeros(self.graph.n_cap + 1, dtype=bool)
         self._watched = np.zeros(self.graph.n_cap + 1, dtype=bool)
         # nids whose invalidation is CURRENTLY being applied from a device
-        # wave — only those skip the journal echo; a handler that host-led
-        # invalidates some OTHER node during application must still journal
-        # (a global flag here would silently desync the device mask)
+        # wave — only those skip the journal echo (is_wave_echo); a handler
+        # that host-led invalidates some OTHER node during application must
+        # still journal (a global flag here would silently desync the device
+        # mask)
         self._applying_ids: set = set()
+        #: table marks dropped as wave echoes (ids, not calls): a watched
+        #: scalar twin's ``mark_row_stale`` handler firing while the wave
+        #: that invalidated it is applied. See :meth:`is_wave_echo`.
+        self.wave_echo_marks_dropped = 0
         # columnar row blocks (bind_table_rows): sorted by base, with flat
         # base/end arrays for O(log blocks) wave partitioning
         self._row_blocks: List[RowBlock] = []
@@ -350,6 +355,7 @@ class TpuGraphBackend:
             "fusion_graph_journal_depth": len(self._journal),
             "fusion_waves_run_total": self.waves_run,
             "fusion_device_invalidations_total": self.device_invalidations,
+            "fusion_wave_echo_marks_dropped_total": self.wave_echo_marks_dropped,
             "fusion_sweep_packed_dispatches_total": self.graph.sweep_packed_dispatches,
         }
 
@@ -544,9 +550,44 @@ class TpuGraphBackend:
                 return  # nodes born before the backend attached
             self._journal.append(("edge", (uid, did)))
 
+    def is_wave_echo(self, nids):
+        """Whether THIS backend is right now applying to a node what the
+        device already computed (``_eager_invalidate`` from
+        ``_apply_newly_ids`` / ``_apply_newly_mask``, ``_on_register``'s
+        displaced computed): a bool for one node id, a mask for an id
+        array. An invalidation that arrives for such a node, through
+        whichever hook (``_on_invalidated`` for the computed; for its
+        ``mark_row_stale`` handler the table's ``on_invalidate`` hooks, the
+        bound block's ``on_inv`` and the service's table → scalar probe),
+        is the wave's own echo: it journals nothing and reaches no node.
+
+        Dropping it is the same work, not less of it: (1) a node handed to
+        ``_eager_invalidate`` is in the wave's newly set, so the wave
+        marked it on the device and expanded through it; (2) non-seed
+        invalid nodes block, so a union wave seeded at it reaches nothing
+        the first wave did not mark or find invalid; (3) ``run_icasc``
+        re-applies no seed and restores ``was_clear``, so the echo's wave
+        changed no bit, no count (its ``total`` was 0), no table row, and
+        fired no handler. The one exception the echo had: rows declared
+        (``declare_row_edges``) as dependents of a row AFTER a wave
+        invalidated it and BEFORE the next flush were reached by the echo
+        when, and only when, that row had a watched twin; for any other
+        invalid row they never were, and now they are not for any row.
+        The displaced computed's echo did harm besides: it arrives when the
+        registry already holds the NEW version, so the table → scalar probe
+        told that one to invalidate itself once computed, and the ``icasc``
+        landed behind the new version's ``bump`` and ``epack``: a twin
+        re-read locally after a wave came back invalid (ROADMAP D11)."""
+        applying = self._applying_ids
+        if isinstance(nids, np.ndarray):
+            if not applying:
+                return np.zeros(nids.shape, dtype=bool)
+            return np.isin(nids, list(applying))
+        return nids in applying
+
     def _on_invalidated(self, computed: "Computed") -> None:
         nid = getattr(computed, "_backend_nid", None)
-        if nid is not None and nid in self._applying_ids:
+        if nid is not None and self.is_wave_echo(nid):
             return  # the device already knows — this IS a wave application
         with self._lock:
             nid = self._id_by_input.get(computed.input)
@@ -814,6 +855,16 @@ class TpuGraphBackend:
                 ids64 = ids64[ids64 < _blk.n_rows]
             if ids64.size == 0:
                 return
+            nids = (_blk.base + ids64).astype(np.int32)
+            echo = self.is_wave_echo(nids)
+            if echo.any():
+                # the wave's own application, arriving through the twin's
+                # mark_row_stale handler: the table has marked its row, the
+                # device has nothing to learn
+                self.wave_echo_marks_dropped += int(echo.sum())
+                nids = nids[~echo]
+                if nids.size == 0:
+                    return
             with self._lock:
                 # icasc, not a bare mark: a host-led table invalidation must
                 # CASCADE through the declared row topology (which exists
@@ -821,7 +872,7 @@ class TpuGraphBackend:
                 # always walks dependents, Computed.cs Invalidate). flush
                 # runs the expansion wave in journal order, so a refresh
                 # that follows still clears exactly its own rows.
-                self._journal.append(("icasc", (_blk.base + ids64).astype(np.int32)))
+                self._journal.append(("icasc", nids))
 
         def on_ref(ids_np, _blk=blk):
             ids64 = np.asarray(ids_np, np.int64)

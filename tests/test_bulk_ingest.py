@@ -7,6 +7,7 @@ registrations one ``Register`` call at a time
 equivalent, with scalar ``@compute_method`` nodes adopting row node ids so
 the two views cascade as one logical node."""
 import numpy as np
+import pytest
 
 from stl_fusion_tpu.core import (
     ComputeService,
@@ -471,5 +472,143 @@ async def test_cascade_rows_batch_seq_matches_sequential_hub_level():
         assert counts.tolist() == [50, 50, 0]
         assert table.stale_count() == 100
         assert bool(table._stale_host[100]) and not bool(table._stale_host[99])
+    finally:
+        set_default_hub(old)
+
+
+# ------------------------------------------------- the wave's echo (ISSUE 31)
+def icasc_ids(backend):
+    parts = [p for kind, p in backend._journal if kind == "icasc"]
+    return sorted(set(np.concatenate(parts).tolist())) if parts else []
+
+
+async def watched_twins(svc, rows, hits):
+    """Scalar twins of ``rows`` with an invalidation observer each: the
+    eager tier of the next wave that reaches them."""
+    twins = [await capture(lambda r=r: svc.val(r)) for r in rows]
+    for twin in twins:
+        twin.on_invalidated(hits.append)
+    return twins
+
+
+async def test_a_wave_over_watched_twins_journals_nothing():
+    """The application of a wave is no host-led invalidation: the watched
+    twins' table marks (``mark_row_stale`` -> ``table.invalidate`` ->
+    ``on_inv``) are dropped and counted, the rows are stale on the table all
+    the same, and the next flush finds nothing to do."""
+    hub, backend, svc, table, block = bound_chain()
+    old = set_default_hub(hub)
+    try:
+        table.read_batch(np.arange(64))
+        hits: list = []
+        twins = await watched_twins(svc, [50, 51, 52], hits)
+        unwatched = await capture(lambda: svc.val(53))
+        backend.flush()
+        version = table.version
+        assert backend.cascade_rows_batch(block, [50]) == 14
+        assert hits == twins  # the eager tier fired, in wave order
+        assert not unwatched.is_consistent  # the lazy tier: pending
+        assert backend._journal == []
+        assert backend.wave_echo_marks_dropped == 3  # ids, one a twin
+        assert backend._collect_metrics()["fusion_wave_echo_marks_dropped_total"] == 3
+        # the table's own side of the mark is what it was
+        np.testing.assert_array_equal(np.nonzero(table._stale_host)[0], np.arange(50, 64))
+        assert table.version == version + 1 + 3  # the wave's mark, then one a twin
+        mask = np.asarray(backend.graph.invalid_mask()).copy()
+        waves, total = backend.graph.lat_waves, backend.device_invalidations
+        backend.flush()
+        np.testing.assert_array_equal(np.asarray(backend.graph.invalid_mask()), mask)
+        assert (backend.graph.lat_waves, backend.device_invalidations) == (waves, total)
+    finally:
+        set_default_hub(old)
+
+
+@pytest.mark.parametrize(
+    "kind", ["table_invalidate", "invalidating_replay", "invalidate_all", "lazy_materialization"]
+)
+async def test_host_led_marks_journal_their_cascade_as_before(kind):
+    """What is NOT a wave's own application keeps its ``icasc``."""
+    hub, backend, svc, table, block = bound_chain()
+    old = set_default_hub(hub)
+    try:
+        table.read_batch(np.arange(64))
+        twin = await capture(lambda: svc.val(30))
+        backend.flush()
+        if kind == "table_invalidate":
+            table.invalidate([5, 30])
+            want = [5, 30]
+        elif kind == "invalidating_replay":
+            with invalidating():
+                await svc.val(30)
+            want = [30]
+        elif kind == "invalidate_all":
+            table.invalidate_all()
+            want = list(range(64))
+        else:
+            # an unwatched twin the wave left pending, materialized by a
+            # host-led invalidate outside any wave application
+            assert backend.cascade_rows_batch(block, [29]) == 35
+            assert backend._journal == []
+            twin.invalidate()
+            want = [30]
+        assert icasc_ids(backend) == want
+        assert backend.wave_echo_marks_dropped == 0
+        assert not twin.is_consistent
+        backend.flush()
+        assert np.asarray(backend.graph.invalid_mask())[min(want):64].all()
+    finally:
+        set_default_hub(old)
+
+
+async def test_a_handler_marking_another_row_during_application_still_journals():
+    """Only the node being applied is an echo: a mixed batch from inside a
+    watched twin's handler loses that node's id and keeps the other."""
+    hub, backend, svc, table, block = bound_chain()
+    old = set_default_hub(hub)
+    try:
+        table.read_batch(np.arange(64))
+        twin = await capture(lambda: svc.val(60))
+        twin.on_invalidated(lambda _c: table.invalidate([60, 3]))
+        backend.flush()
+        assert backend.cascade_rows_batch(block, [60]) == 4
+        assert len(backend._journal) == 1 and icasc_ids(backend) == [3]
+        assert backend.wave_echo_marks_dropped == 2  # row 60 twice: its own mark, the handler's
+        backend.flush()
+        assert np.asarray(backend.graph.invalid_mask())[3:64].all()
+    finally:
+        set_default_hub(old)
+
+
+@pytest.mark.parametrize("watched", [True, False])
+async def test_rows_declared_under_an_invalid_row_wait_for_the_next_wave(watched):
+    """The one observable difference of dropping the echo (ISSUE 31): a row
+    declared as a dependent of a row AFTER a wave invalidated it and BEFORE
+    the next flush is not invalidated by that flush, whether the invalid
+    row has a watched twin or not (the echo's ``icasc`` used to reach it for
+    a watched one alone). The edge is live: the next wave through the row
+    reaches it."""
+    hub = FusionHub()
+    backend = TpuGraphBackend(hub, node_capacity=256, edge_capacity=1024)
+    svc = Chain(hub, 64)
+    hub.add_service(svc)
+    table = memo_table_of(svc.val)
+    block = backend.bind_table_rows(table)
+    backend.declare_row_edges(block, np.arange(31), block, np.arange(1, 32))  # 0 -> .. -> 31
+    old = set_default_hub(hub)
+    try:
+        table.read_batch(np.arange(64))
+        twin = await capture(lambda: svc.val(10))
+        if watched:
+            twin.on_invalidated(lambda _c: None)
+        backend.flush()
+        assert backend.cascade_rows_batch(block, [10]) == 22
+        assert twin.is_invalidated
+        backend.declare_row_edges(block, np.array([10]), block, np.array([40]))
+        backend.flush()
+        assert not np.asarray(backend.graph.invalid_mask())[40]
+        assert not table._stale_host[40]
+        assert backend.wave_echo_marks_dropped == (1 if watched else 0)
+        assert backend.cascade_rows_batch(block, [10]) == 1  # row 40, through the new edge
+        assert table._stale_host[40]
     finally:
         set_default_hub(old)

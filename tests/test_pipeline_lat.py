@@ -11,7 +11,9 @@ dependent is recaptured again and again must not fill up), the written-row
 rule ``refresh_block_on_device``'s docstring states, the span sites of the
 write path, and ``ClusterCommander`` end to end against the benchmark's
 plain reference (``benchmarks/lib/servedref.py``: op-log, store, who
-observed what).
+observed what). Since ISSUE 31 also: a wave's own application journals
+nothing on either apply path (ids, mask), so a served command is ONE lat
+wave, and a twin displaced by a local re-read leaves its successor alone.
 """
 import asyncio
 import dataclasses
@@ -48,7 +50,7 @@ sys.path.insert(
     0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
 )
 from lib.hostgraph import HostGraph  # noqa: E402
-from lib.servedref import replay  # noqa: E402
+from lib.servedref import _wave as reference_wave, replay  # noqa: E402
 
 N = 3000
 SRC, DST = power_law_dag(N, avg_degree=3, seed=28)
@@ -286,6 +288,69 @@ def test_counters_reach_the_metrics_collector():
     assert got["fusion_pipeline_lat_overflow_waves_total"] == 0
 
 
+# ------------------------------------------------- the wave's echo (ISSUE 31)
+@pytest.mark.parametrize("route", ["lat_ids", "chain_mask"])
+async def test_applying_a_wave_journals_nothing_on_either_apply_path(route):
+    """Watched twins on a wave's rows, the wave through the pipeline's lat
+    route (``_apply_newly_ids``) and through the fused chain
+    (``_apply_newly_mask``): the eager tier fires, the table marks of its
+    twins are dropped and counted, the journal stays empty, and the flush
+    that follows runs no wave and changes no bit."""
+    hub, backend, svc, table, block = make_stack(lat=route == "lat_ids")
+    row = int(np.flatnonzero(np.bincount(SRC, minlength=N)[N // 2:] >= 2)[0] + N // 2)
+    closure = sorted(HostGraph(SRC, DST, N).closure_ids([row]))
+    watched_rows = closure[:3]
+    hits: list = []
+    for r in watched_rows:
+        twin = await capture(lambda r=r: svc.node(r))
+        twin.on_invalidated(lambda c: hits.append(c.input.args[0]))
+    backend.flush()
+    pipe = hub.enable_nonblocking(fuse_depth=2)
+    ticket = pipe.submit_rows(block, [row])
+    pipe.drain()
+    assert ticket.count == len(closure)
+    assert sorted(hits) == sorted(watched_rows)
+    assert backend._journal == []
+    assert backend.wave_echo_marks_dropped == len(watched_rows)
+    stats = pipe.stats()
+    assert (stats["lat_waves"], stats["fused_dispatches"]) == ((1, 0) if route == "lat_ids" else (0, 1))
+    assert np.flatnonzero(~np.asarray(table.valid_mask)).tolist() == closure
+    mask = np.asarray(backend.graph.invalid_mask()).copy()
+    waves, total = backend.graph.lat_waves, backend.device_invalidations
+    backend.flush()
+    assert np.array_equal(np.asarray(backend.graph.invalid_mask()), mask)
+    assert (backend.graph.lat_waves, backend.device_invalidations) == (waves, total)
+    assert not [r for r in backend.profiler.recent() if r["kind"] == "icasc"]
+    pipe.dispose()
+
+
+async def test_a_twin_displaced_by_a_local_reread_leaves_its_successor_alone():
+    """ROADMAP D11's recipe: capture, cascade, capture again, flush. The
+    first twin is unwatched, so the wave leaves it pending and the re-read's
+    registration materializes it AFTER the registry holds the new version.
+    Its ``mark_row_stale`` is the wave's echo: it must neither tell the new
+    version to invalidate itself once computed (the table -> scalar probe)
+    nor journal an ``icasc`` behind the new version's ``bump`` and
+    ``epack``."""
+    _hub, backend, svc, table, block = make_stack(lat=True)
+    row = shallow_rows(1, seed=11)[0]
+    nid = block.base + row
+    first = await capture(lambda: svc.node(row))
+    backend.flush()
+    assert backend.cascade_rows_batch(block, [row]) >= 1
+    assert not first.is_consistent and backend._journal == []
+    second = await capture(lambda: svc.node(row))
+    assert [kind for kind, _p in backend._journal] == ["bump", "epack"]
+    assert backend.wave_echo_marks_dropped == 1
+    backend.flush()
+    assert first.is_invalidated and second.is_consistent and second is not first
+    assert not backend.graph._h_invalid[nid]
+    assert not np.asarray(backend.graph.invalid_mask())[nid]
+    assert not np.asarray(table.valid_mask)[row]  # the written-row rule: stale on the table
+    assert backend.cascade_rows_batch(block, [row]) >= 1  # ... and the next wave counts it again
+    assert not second.is_consistent
+
+
 # ------------------------------------------------------------ lat dead slots
 async def test_lat_patcher_reuses_dead_slots():
     """A row whose dependent's scalar twin is recaptured over and over (a
@@ -441,7 +506,10 @@ async def test_commander_end_to_end_equals_the_plain_reference(seed):
     """Commands through ``ClusterCommander.call``, watched over RPC,
     re-read on invalidation, nothing reset: op-log, store, who observed
     what, every re-read value, every wave's newly count and the table's
-    stale mask equal ``lib/servedref.py``'s replay of the same events."""
+    stale mask equal ``lib/servedref.py``'s replay of the same events, the
+    device's invalid mask equals the reference's wave rule replayed over
+    them, and a command is ONE lat wave: the eager tier's table marks are
+    dropped, the re-reads journal ``bump`` and ``epack`` alone."""
     s = await served_stack(n_clients=3)
     backend, svc, table, cc = s["backend"], s["svc"], s["table"], s["cc"]
     graph = HostGraph(SRC, DST, N)
@@ -470,6 +538,7 @@ async def test_commander_end_to_end_equals_the_plain_reference(seed):
         events.append(("cmd", op, row, delta))
         assert s["log"].contains(op)  # journaled before anything fans out
         assert not observed
+        assert "icasc" not in {kind for kind, _p in backend._journal}
         got_counts.append(cc.drain())
         want_now = replay(graph, subs, events).observers[-1]
         await until(lambda: len(observed) >= len(want_now))
@@ -495,4 +564,19 @@ async def test_commander_end_to_end_equals_the_plain_reference(seed):
     assert stats["lat_waves"] == 24 and stats["eager_waves"] == 0
     assert stats["fused_dispatches"] == 0 and stats["chain_faults"] == 0
     assert backend.graph._topo_mirror["lat"] is not None
+    # one wave a command, none for the flush of its re-reads
+    backend.flush()
+    assert backend.graph.lat_waves == 24 and backend.waves_run == 24
+    assert not [r for r in backend.profiler.recent() if r["kind"] == "icasc"]
+    assert backend.wave_echo_marks_dropped == sum(
+        len({key for _ci, key in seen}) for seen in want.observers)
+    invalid: set = set()
+    for event in events:
+        if event[0] == "cmd":
+            invalid |= reference_wave(graph, int(event[2]), invalid, None)
+        else:
+            invalid.discard(int(event[2]))
+    base = s["block"].base
+    for mask in (backend.graph._h_invalid, np.asarray(backend.graph.invalid_mask())):
+        assert set(np.flatnonzero(mask[base:base + N]).tolist()) == invalid
     await s["close"]()
