@@ -2049,15 +2049,25 @@ class TpuGraphBackend:
             entry = self.routed_mirror()
         graph = entry["graph"]
         dg = self.graph
-        self._routed_sync(entry)
+        with hot_span("routed.sync"):
+            self._routed_sync(entry)
         cause, wave_seq = self._begin_wave()
         t0 = time.perf_counter()
         levels0 = graph.levels_total
         count, newly_ids, overflow = graph.run_wave_collect(seeds)
         if overflow:
-            newly = graph.invalid_mask() & ~dg._h_invalid[: graph.n_nodes]
-            newly_ids = np.nonzero(newly)[0].astype(np.int32)
-        dg.mark_invalid(newly_ids)
+            # the closure outgrew the compacted id buffers: the whole mask
+            # comes back instead (40 MB at 40 M nodes), counted
+            global_metrics().counter(
+                "fusion_mesh_routed_overflows_total",
+                help="routed union waves whose closure overflowed the "
+                "compacted newly-id buffers and read the whole mask back",
+            ).inc()
+            with hot_span("routed.mask_fetch"):
+                newly = graph.invalid_mask() & ~dg._h_invalid[: graph.n_nodes]
+                newly_ids = np.nonzero(newly)[0].astype(np.int32)
+        with hot_span("routed.mark"):
+            dg.mark_invalid(newly_ids)
         entry["invalid_version"] = dg.invalid_version
         t1 = time.perf_counter()
         levels = graph.levels_total - levels0

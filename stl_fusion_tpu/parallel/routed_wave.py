@@ -82,7 +82,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..cluster.placement import DevicePlacement, PlacementError
 from ..diagnostics.mesh_telemetry import current_dispatch_cause, global_mesh_trace
 from ..diagnostics.metrics import global_metrics, next_wave_seq
-from ..diagnostics.tracing import wave_shaped_cause
+from ..diagnostics.tracing import hot_span, wave_shaped_cause
+from ..graph.program_cache import time_program_warm
 from .mesh import GRAPH_AXIS, graph_mesh, shard_map_compat
 
 __all__ = ["RoutedShardedGraph", "build_routed_wave", "record_level_stall_ms"]
@@ -1167,22 +1168,32 @@ class RoutedShardedGraph:
                 raise PlacementError("seed node lands on an off-mesh shard")
             rows[:k] = r
         capd = max(cap // self.n_dev, 1024)
-        fn = self._collect_cache.get((capd, width))
-        if fn is None:
-            fn = self._build_collect(capd)
-            self._collect_cache[(capd, width)] = fn
         cause = self._trace_cause_for_dispatch()
         t0 = time.perf_counter()
-        self.g_invalid, counts, levels, spec, bufs = fn(
-            self._host_arg(rows), self.g_send, self.g_hsend, self.g_eprod,
-            self.g_ebslot, self.g_ebit, self.g_edst, self.g_elsrc, self.g_eep,
-            self.g_node_epoch, self.g_invalid, self.g_is_real,
-        )
-        self._sync(self.g_invalid, counts, levels, spec, bufs)
-        counts = self._fetch(counts)
-        levels = self._fetch(levels)
-        spec = self._fetch(spec)
-        bufs = self._fetch(bufs)
+        with hot_span("routed.dispatch"):
+            args = (
+                self._host_arg(rows), self.g_send, self.g_hsend, self.g_eprod,
+                self.g_ebslot, self.g_ebit, self.g_edst, self.g_elsrc, self.g_eep,
+                self.g_node_epoch, self.g_invalid, self.g_is_real,
+            )
+            fn = self._collect_cache.get((capd, width))
+            if fn is None:
+                fn = self._build_collect(capd)
+                self._collect_cache[(capd, width)] = fn
+                # the compile alone, apart from the wave that follows it:
+                # program_warm_report() then says what a cold start owes it
+                with time_program_warm(
+                    "routed_collect",
+                    key=(self.n_global, self.n_dev, self.exchange, capd, width),
+                ):
+                    fn.lower(*args).compile()
+            self.g_invalid, counts, levels, spec, bufs = fn(*args)
+        with hot_span("routed.readback"):
+            self._sync(self.g_invalid, counts, levels, spec, bufs)
+            counts = self._fetch(counts)
+            levels = self._fetch(levels)
+            spec = self._fetch(spec)
+            bufs = self._fetch(bufs)
         self.waves_run += 1
         self._count_exchange(int(levels), int(spec))
         count = int(counts.sum())
