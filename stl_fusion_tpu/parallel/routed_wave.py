@@ -34,9 +34,13 @@ frontier with mesh collectives instead of surfacing to the host:
 - ``exchange="gather"``: plain ``lax.all_gather`` of packed words — the
   reference for equivalence tests.
 
-Per level, after the exchange: local row gather (``node_epoch[dst]`` —
-device-local by construction, the reason edges shard by destination),
-version-masked fire, local scatter, and a ``psum`` for the continuation
+Once a wave, before the level loop: the version check, one local row
+gather (``node_epoch[dst] == edge epoch`` — device-local by construction,
+the reason edges shard by destination), folded into the scatter's index (a
+stale slot or a pad points at the row the scatter drops), and the per-edge
+word index. Per level, after the exchange: ONE indexed read over the
+shard's edge slots (the source word), one local scatter, the
+already-invalid mask on the node rows, and a ``psum`` for the continuation
 flag. The while_loop carries the flag, so no collective runs in ``cond``.
 
 The **chain faces** (:meth:`RoutedShardedGraph.dispatch_union_chain` /
@@ -259,26 +263,31 @@ def build_routed_wave(
             hstep *= 2
         return intra, acc.reshape(-1)  # [n_hosts(H) * n_hosts(G) * hcap]
 
-    def _lookup(intra_flat, cross_flat, send_idx_l, hsend_idx_l, eprod_l, ebslot_l):
-        """Per-edge source word via the cap-independent (eprod, ebslot)
-        routing. Capacities come from trace-time table shapes — the hook
-        dynamic bucket growth hangs off."""
+    def _word_lookup(send_idx_l, hsend_idx_l, eprod_l, ebslot_l):
+        """``lookup(intra_flat, cross_flat) -> per-edge source word`` over
+        what :func:`_exchange_words` returns, via the cap-independent
+        (eprod, ebslot) routing. The index arithmetic runs here, once a
+        wave; a level pays the indexed read alone. Capacities come from
+        trace-time table shapes — the hook dynamic bucket growth hangs
+        off."""
         if exchange in ("tree", "gather"):
-            return intra_flat[ebslot_l]
+            return lambda intra_flat, _cross: intra_flat[ebslot_l]
+        icap = send_idx_l.shape[-1]
         if exchange == "a2a":
-            icap = send_idx_l.shape[-1]
-            return intra_flat[eprod_l * icap + ebslot_l]
+            idx = eprod_l * icap + ebslot_l
+            return lambda intra_flat, _cross: intra_flat[idx]
         # hier: intra edges read the subgroup-a2a rows; cross edges read
         # the (producer host, consumer host) bucket of the host tree
-        icap = send_idx_l.shape[-1]
         hcap = hsend_idx_l.shape[-1]
         g = lax.axis_index(HOST_AXIS)
         is_cross = eprod_l >= n_dev
-        idx_i = (eprod_l % dph) * icap + ebslot_l
-        idx_c = ((eprod_l - n_dev) * n_hosts + g) * hcap + ebslot_l
-        w_i = intra_flat[jnp.where(is_cross, 0, idx_i)]
-        w_c = cross_flat[jnp.where(is_cross, idx_c, 0)]
-        return jnp.where(is_cross, w_c, w_i)
+        idx_i = jnp.where(is_cross, 0, (eprod_l % dph) * icap + ebslot_l)
+        idx_c = jnp.where(
+            is_cross, ((eprod_l - n_dev) * n_hosts + g) * hcap + ebslot_l, 0
+        )
+        return lambda intra_flat, cross_flat: jnp.where(
+            is_cross, cross_flat[idx_c], intra_flat[idx_i]
+        )
 
     @shard_map_compat(
         mesh=mesh,
@@ -295,20 +304,31 @@ def build_routed_wave(
         count0 = lax.psum(fresh.sum(dtype=jnp.int32), ax)
         go0 = lax.psum(seeds_l.any().astype(jnp.int32), ax) > 0
 
+        # once a wave, outside the level loop: nothing here changes while a
+        # wave runs. A slot whose captured epoch is behind its destination's
+        # (or a pad: eepoch -1 never matches, the gather clamps) points at
+        # the out-of-range row the scatter drops, so the version check costs
+        # a level nothing.
+        live = nepoch_l[edst_l] == eepoch_l
+        edst_live = jnp.where(live, edst_l, n_local)
+        lookup = _word_lookup(send_idx_l, hsend_idx_l, eprod_l, ebslot_l)
+        ebit_u = ebit_l.astype(jnp.uint32)
+
         def merge_fire(frontier, inv):
             """One global exchange of ``frontier`` + a fire over EVERY
-            edge against it (shared by the sync per-level step and the
-            async merge epoch)."""
+            edge slot against it: one indexed read (the source word) and
+            one scatter. The already-invalid rule is a fact of the
+            destination ROW, so it is applied on the node rows after the
+            scatter — ``max(a & ~inv[dst]) == max(a) & ~inv``, bit for
+            bit. Shared by the sync per-level step and the async merge
+            epoch."""
             intra_flat, cross_flat = _exchange_words(
                 frontier, send_idx_l, hsend_idx_l
             )
-            word = _lookup(
-                intra_flat, cross_flat, send_idx_l, hsend_idx_l, eprod_l, ebslot_l
-            )
-            src_active = ((word >> ebit_l.astype(jnp.uint32)) & 1).astype(bool)
-            ver_ok = nepoch_l[edst_l] == eepoch_l  # gather clamps; -1 never matches
-            fire = src_active & ver_ok & ~inv[edst_l]
-            return jnp.zeros_like(frontier).at[edst_l].max(fire)  # OOB pads dropped
+            word = lookup(intra_flat, cross_flat)
+            src_active = ((word >> ebit_u) & 1).astype(bool)
+            hit = jnp.zeros_like(frontier).at[edst_live].max(src_active)
+            return hit & ~inv
 
         if async_depth and async_depth > 0:
             # ---- asynchronous mode: speculative local levels between
@@ -318,9 +338,7 @@ def build_routed_wave(
                 # local-only expansion: a remote-sourced edge's elsrc is
                 # the pad row → fill False, so it simply waits for a merge
                 src_active = f.at[elsrc_l].get(mode="fill", fill_value=False)
-                ver_ok = nepoch_l[edst_l] == eepoch_l
-                fire = src_active & ver_ok & ~inv[edst_l]
-                nxt = jnp.zeros_like(f).at[edst_l].max(fire)
+                nxt = jnp.zeros_like(f).at[edst_live].max(src_active) & ~inv
                 return (
                     nxt, inv | nxt, acc | nxt,
                     newly_l + nxt.sum(dtype=jnp.int32),

@@ -19,6 +19,7 @@ import pytest
 from stl_fusion_tpu.cluster import DevicePlacement, ShardMap
 from stl_fusion_tpu.graph.synthetic import power_law_dag
 from stl_fusion_tpu.parallel import RoutedShardedGraph, graph_mesh
+from test_routed_wave import EXCHANGES, LEVEL_RULE_CASES, check_level_rules
 
 
 def bfs_closure(adj, seeds):
@@ -85,6 +86,14 @@ def test_async_matches_sync_and_host_bfs(depth):
     st = g_a.stats()
     assert st["exchange_async"] is True and st["async_depth"] == depth
     assert st["quiescence_checks"] == g_a.quiescence_checks
+
+
+@pytest.mark.parametrize("case", LEVEL_RULE_CASES)
+@pytest.mark.parametrize("exchange", EXCHANGES)
+def test_async_level_rules_match_host_levels(exchange, case):
+    """ISSUE 33: the speculative levels and the merge epoch share the
+    hoisted version check and the node-row invalid mask."""
+    check_level_rules(exchange, case, async_depth=2)
 
 
 def test_async_deep_chain_reclaims_barriers_strictly():

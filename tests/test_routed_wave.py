@@ -150,6 +150,184 @@ def test_routed_wave_matches_bfs_oracle(exchange):
     assert g.levels_total > 0  # collective exchange rounds were counted
 
 
+# ------------------------------------------------- the level's two rules
+EXCHANGES = ["a2a", "tree", "gather", "hier"]
+LEVEL_RULE_CASES = ["stale_and_invalid", "invalid_seed_conducts", "bump_between_waves"]
+
+
+class HostLevels:
+    """The routed wave's rules on the host, level by level: an edge
+    conducts only while its captured epoch equals its destination's node
+    epoch; an already-invalid destination is neither counted nor re-lit;
+    a seed conducts even when it is already invalid (the union rule)."""
+
+    def __init__(self, src, dst, n, invalid=None):
+        self.src = np.asarray(src, dtype=np.int64)
+        self.dst = np.asarray(dst, dtype=np.int64)
+        self.eep = np.zeros(len(self.src), dtype=np.int32)
+        self.nepoch = np.zeros(n, dtype=np.int32)
+        self.inv = np.zeros(n, dtype=bool) if invalid is None else invalid.copy()
+
+    def patch(self, bump_ids, add_u, add_v, add_ep):
+        np.add.at(self.nepoch, np.asarray(bump_ids, dtype=np.int64), 1)
+        self.src = np.concatenate([self.src, np.asarray(add_u, dtype=np.int64)])
+        self.dst = np.concatenate([self.dst, np.asarray(add_v, dtype=np.int64)])
+        self.eep = np.concatenate([self.eep, np.asarray(add_ep, dtype=np.int32)])
+
+    def wave(self, seeds, versions=True, skip_invalid=True, commit=True):
+        """(count, levels, newly ids ascending, mask after)."""
+        live = self.nepoch[self.dst] == self.eep if versions else slice(None)
+        src, dst = self.src[live], self.dst[live]
+        blocked = self.inv if skip_invalid else np.zeros_like(self.inv)
+        frontier = np.zeros_like(self.inv)
+        frontier[np.asarray(seeds, dtype=np.int64)] = True
+        lit = frontier.copy()
+        levels = 0
+        while frontier.any():
+            hit = np.zeros_like(lit)
+            hit[dst[frontier[src]]] = True
+            frontier = hit & ~lit & ~blocked
+            lit |= frontier
+            levels += 1
+        newly = lit & ~self.inv
+        mask = self.inv | lit
+        if commit:
+            self.inv = mask
+        return int(newly.sum()), levels, np.flatnonzero(newly), mask
+
+
+def check_level_rules(exchange, case, async_depth=0):
+    """One case of the level's rules on the device against
+    :class:`HostLevels`: count, compacted newly ids, the mask and (sync
+    mode: an async merge epoch is no BFS level) the number of levels."""
+    n = 4000
+    smap = ShardMap.initial(["a", "b"], n_shards=32)
+    pl = DevicePlacement.build(
+        smap, 8, n, devices_per_host=4 if exchange == "hier" else None
+    )
+    rng = np.random.default_rng(33)
+    pre = np.zeros(n, dtype=bool)
+    if case == "invalid_seed_conducts":
+        # a chain over five shards and a pair; 2005 and 700 invalid before
+        src = np.array([5, 1005, 2005, 3005, 700], dtype=np.int64)
+        dst = np.array([1005, 2005, 3005, 3905, 2700], dtype=np.int64)
+        pre[[2005, 700]] = True
+    else:
+        src, dst, _adj = make_graph(n, seed=7)
+        pre[rng.choice(n, size=300, replace=False)] = True
+    host = HostLevels(src, dst, n, invalid=pre)
+    g = RoutedShardedGraph(
+        src, dst, n, pl, mesh=graph_mesh(), exchange=exchange, invalid=pre,
+        exchange_async=async_depth > 0, async_depth=async_depth,
+    )
+    assert g.exchange == exchange
+
+    def patch(n_bump, n_redeclare):
+        """Bump rows (every in-edge of theirs goes stale) and declare one
+        in-edge of some of them again at the new epoch."""
+        has_in = np.unique(host.dst)
+        bumped = rng.choice(has_in, size=n_bump, replace=False)
+        again = bumped[:n_redeclare]
+        first_in = dict(zip(host.dst[::-1].tolist(), host.src[::-1].tolist()))
+        u = np.array([first_in[v] for v in again.tolist()], dtype=np.int64)
+        ep = host.nepoch[again] + 1
+        host.patch(bumped, u, again, ep)
+        assert g.patch_batch(bumped, u, again, ep.astype(np.int32))
+
+    def wave(seeds):
+        levels0 = g.levels_total
+        want_count, want_levels, want_ids, want_mask = host.wave(seeds)
+        count, ids, over = g.run_wave_collect(seeds)
+        assert not over
+        assert count == want_count
+        assert np.array_equal(np.sort(ids), want_ids)
+        assert np.array_equal(g.invalid_mask(), want_mask)
+        if async_depth:
+            assert 1 <= g.levels_total - levels0 <= want_levels
+        else:
+            assert g.levels_total - levels0 == want_levels
+        return want_count, want_ids, want_mask
+
+    if case == "stale_and_invalid":
+        patch(n_bump=400, n_redeclare=100)
+        seeds = rng.choice(n // 10, size=5, replace=False).tolist()
+        # the scenario tells the rules apart: dropping either changes the mask
+        for off in ("versions", "skip_invalid"):
+            other = host.wave(seeds, commit=False, **{off: False})[3]
+            assert not np.array_equal(other, host.wave(seeds, commit=False)[3])
+        wave(seeds)
+    elif case == "invalid_seed_conducts":
+        # 700 is invalid and conducts to 2700; 2005 is invalid already:
+        # not counted, not lit again, so 3005 and 3905 stay valid
+        count, ids, mask = wave([5, 700])
+        assert count == 3 and ids.tolist() == [5, 1005, 2700]
+        assert np.flatnonzero(mask).tolist() == [5, 700, 1005, 2005, 2700]
+        count, ids, mask = wave([2005])  # as a seed it conducts
+        assert count == 2 and ids.tolist() == [3005, 3905]
+    else:
+        wave(rng.choice(np.arange(n // 2, n), size=5, replace=False).tolist())
+        seeds = rng.choice(n // 10, size=5, replace=False).tolist()
+        before = host.wave(seeds, commit=False)[3]
+        patch(n_bump=400, n_redeclare=100)  # between the waves
+        assert not np.array_equal(before, host.wave(seeds, commit=False)[3])
+        wave(seeds)
+        patch(n_bump=200, n_redeclare=200)  # every bumped row declared again
+        wave(rng.choice(n // 10, size=5, replace=False).tolist())
+
+
+@pytest.mark.parametrize("case", LEVEL_RULE_CASES)
+@pytest.mark.parametrize("exchange", EXCHANGES)
+def test_level_rules_match_host_levels(exchange, case):
+    """ISSUE 33: the version check is hoisted out of the level loop (once
+    a wave, not once a graph) and the already-invalid mask is applied on
+    node rows after the scatter; both rules hold on every exchange."""
+    check_level_rules(exchange, case)
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (list, tuple)) else (v,):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _eqns(sub)
+
+
+def test_level_loop_reads_each_edge_slot_once():
+    """ISSUE 33, structural: the ``while`` body of the a2a sync wave holds
+    exactly ONE gather indexed per edge slot (the source word). A per-edge
+    row lookup put back inside the level fails here, not in a benchmark."""
+    import jax
+
+    n = 4000
+    src, dst, _adj = make_graph(n)
+    pl = DevicePlacement.build(ShardMap.initial(["a", "b"], n_shards=32), 8, n)
+    g = RoutedShardedGraph(src, dst, n, pl, mesh=graph_mesh(), exchange="a2a")
+    assert g.e_cap not in (g.n_local, g.w_local, g.g_send.shape[-1])
+    jaxpr = jax.make_jaxpr(g._wave)(
+        g.g_invalid, g.g_send, g.g_hsend, g.g_eprod, g.g_ebslot, g.g_ebit,
+        g.g_edst, g.g_elsrc, g.g_eep, g.g_node_epoch, g.g_invalid,
+    )
+    loops = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "while"]
+    assert len(loops) == 1  # the level loop, one program
+    body = loops[0].params["body_jaxpr"].jaxpr
+    per_edge = [
+        e for e in _eqns(body)
+        if e.primitive.name == "gather" and e.invars[1].aval.shape[0] == g.e_cap
+    ]
+    assert len(per_edge) == 1
+    # and it reads the exchanged words, not a node-row table
+    assert per_edge[0].invars[0].aval.dtype == np.uint32
+    scatters = [e for e in _eqns(body) if e.primitive.name.startswith("scatter")]
+    assert [e.invars[1].aval.shape[0] for e in scatters] == [g.e_cap]
+
+
 @pytest.mark.parametrize("dph", [2, 4])
 def test_hier_exchange_matches_bfs_oracle_and_counts_cross_words(dph):
     """ISSUE 15 tentpole: the hierarchical two-stage exchange (intra-host
