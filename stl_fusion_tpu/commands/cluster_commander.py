@@ -259,19 +259,20 @@ class ClusterCommander:
             "fusion_cmd_forwarded_total",
             "commands forwarded to a remote shard owner over RPC",
         ).inc()
-        if getattr(self.rpc_hub, "call_router", None) is not None:
-            # routed path: the hub stamps @shard/@epoch headers and the
-            # router fails fast (ShardMovedError) on an unreachable owner —
-            # commands never fail over to a replica. The deadline covers the
-            # peer that dies with the call in flight (no reply, no error).
+        with hot_span("cmd.forward"):  # envelope out to reply in
+            if getattr(self.rpc_hub, "call_router", None) is not None:
+                # routed path: the hub stamps @shard/@epoch headers and the
+                # router fails fast (ShardMovedError) on an unreachable owner —
+                # commands never fail over to a replica. The deadline covers the
+                # peer that dies with the call in flight (no reply, no error).
+                return await asyncio.wait_for(
+                    self.rpc_hub.call(self.service, "call", (envelope,)),
+                    self.call_timeout_s,
+                )
             return await asyncio.wait_for(
-                self.rpc_hub.call(self.service, "call", (envelope,)),
+                self.rpc_hub.call(self.service, "call", (envelope,), peer_ref=owner),
                 self.call_timeout_s,
             )
-        return await asyncio.wait_for(
-            self.rpc_hub.call(self.service, "call", (envelope,), peer_ref=owner),
-            self.call_timeout_s,
-        )
 
     # ------------------------------------------------------------- execution
     async def execute_local(self, command: Any, operation_id: str) -> Any:

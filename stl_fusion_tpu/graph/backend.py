@@ -46,7 +46,7 @@ import numpy as np
 from ..diagnostics.flight_recorder import RECORDER
 from ..diagnostics.metrics import WaveProfiler, global_metrics, next_wave_seq
 from ..diagnostics.tracing import CAUSE_PREFIX, current_span, hot_span, span_cause_id
-from .device_graph import DeviceGraph
+from .device_graph import DeviceGraph, array_placement, run_on_device
 
 if TYPE_CHECKING:
     from ..core.computed import Computed
@@ -263,9 +263,27 @@ class _RefreshChainTicket:
 
 
 class TpuGraphBackend:
-    def __init__(self, hub: "FusionHub", node_capacity: int = 4096, edge_capacity: int = 16384):
+    """The hub's graph on a device (module docstring). ``device`` is where
+    everything this backend owns lives: the :class:`DeviceGraph`'s arrays
+    and mirrors, every table bound with :meth:`bind_table_rows` (its values,
+    its validity mask, the loader arguments its refresh makes) and every
+    argument a wave, a patch or a refresh stages. ``None`` (the one-chip
+    deployments) names no device anywhere: arrays lie where JAX's default
+    placement puts them, ``jax.devices()[0]``. With a ``jax.Device``, one
+    process can hold one backend per chip, each hub's waves on its own."""
+
+    def __init__(self, hub: "FusionHub", node_capacity: int = 4096,
+                 edge_capacity: int = 16384, device=None):
         self.hub = hub
-        self.graph = DeviceGraph(node_capacity, edge_capacity)
+        self.device = device
+        self.graph = DeviceGraph(node_capacity, edge_capacity, device=device)
+        if device is not None:
+            # the entries that reach JAX without going through the graph's
+            # own (placed) methods: the table programs and the loader's args
+            run_on_device(self, device, (
+                "refresh_block_on_device", "warm_block_on_device",
+                "_block_refresh_state",
+            ))
         self._lock = threading.Lock()
         self._id_by_input: Dict["ComputedInput", int] = {}
         self._computed_by_id: Dict[int, "weakref.ref[Computed]"] = {}
@@ -345,6 +363,16 @@ class TpuGraphBackend:
         hub.invalidated_hooks.append(self._on_invalidated)
         hub.attach_graph_backend(self)
         global_metrics().register_collector(self, TpuGraphBackend._collect_metrics)
+
+    def device_layout(self) -> dict:
+        """Where each resident array of this backend lies
+        (:meth:`DeviceGraph.device_layout`'s form): the graph's and, as
+        ``table<i>.values`` / ``table<i>.valid``, every bound table's."""
+        layout = self.graph.device_layout()
+        for i, blk in enumerate(self._row_blocks):
+            layout[f"table{i}.values"] = array_placement(blk.table._values)
+            layout[f"table{i}.valid"] = array_placement(blk.table._valid_dev)
+        return layout
 
     def _collect_metrics(self) -> dict:
         """Pull-time gauges for /metrics (weak-registered — a dead backend
@@ -835,6 +863,8 @@ class TpuGraphBackend:
                         f"table already bound with {existing.n_rows} rows"
                     )
                 return existing
+            if self.device is not None:
+                table.place_on(self.device)
             base = self.graph.n_nodes
             self.graph.add_nodes(n)
             self._ensure_host_masks()
@@ -1162,7 +1192,8 @@ class TpuGraphBackend:
                 return fn(ids, *largs), jnp.ones(n_rows, dtype=jnp.bool_)
 
             block._dev_refresh["warm"] = prog
-        table._values, table._valid_dev = prog(*loader_args)
+        # the loader's arguments are staged, not resident: commit the outputs
+        table._values, table._valid_dev = self.graph.commit(prog(*loader_args))
         table._valid_dev_dirty = False
         table._valid_pending.clear()
         table._valid_pending_n = 0

@@ -159,11 +159,55 @@ def check_structure_cache(entry: dict, struct_version: int, fp_fn) -> bool:
     return False
 
 
+def run_on_device(obj, device, names) -> None:
+    """Rebind ``obj``'s named SYNCHRONOUS methods so that each runs under
+    ``jax.default_device(device)``: every array a call stages
+    (``jnp.asarray`` of a host buffer, ``zeros``, ``full``, the loader
+    arguments user code makes) then goes to that device directly, never by
+    way of ``jax.devices()[0]``. The scope is thread-local and closes when
+    the call returns, so it is never held across an ``await``. Called only
+    where a device was given: an object built without one keeps its class's
+    methods untouched (no added call on any path)."""
+    import jax
+
+    def placed(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with jax.default_device(device):
+                return fn(*args, **kwargs)
+
+        return run
+
+    for name in names:
+        setattr(obj, name, placed(getattr(obj, name)))
+
+
+def array_placement(array) -> dict:
+    """``{"devices": [device ids], "committed": bool}`` of one device array:
+    an entry of a ``device_layout()``."""
+    return {
+        "devices": sorted(d.id for d in array.devices()),
+        "committed": bool(array.committed),
+    }
+
+
 class DeviceGraph:
-    def __init__(self, node_capacity: int = 1024, edge_capacity: int = 4096):
+    """The device CSR mirror and its topo/lat mirrors. With ``device=None``
+    every resident array lives where JAX puts uncommitted arrays (the
+    process's default device, ``jax.devices()[0]``) and nothing here names a
+    device. With a ``device``, every resident array (the five graph arrays,
+    both mirrors' tables, the epoch snapshots) is committed to it and every
+    public method runs under :func:`run_on_device`, so staged arguments go
+    to it directly: one process can then hold one graph per chip."""
+
+    def __init__(self, node_capacity: int = 1024, edge_capacity: int = 4096,
+                 device=None):
         import jax.numpy as jnp
 
         self._jnp = jnp
+        #: the ``jax.Device`` this graph's arrays are committed to; None =
+        #: wherever JAX's default placement puts them
+        self.device = device
         self.n_cap = _round_up_pow2(max(node_capacity, 16))
         self.e_cap = _round_up_pow2(max(edge_capacity, 16))
         self.n_nodes = 0  # dense ids [0, n_nodes)
@@ -225,6 +269,47 @@ class DeviceGraph:
         # the topo mirror consumes; an overflowing or broken log marks
         # itself and its owner falls back to a rebuild
         self._aux_delta_logs: list = []
+        if device is not None:
+            run_on_device(self, device, [
+                name for name in dir(type(self))
+                if not name.startswith("_") and callable(getattr(type(self), name))
+            ])
+
+    def commit(self, tree):
+        """``tree`` with every ``jax.Array`` leaf COMMITTED to this graph's
+        device (no copy where it already lies there); as given where there
+        is no device. For the sites that MAKE a resident array: an
+        uncommitted one follows the process's default device the first time
+        something outside :func:`run_on_device`'s scope touches it."""
+        if self.device is None:
+            return tree
+        import jax
+
+        return jax.tree_util.tree_map(
+            lambda x: jax.device_put(x, self.device) if isinstance(x, jax.Array) else x,
+            tree,
+        )
+
+    def device_layout(self) -> dict:
+        """Where each resident array lies: ``{name: {"devices": [device
+        ids], "committed": bool}}`` over the graph arrays and both mirrors'
+        device tables, as far as they are built. A graph given a device
+        shows every entry committed to that device alone; a bring-up check
+        that nothing of a member's state sits on another member's chip."""
+        arrays: dict = {}
+        if self._g is not None:
+            arrays.update((f"g.{k}", v) for k, v in self._g._asdict().items())
+        m = self._topo_mirror
+        if m is not None:
+            arrays.update(
+                (f"topo.{k}", v) for k, v in m["garrays"]._asdict().items()
+            )
+            arrays["topo.node_epoch0"] = m["node_epoch0"]
+            arrays["topo.perm_clipped"] = m["perm_clipped"]
+            if m.get("lat") is not None:
+                arrays["lat.ell_dst"] = m["lat"]["ell_dst"]
+                arrays["lat.ell_epoch"] = m["lat"]["ell_epoch"]
+        return {name: array_placement(a) for name, a in arrays.items()}
 
     MAX_MIRROR_DELTAS = 65536
 
@@ -430,7 +515,9 @@ class DeviceGraph:
             # unpack on device. The packed temp is fresh, so no aliasing.
             n = len(self._h_invalid)
             packed = self._jnp.asarray(_pack_mask_host(self._h_invalid))
-            self._g = self._g._replace(invalid=_unpack_mask_kernel(n)(packed))
+            self._g = self._g._replace(
+                invalid=self.commit(_unpack_mask_kernel(n)(packed))
+            )
             return
         ids = self._jnp.asarray(self._pad_ids_pow2(node_ids))
         self._g = self._g._replace(invalid=self._g.invalid.at[ids].set(value))
@@ -489,13 +576,13 @@ class DeviceGraph:
         timing-dependent). One memcpy per rebuild buys determinism."""
         if self._g is None or self._dirty:
             jnp = self._jnp
-            self._g = GraphArrays(
+            self._g = self.commit(GraphArrays(
                 edge_src=jnp.asarray(self._h_edge_src.copy()),
                 edge_dst=jnp.asarray(self._h_edge_dst.copy()),
                 edge_dst_epoch=jnp.asarray(self._h_edge_dst_epoch.copy()),
                 node_epoch=jnp.asarray(self._h_node_epoch.copy()),
                 invalid=jnp.asarray(self._h_invalid.copy()),
-            )
+            ))
             self._dirty = False
         return self._g
 
@@ -1310,9 +1397,11 @@ class DeviceGraph:
             "n_nodes": n_nodes,
             "n_tot": n_tot,
             "inv_perm": topo.inv_perm,
-            "garrays": garrays if garrays is not None else topo_graph_arrays(topo),
-            "node_epoch0": node_epoch0,
-            "perm_clipped": perm_clipped,
+            "garrays": self.commit(
+                garrays if garrays is not None else topo_graph_arrays(topo)
+            ),
+            "node_epoch0": self.commit(node_epoch0),
+            "perm_clipped": self.commit(perm_clipped),
             "level_starts": topo.level_starts,
             "levels": len(topo.level_starts) - 1,
             # incremental-patch state: host copy of the in-ELL (slot
@@ -1360,7 +1449,7 @@ class DeviceGraph:
             node_epoch_dev = g.node_epoch
         if h_node_epoch is None:
             h_node_epoch = self._h_node_epoch
-        ell_dst_dev = jnp.asarray(lat.ell_dst)
+        ell_dst_dev = self.commit(jnp.asarray(lat.ell_dst))
         if node_epoch_dev.shape[0] == self.n_cap + 1:
             ell_epoch_dev = ell_live_epoch_init(lat.n_real, self.n_cap)(
                 ell_dst_dev, node_epoch_dev
@@ -1369,13 +1458,13 @@ class DeviceGraph:
             # capacity grew between snapshot and install: derive on host
             # from the snapshot epochs and pay the upload (rare — a grow
             # implies new nodes, whose edges break the delta log anyway)
-            ell_epoch_dev = jnp.asarray(
+            ell_epoch_dev = self.commit(jnp.asarray(
                 np.where(
                     lat.ell_dst < lat.n_real,
                     h_node_epoch[np.clip(lat.ell_dst, 0, len(h_node_epoch) - 1)],
                     0,
                 ).astype(np.int32)
-            )
+            ))
         return {
             "n_tot": lat.n_tot,
             "n_real": lat.n_real,

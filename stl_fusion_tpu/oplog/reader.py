@@ -26,7 +26,7 @@ import contextlib
 from dataclasses import dataclass
 
 from ..diagnostics.flight_recorder import RECORDER
-from ..diagnostics.tracing import get_activity_source
+from ..diagnostics.tracing import get_activity_source, hot_span, hot_spans_on
 from ..operations.operation import Operation
 from ..operations.pipeline import batch_cascade_scope
 from ..resilience.events import ResilienceEvents, global_events
@@ -155,6 +155,13 @@ class OperationLogReader(WorkerBase):
         else:
             self.watermark = log_store.last_index() if start_from_end else 0
         self.external_seen = 0
+        #: external operations whose collected invalidations were handed to
+        #: the hub's nonblocking wave pipeline (the road an owner's own
+        #: completion takes), and lane bursts this reader ran where the hub
+        #: has no pipeline (each replays one batch's operations in a whole
+        #: mirror sweep, on the event loop)
+        self.replay_submitted = 0
+        self.replay_lane_bursts = 0
         # reader-lag gauge for /metrics (ISSUE 3): how far this reader's
         # watermark trails the writer's last index — THE cross-host
         # staleness number. Weak-registered; a dead reader drops out.
@@ -172,6 +179,8 @@ class OperationLogReader(WorkerBase):
         return {
             "fusion_oplog_reader_lag": lag,
             "fusion_oplog_external_seen_total": self.external_seen,
+            "fusion_oplog_replay_submitted_total": self.replay_submitted,
+            "fusion_oplog_replay_lane_bursts_total": self.replay_lane_bursts,
             "fusion_oplog_corrupt_seen_total": self.corrupt_seen,
             "fusion_oplog_gaps_seen_total": self.gaps_seen,
         }
@@ -199,18 +208,35 @@ class OperationLogReader(WorkerBase):
     async def read_new(self) -> int:
         """Tail from the watermark; feed EXTERNAL operations to completion.
 
-        When the hub has a TPU graph backend, a batch of external
-        operations lane-packs: each operation's replay COLLECTS its
-        directly-invalidated computeds (``invalidating(sink=...)``) as one
-        group, and the whole batch cascades in one device lane burst
-        (``invalidate_cascade_batch_lanes``) — the production consumer of
-        the lane path: N external commands cost one mirror sweep, not N
-        host cascades. Without a backend the replay cascades host-side per
-        operation, exactly as before."""
+        Without a graph backend on the hub the replay cascades host-side per
+        operation. With one, each operation's replay COLLECTS its directly
+        invalidated computeds (``batch_cascade_scope``) as one group, and
+        the batch's groups are handed on by one of two roads:
+
+        - the hub's backend has a nonblocking pipeline
+          (``hub.enable_nonblocking``: what ``ClusterCommander.execute_local``
+          asks too): every group is SUBMITTED to it (``WavePipeline.submit``),
+          the road the owner's own completion takes. A small external batch
+          is then one lat wave per operation at the member's next drain, a
+          large one fuses into a chain as command waves do, and this
+          coroutine never blocks the loop for a sweep. As for the owner, the
+          invalidations are visible after the member's next
+          ``ClusterCommander.drain()`` (or the pipeline's own
+          ``fuse_depth`` dispatch); ``replay_submitted`` counts the
+          operations;
+        - no pipeline: the whole batch cascades in one device lane burst
+          (``invalidate_cascade_batch_lanes``, over ``mesh=`` where one was
+          given): N external commands cost one mirror sweep, not N host
+          cascades, applied before this call returns;
+          ``replay_lane_bursts`` counts the bursts.
+
+        On both roads what was collected is handed on even if the reader is
+        stopped mid-batch (the ``finally`` below)."""
         handled = 0
         backend = getattr(self.operations.commander.hub, "graph_backend", None)
         while True:
-            records = self.log_store.read_after(self.watermark, self.batch_size)
+            with hot_span("oplog.read"):
+                records = self.log_store.read_after(self.watermark, self.batch_size)
             if not records:
                 return handled
             groups: List[List] = []
@@ -253,6 +279,16 @@ class OperationLogReader(WorkerBase):
                         if rec.agent_id == self.operations.agent.id:
                             continue  # our own operation: already completed locally
                         self.external_seen += 1
+                        if hot_spans_on() and rec.commit_time:
+                            # the origin's commit stamp (wall clock, taken
+                            # just before its append) on this host's span
+                            # clock: how long the record waited for a reader
+                            now = time.perf_counter()
+                            with hot_span(
+                                "oplog.lag",
+                                start=now - max(time.time() - rec.commit_time, 0.0),
+                            ):
+                                pass
                         operation = Operation(
                             command=rec.command,
                             agent_id=rec.agent_id,
@@ -294,7 +330,7 @@ class OperationLogReader(WorkerBase):
                         try:
                             with get_activity_source("oplog").span(
                                 "replay", index=rec.index, agent=rec.agent_id
-                            ):
+                            ), hot_span("oplog.replay"):
                                 await self.operations.notify_completed(
                                     operation, is_local=False
                                 )
@@ -311,13 +347,20 @@ class OperationLogReader(WorkerBase):
                     # names the range so lane-wave causes resolve to it
                     with get_activity_source("oplog").span(
                         "batch", upto=self.watermark, groups=len(groups)
-                    ):
+                    ), hot_span("oplog.batch"):
                         if self.mesh is not None:
                             backend.invalidate_cascade_batch_lanes_sharded(
                                 groups, mesh=self.mesh
                             )
+                            self.replay_lane_bursts += 1
+                        elif backend.pipeline is not None:
+                            for group in groups:
+                                if group:
+                                    backend.pipeline.submit(group)
+                                    self.replay_submitted += 1
                         else:
                             backend.invalidate_cascade_batch_lanes(groups)
+                            self.replay_lane_bursts += 1
 
     # ------------------------------------------------------------------ quarantine
     def _quarantine(

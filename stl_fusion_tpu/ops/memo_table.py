@@ -73,6 +73,10 @@ class MemoTable:
 
         self._jax = jax
         self._jnp = jnp
+        #: host buffer -> device array, for every upload this table makes.
+        #: ``jnp.asarray`` (JAX's default placement) until a graph backend
+        #: that was given a device binds the table (:meth:`place_on`)
+        self._put = jnp.asarray
         self.n_rows = int(n_rows)
         self.compute_fn = compute_fn
         self.version = 0
@@ -168,7 +172,7 @@ class MemoTable:
             self.refresh(np.unique(ids_np[stale]))
         k = len(ids_np)
         padded = _pad_repeat_pow2(ids_np)
-        out = self._jit_cache["gather"](self._values, self._jnp.asarray(padded))
+        out = self._jit_cache["gather"](self._values, self._put(padded))
         return out if len(padded) == k else out[:k]
 
     def encode_keys(self, keys, allocate: bool = True) -> np.ndarray:
@@ -252,7 +256,7 @@ class MemoTable:
         per-batch replay — and its one dispatch per batch — is
         unnecessary."""
         if self._valid_dev_dirty:
-            self._valid_dev = self._jnp.asarray(~self._stale_host)
+            self._valid_dev = self._put(~self._stale_host)
             self._valid_dev_dirty = False
             self._valid_pending.clear()
             self._valid_pending_n = 0
@@ -261,8 +265,8 @@ class MemoTable:
             padded = _pad_repeat_pow2(ids)
             self._valid_dev = self._jit_cache["set_mask_vals"](
                 self._valid_dev,
-                self._jnp.asarray(padded),
-                self._jnp.asarray(~self._stale_host[padded]),
+                self._put(padded),
+                self._put(~self._stale_host[padded]),
             )
             self._valid_pending.clear()
             self._valid_pending_n = 0
@@ -293,8 +297,8 @@ class MemoTable:
                 rows[:1], (len(padded) - len(ids_np), *rows.shape[1:])
             )
             rows = np.concatenate([rows, pad_rows])
-        jids = self._jnp.asarray(padded)
-        self._values = self._jit_cache["scatter"](self._values, jids, self._jnp.asarray(rows))
+        jids = self._put(padded)
+        self._values = self._jit_cache["scatter"](self._values, jids, self._put(rows))
         self._defer_valid(ids_np, True)  # dirty: lazy materialization covers it
         self._stale_count -= int(np.count_nonzero(self._stale_host[ids_np]))
         self._stale_host[ids_np] = False
@@ -407,10 +411,10 @@ class MemoTable:
                 f"{tuple(np.asarray(self._values).shape)}"
             )
         valid = np.asarray(state["valid"], dtype=bool)
-        self._values = self._jnp.asarray(values)
+        self._values = self._put(values)
         self._stale_host = ~valid
         self._stale_count = int((~valid).sum())
-        self._valid_dev = self._jnp.asarray(valid)
+        self._valid_dev = self._put(valid)
         self._valid_dev_dirty = False
         self._valid_pending.clear()
         self._valid_pending_n = 0
@@ -419,6 +423,25 @@ class MemoTable:
         self._bump()
 
     # ------------------------------------------------------------------ misc
+    def place_on(self, device) -> None:
+        """Commit this table's device state to ``device`` and send every
+        later upload there directly (``TpuGraphBackend.bind_table_rows`` of
+        a backend that was given one: the table then lives beside the graph
+        its rows are nodes of). A table is made before it is bound; one
+        that has computed nothing yet is made anew on the device (its zeros
+        never cross from the default device), any other is moved, once."""
+        jax, jnp = self._jax, self._jnp
+        self._put = functools.partial(jax.device_put, device=device)
+        if self._stale_count == self.n_rows and self.version == 0:
+            with jax.default_device(device):
+                values = jnp.zeros(self._values.shape, dtype=self._values.dtype)
+                valid = jnp.zeros(self.n_rows, dtype=jnp.bool_)
+        else:
+            values, valid = self._values, self.valid_mask
+        self._values = jax.device_put(values, device)
+        self._valid_dev = jax.device_put(valid, device)
+        self._packed_cache = None
+
     def stale_count(self) -> int:
         return self._stale_count
 
