@@ -190,20 +190,27 @@ class DevicePlacement:
             raise PlacementError(f"shard {shard} is off-mesh (DCN-relayed)")
         return dev * self.n_local + int(self.shard_slot[shard]) * self.slot_rows
 
+    def shard_runs(self) -> List[Tuple[int, int, int]]:
+        """``(lo, hi, base)`` per on-mesh shard that holds nodes: node ids
+        ``[lo, hi)`` sit at the consecutive global rows ``[base, base + hi -
+        lo)``. The whole node <-> row permutation is these runs (one per
+        shard), so a dense per-node array permutes by block copies."""
+        runs = []
+        for s in range(self.shard_map.n_shards):
+            if self.shard_dev[s] < 0:
+                continue
+            lo = s * self.ids_per_shard
+            hi = min(lo + self.ids_per_shard, self.n_nodes)
+            if hi > lo:
+                runs.append((lo, hi, self.row_of_shard(s)))
+        return runs
+
     def permutation(self) -> Tuple[np.ndarray, np.ndarray]:
         """``(perm, inv)``: node id → global device row, and row → node id
         (-1 on pad / off-mesh rows). Vectorized over all on-mesh shards."""
         perm = np.full(self.n_nodes, -1, np.int64)
         inv = np.full(self.n_global, -1, np.int64)
-        V = self.shard_map.n_shards
-        for s in range(V):
-            if self.shard_dev[s] < 0:
-                continue
-            lo = s * self.ids_per_shard
-            hi = min(lo + self.ids_per_shard, self.n_nodes)
-            if hi <= lo:
-                continue
-            base = self.row_of_shard(s)
+        for lo, hi, base in self.shard_runs():
             rows = np.arange(base, base + (hi - lo), dtype=np.int64)
             perm[lo:hi] = rows
             inv[rows] = np.arange(lo, hi, dtype=np.int64)

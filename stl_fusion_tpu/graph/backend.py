@@ -2085,10 +2085,11 @@ class TpuGraphBackend:
         cause, wave_seq = self._begin_wave()
         t0 = time.perf_counter()
         levels0 = graph.levels_total
-        count, newly_ids, overflow = graph.run_wave_collect(seeds)
+        count, newly, overflow = graph.run_wave_collect(seeds)
         if overflow:
             # the closure outgrew the compacted id buffers: the whole mask
-            # comes back instead (40 MB at 40 M nodes), counted
+            # comes back instead (40 MB at 40 M nodes), counted, and stays
+            # a mask to the last hook: no id array of its length is built
             global_metrics().counter(
                 "fusion_mesh_routed_overflows_total",
                 help="routed union waves whose closure overflowed the "
@@ -2096,13 +2097,15 @@ class TpuGraphBackend:
             ).inc()
             with hot_span("routed.mask_fetch"):
                 newly = graph.invalid_mask() & ~dg._h_invalid[: graph.n_nodes]
-                newly_ids = np.nonzero(newly)[0].astype(np.int32)
         with hot_span("routed.mark"):
-            dg.mark_invalid(newly_ids)
+            if overflow:
+                dg.mark_invalid_mask(newly)
+            else:
+                dg.mark_invalid(newly)
         entry["invalid_version"] = dg.invalid_version
         t1 = time.perf_counter()
         levels = graph.levels_total - levels0
-        self._apply_newly(newly_ids)
+        self._apply_newly(newly)
         self.waves_run += 1
         self.device_invalidations += count
         global_metrics().counter(
@@ -2114,7 +2117,8 @@ class TpuGraphBackend:
             help="collective frontier-exchange rounds run on the mesh",
         ).inc(levels)
         self._profile_wave(
-            "routed_union", len(seeds), cause, t0, t1, len(newly_ids), wave_seq,
+            "routed_union", len(seeds), cause, t0, t1,
+            int(np.count_nonzero(newly)) if overflow else len(newly), wave_seq,
             mesh={
                 "exchange": graph.exchange,
                 "levels": int(levels),

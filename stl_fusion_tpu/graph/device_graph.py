@@ -500,6 +500,27 @@ class DeviceGraph:
         self.invalid_version += 1
         self._device_invalid_update(node_ids, True)
 
+    def mark_invalid_mask(self, mask: np.ndarray) -> None:
+        """:meth:`mark_invalid` for a closure that arrives as a bool mask
+        over node ids (a routed wave that overflowed its id buffers: 40 M
+        rows): OR-ed into the host mask, and the device copy takes the
+        bit-packed upload without the ids ever being built. A mask of few
+        rows goes the id way, as a batch of that size does."""
+        mask = np.asarray(mask, dtype=bool)
+        hits = int(np.count_nonzero(mask))
+        if hits == 0:
+            return
+        self._h_invalid[: len(mask)] |= mask
+        self.invalid_version += 1
+        if hits * 4 <= self.n_cap + 1:
+            self._device_invalid_update(np.flatnonzero(mask).astype(np.int32), True)
+        elif self._g is not None and not self._dirty:
+            # _device_invalid_update's bulk upload, with no ids to size it by
+            packed = self._jnp.asarray(_pack_mask_host(self._h_invalid))
+            self._g = self._g._replace(
+                invalid=self.commit(_unpack_mask_kernel(len(self._h_invalid))(packed))
+            )
+
     def _device_invalid_update(self, node_ids: np.ndarray, value: bool) -> None:
         """Apply a host-side invalid-state change to the device copy. Small
         batches scatter by (pow2-padded) ids; batches whose id payload

@@ -584,29 +584,22 @@ class RoutedShardedGraph:
         self._rep_sh = NamedSharding(self.mesh, P())
         self._replicator = None  # lazy jit identity → replicated (multihost fetch)
 
-        perm, inv_perm = placement.permutation()
-        self.perm, self.inv_perm = perm, inv_perm
-        self._real_rows = np.flatnonzero(inv_perm >= 0)
-        self._real_nodes = inv_perm[self._real_rows]
+        self._set_permutation(placement)
+        perm = self.perm  # node id → global row (-1 off-mesh)
 
         # node state, absolute epochs (no rebase: patches translate nothing)
         nep = np.zeros(self.n_global, dtype=np.int32)
-        inv0 = np.zeros(self.n_global, dtype=bool)
         if node_epoch is not None:
             nep[perm[: len(node_epoch)][perm[: len(node_epoch)] >= 0]] = np.asarray(
                 node_epoch, dtype=np.int32
             )[perm[: len(node_epoch)] >= 0]
-        if invalid is not None:
-            m = np.asarray(invalid, dtype=bool)
-            rows = perm[: len(m)]
-            ok = rows >= 0
-            inv0[rows[ok]] = m[ok]
-        self._h_is_real = np.zeros(self.n_global, dtype=bool)
-        self._h_is_real[self._real_rows] = True
 
         self._build_exchange_and_edges()
         self.g_node_epoch = self._put(nep, self._node_sh)
-        self.g_invalid = self._put(inv0, self._node_sh)
+        if invalid is None:
+            self.clear_invalid()
+        else:
+            self.set_invalid(invalid)
         self.g_is_real = self._put(self._h_is_real, self._node_sh)
         self._wave = build_routed_wave(
             self.mesh, self.n_global, self.n_dev, self.exchange,
@@ -625,6 +618,14 @@ class RoutedShardedGraph:
             global_metrics().set_aggregation("fusion_mesh_hosts", "max")
 
     # ---------------------------------------------------------------- helpers
+    def _set_permutation(self, placement: DevicePlacement) -> None:
+        """The node <-> row maps of ``placement``: the index arrays for
+        callers that map ids, and the shard runs (at most one per shard)
+        by which a whole mask moves as block copies."""
+        self.perm, self.inv_perm = placement.permutation()
+        self._runs = placement.shard_runs()
+        self._h_is_real = self.inv_perm >= 0
+
     def _host_of_dev(self, d) -> np.ndarray:
         return np.asarray(d) // self.dph
 
@@ -1439,15 +1440,17 @@ class RoutedShardedGraph:
         """bool[n_nodes] in NODE space (reads the device state once)."""
         arr = self._fetch(self.g_invalid)
         out = np.zeros(self.n_nodes, dtype=bool)
-        out[self._real_nodes] = arr[self._real_rows]
+        for lo, hi, base in self._runs:
+            out[lo:hi] = arr[base : base + hi - lo]
         return out
 
     def set_invalid(self, mask: np.ndarray) -> None:
         inv = np.zeros(self.n_global, dtype=bool)
         m = np.asarray(mask[: self.n_nodes], dtype=bool)
-        rows = self.perm[: len(m)]
-        ok = rows >= 0
-        inv[rows[ok]] = m[ok]
+        for lo, hi, base in self._runs:
+            hi = min(hi, len(m))
+            if hi > lo:
+                inv[base : base + hi - lo] = m[lo:hi]
         self.g_invalid = self._put(inv, self._node_sh)
 
     def clear_invalid(self) -> None:
@@ -1504,11 +1507,7 @@ class RoutedShardedGraph:
                 affected_devs.add(d)
         cross = new_placement.cross_host_moves(moves) if self.n_hosts > 1 else 0
         self.placement = new_placement
-        self.perm, self.inv_perm = new_placement.permutation()
-        self._real_rows = np.flatnonzero(self.inv_perm >= 0)
-        self._real_nodes = self.inv_perm[self._real_rows]
-        self._h_is_real = np.zeros(self.n_global, dtype=bool)
-        self._h_is_real[self._real_rows] = True
+        self._set_permutation(new_placement)
         self.g_is_real = self._put(self._h_is_real, self._node_sh)
         if old_rows_l:
             old_rows = np.concatenate(old_rows_l)

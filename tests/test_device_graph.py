@@ -112,6 +112,53 @@ def test_epoch_bump_kills_stale_edges_and_revives_node():
     assert mask[0] and mask[1] and not mask[2]
 
 
+@pytest.mark.parametrize("case", ["sparse", "dense", "no_device_copy", "dirty", "empty"])
+def test_mark_invalid_mask_equals_mark_invalid_of_its_ids(case, monkeypatch):
+    """A closure marked as a bool mask leaves the graph where marking its
+    ids leaves it: host mask, device mask and ``invalid_version``. Few rows
+    go the id scatter's way, many the bit-packed upload's, and no device
+    copy (none yet, or a stale one) gets none."""
+    from stl_fusion_tpu.graph import device_graph as dg_mod
+
+    rng = np.random.default_rng(4)
+    n = 300  # n_cap 512: four bytes an id outweigh the mask from 129 rows on
+    g, twin = (DeviceGraph(node_capacity=n, edge_capacity=8) for _ in range(2))
+    before = rng.choice(n, size=20, replace=False)
+    for graph in (g, twin):
+        graph.add_nodes(n)
+        if case != "no_device_copy":
+            graph.device_arrays()
+        graph.mark_invalid(before)
+        if case == "dirty":
+            graph.add_nodes(400)  # grows past n_cap: the device copy is stale
+            assert graph._dirty
+    hits = {"sparse": 5, "dense": 200, "no_device_copy": 200, "dirty": 200, "empty": 0}
+    mask = np.zeros(n, dtype=bool)
+    # half of the rows are invalid already: the mask is OR-ed in
+    mask[np.concatenate([before[:hits[case] // 2],
+                         rng.choice(n, size=hits[case], replace=False)])] = True
+    ids = np.flatnonzero(mask)
+
+    uploads, scatters = [], []
+    monkeypatch.setattr(dg_mod, "_pack_mask_host",
+                        lambda m, f=dg_mod._pack_mask_host: uploads.append(1) or f(m))
+    monkeypatch.setattr(g, "_pad_ids_pow2",
+                        lambda i, f=g._pad_ids_pow2: scatters.append(len(i)) or f(i))
+    monkeypatch.setattr(g, "mark_invalid", None)  # not by way of the id method
+    version = g.invalid_version
+    g.mark_invalid_mask(mask)
+    assert (len(uploads), scatters) == {
+        "sparse": (0, [len(ids)]), "dense": (1, []),
+    }.get(case, (0, []))
+    twin.mark_invalid(ids)
+
+    assert g.invalid_version == twin.invalid_version == version + (case != "empty")
+    np.testing.assert_array_equal(g._h_invalid, twin._h_invalid)
+    assert g._h_invalid[:n].sum() == len(np.union1d(before, ids))
+    np.testing.assert_array_equal(g.invalid_mask(), twin.invalid_mask())
+    np.testing.assert_array_equal(g.invalid_mask(), g._h_invalid[: g.n_nodes])
+
+
 def test_capacity_growth():
     g = DeviceGraph(node_capacity=16, edge_capacity=16)
     ids = g.add_nodes(100)

@@ -1000,3 +1000,179 @@ def test_single_shard_move_repacks_remote_consumers():
         f"single-shard move lost {len(want) - count} cascaded invalidations"
     )
     assert count == len(want)
+
+
+# ------------------------------------------------- a mask moves by shard blocks
+def _block_perm_case(case):
+    """(graph, n) on a placement whose node <-> row permutation has the
+    shape the case names; edges only between on-mesh nodes."""
+    n = 3990 if case == "short_last_shard" else 4000
+    src, dst, _adj = make_graph(n)
+    members = ["a", "b", "c", "d"] if case == "off_mesh_shard" else ["a", "b"]
+    smap = ShardMap.initial(members, n_shards=32)
+    on_mesh = ["a", "b"]
+    pl = DevicePlacement.build(smap, 8, n, mesh_members=on_mesh, slot_headroom=3.0)
+    if case == "off_mesh_shard":
+        perm, _inv = pl.permutation()
+        keep = (perm[src] >= 0) & (perm[dst] >= 0)
+        src, dst = src[keep], dst[keep]
+    built_with = np.random.default_rng(8).random(n) < 0.3
+    g = RoutedShardedGraph(
+        src, dst, n, pl, mesh=graph_mesh(), edge_headroom=2.5, bucket_headroom=2.5,
+        invalid=built_with,
+    )
+    if case == "after_moves":
+        pl2, moves = pl.moved_to(smap.with_members(["a"]), mesh_members=["a"])
+        assert moves
+        g.apply_placement(pl2, moves)
+        assert not np.array_equal(g.perm, pl.permutation()[0])
+    return g, n, built_with
+
+
+@pytest.mark.parametrize(
+    "case", ["initial", "after_moves", "off_mesh_shard", "short_last_shard"]
+)
+def test_mask_permutes_by_shard_blocks_as_by_index(case):
+    g, n, built_with = _block_perm_case(case)
+    perm, inv_perm = g.placement.permutation()
+    assert np.array_equal(g.perm, perm) and np.array_equal(g.inv_perm, inv_perm)
+    on = perm >= 0
+    assert on.all() == (case != "off_mesh_shard")
+    if case == "short_last_shard":
+        lo, hi, _base = g._runs[-1]
+        assert hi == n and hi - lo < g.placement.ids_per_shard
+    # the mask the graph was built with, carried through the moves
+    assert np.array_equal(g.invalid_mask(), built_with & on)
+    m = np.random.default_rng(9).random(n) < 0.4
+    assert m[~on].any() or on.all()
+
+    g.set_invalid(m)
+    rows = np.asarray(g.g_invalid)
+    by_index = np.zeros(g.n_global, dtype=bool)
+    by_index[perm[on]] = m[on]
+    assert np.array_equal(rows, by_index)  # pad and off-mesh rows stay False
+
+    got = g.invalid_mask()
+    real = inv_perm >= 0
+    by_index = np.zeros(n, dtype=bool)
+    by_index[inv_perm[real]] = rows[real]
+    assert np.array_equal(got, by_index)
+    assert np.array_equal(got, m & on)  # the round trip; off-mesh nodes read False
+    assert np.array_equal(np.asarray(g.g_is_real), real)
+
+
+# ------------------------------------- a routed closure on the host (ISSUE 35)
+@pytest.mark.parametrize("kind", ["overflow", "no_overflow"])
+async def test_routed_closure_applies_by_mask_on_overflow_and_by_ids_otherwise(kind):
+    """``cascade_rows_batch_routed`` on a closure that outgrows the compacted
+    id buffers (it comes back as the mesh's mask, and stays one to the last
+    hook) and on one that fits (ids, as ever): either way the hub ends where
+    applying the host BFS closure's ids would leave it."""
+    from stl_fusion_tpu.core import (
+        ComputeService, FusionHub, TableBacking, capture, compute_method,
+        memo_table_of, set_default_hub,
+    )
+    from stl_fusion_tpu.diagnostics.metrics import global_metrics
+    from stl_fusion_tpu.graph import TpuGraphBackend
+
+    ns = 100_000  # 25 k rows a device against id buffers of 16,384
+    src, dst, adj = make_graph(ns, seed=5)
+    rng = np.random.default_rng(6)
+    earlier = [int(ns // 2 + rng.integers(ns // 4))]
+    if kind == "overflow":
+        seeds = rng.choice(ns // 10, size=200, replace=False).tolist()
+    else:
+        seeds = (ns // 2 + rng.choice(ns // 2, size=40, replace=False)).tolist()
+    already = np.zeros(ns, dtype=bool)
+    already[list(bfs_closure(adj, earlier))] = True
+    closure = np.zeros(ns, dtype=bool)
+    closure[list(bfs_closure(adj, seeds))] = True
+    want_newly = closure & ~already
+    newly_ids = np.flatnonzero(want_newly)
+
+    hub = FusionHub()
+    old = set_default_hub(hub)
+    try:
+        backend = TpuGraphBackend(hub, node_capacity=ns + 16, edge_capacity=len(src) + 256)
+
+        class RowSvc(ComputeService):
+            def load(self, ids):
+                return np.asarray(ids, dtype=np.float32)
+
+            @compute_method(table=TableBacking(rows=ns, batch="load"))
+            async def row(self, i: int) -> float:
+                return float(i)
+
+        svc = RowSvc(hub)
+        hub.add_service(svc)
+        table = memo_table_of(svc.row)
+        blk = backend.bind_table_rows(table)
+        backend.declare_row_edges(blk, src, blk, dst)
+        table.read_batch(np.arange(ns))
+        # two scalar twins inside the closure: one watched (the eager tier),
+        # one not (it stays pending)
+        watched_row, lazy_row = (int(r) for r in newly_ids[[len(newly_ids) // 2, -1]])
+        hits: list = []
+        watched = await capture(lambda: svc.row(watched_row))
+        watched.on_invalidated(hits.append)
+        lazy = await capture(lambda: svc.row(lazy_row))
+        backend.flush()
+        backend.enable_mesh_routing(
+            ShardMap.initial(["m0", "m1", "m2", "m3"], n_shards=32),
+            mesh=graph_mesh(n_devices=4),
+        )
+        dg = backend.graph
+        assert backend.cascade_rows_batch_routed(blk, earlier) == np.count_nonzero(already)
+
+        hooked: list = []
+        backend.newly_hooks.append(hooked.append)
+        calls = {"mark_invalid": 0, "mark_invalid_mask": 0}
+        for name in calls:
+            def spy(arg, _f=getattr(dg, name), _name=name):
+                calls[_name] += 1
+                return _f(arg)
+            setattr(dg, name, spy)
+        def overflows() -> int:
+            return int(
+                global_metrics().snapshot().get("fusion_mesh_routed_overflows_total", 0)
+            )
+
+        overflows0, version0 = overflows(), dg.invalid_version
+        pending0 = backend._pending.copy()
+
+        count = backend.cascade_rows_batch_routed(blk, seeds)
+
+        assert count == len(newly_ids)
+        overflowed = kind == "overflow"
+        assert overflows() - overflows0 == int(overflowed)
+        assert calls == {"mark_invalid": int(not overflowed),
+                         "mark_invalid_mask": int(overflowed)}
+        assert dg.invalid_version == version0 + 1
+        # the table, the dense graph on host and device, the mesh's own mask
+        stale = already | closure
+        assert np.array_equal(table._stale_host[:ns], stale)
+        assert np.array_equal(~np.asarray(table.valid_mask), stale)
+        assert table.stale_count() == np.count_nonzero(stale)
+        assert np.array_equal(dg._h_invalid[:ns], stale)
+        assert not dg._h_invalid[ns:].any()
+        assert np.array_equal(np.asarray(dg.invalid_mask()), stale)
+        assert np.array_equal(backend.routed_mirror()["graph"].invalid_mask(), stale)
+        # the lazy tier: every newly row pending, but the one applied eagerly
+        want_pending = pending0.copy()
+        want_pending[newly_ids] = True
+        want_pending[watched_row] = False
+        assert np.array_equal(backend._pending, want_pending)
+        assert hits == [watched] and watched._invalidation_cause == backend.last_cause_id
+        assert backend.last_cause_id is not None
+        assert backend._pending[lazy_row] and not lazy.is_consistent  # materialized on read
+        # the hooks get the closure in the form it came back in
+        (got,) = hooked
+        if overflowed:
+            assert got.dtype == np.bool_ and got.shape == (dg.n_nodes,)
+            assert np.array_equal(got, want_newly)
+        else:
+            assert got.dtype != np.bool_
+            assert np.array_equal(np.sort(got), newly_ids)
+        assert backend.profiler.recent()[-1]["newly"] == len(newly_ids)
+    finally:
+        set_default_hub(old)
