@@ -22,7 +22,10 @@ one predicate and gets the shared no-op. On, each hot span is also a
 host line of the profiler's trace, on the clock of the device planes (the
 outermost span that knows its wave's seq when it opens carries it as the
 event's ``wave`` stat), and its (name, start, end, parent, wave seq) goes to
-an in-memory record that :func:`hot_spans` hands to a reader.
+an in-memory record that :func:`hot_spans` hands to a reader. No span site
+opens the ``jit.trace.<f>``, ``jit.lower.<f>`` and ``jit.compile.<f>`` spans:
+``graph/program_cache.py``'s ``jax.monitoring`` listeners do, when JAX traces,
+lowers or compiles ``f`` inside whatever hot span is open at that moment.
 """
 from __future__ import annotations
 
@@ -263,12 +266,17 @@ _TraceAnnotation: Any = None  # jax.profiler.TraceAnnotation, bound on first use
 def _profiler_tracing() -> bool:
     """First call only: bind the profiler's own predicate in this one's
     place (importing jax at module scope would charge every importer of
-    ``diagnostics`` for it)."""
+    ``diagnostics`` for it), and have JAX's own trace, lower and compile
+    phases reported as ``jit.<phase>.<function>`` spans under whatever hot
+    span is open when JAX enters them (``graph/program_cache.py``)."""
     global _TraceAnnotation, _profiler_tracing
     from jax.profiler import TraceAnnotation
 
+    from ..graph.program_cache import watch_compiles
+
     _TraceAnnotation = TraceAnnotation
     _profiler_tracing = TraceAnnotation.is_enabled
+    watch_compiles()
     return _profiler_tracing()
 
 

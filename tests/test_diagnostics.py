@@ -3,6 +3,7 @@ CommandTracer filter, and batched EntityResolver tests (SURVEY §5.1 tracing;
 §2.3 CommandTracer; §2.6 DbEntityResolver)."""
 import asyncio
 import glob
+import time
 from collections import Counter
 from dataclasses import dataclass
 
@@ -36,6 +37,7 @@ from stl_fusion_tpu.diagnostics.tracing import (
 )
 from stl_fusion_tpu.graph import TpuGraphBackend
 from stl_fusion_tpu.graph.device_graph import DeviceGraph
+from stl_fusion_tpu.graph.program_cache import compile_report, reset_program_warms
 from stl_fusion_tpu.graph.synthetic import power_law_dag
 from stl_fusion_tpu.oplog import EntityResolver
 
@@ -337,7 +339,9 @@ def test_span_sites_of_the_live_loop(site, gate, monkeypatch, annotations):
             assert r.wave in waves, (name, r.wave, seq)
             assert (by_id[r.parent_id].name if r.parent_id else None) == parent, name
     if site == "lat":
-        assert set(names) == set(with_seq)  # nothing else on a lone edit's path
+        # nothing else on a lone edit's path, but for what JAX compiled on
+        # this first call (the listener's spans: TestJitSpans)
+        assert {n for n in names if not n.startswith("jit.")} == set(with_seq)
         # one event of the edit names its wave: the first to know it
         assert [(n, m) for n, m in annotations.opened if m] == [
             ("fusion:wave.union", {"wave": seq})]
@@ -383,6 +387,220 @@ def test_profiler_trace_turns_the_spans_on_and_holds_them(tmp_path):
     assert outer.start_ns <= inner.start_ns
     assert inner.start_ns + inner.duration_ns <= outer.start_ns + outer.duration_ns
     assert dict(found["fusion:wave.union"].stats)["wave"] == seq
+
+
+JIT_EVENTS = {
+    "trace": "/jax/core/compile/jaxpr_trace_duration",
+    "lower": "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "compile": "/jax/core/compile/backend_compile_duration",
+}
+JIT_PHASES = [f"jit.{phase}.probe" for phase in JIT_EVENTS]
+
+
+def build_probe(body=None):
+    """What an ``lru_cache`` program builder hands out on a miss: a NEW
+    function under a new ``jax.jit``. (A new ``jax.jit`` over the SAME
+    function object hits JAX's own caches and traces nothing.)"""
+    import jax
+
+    def probe(x):
+        if body is not None:
+            body()
+        return jax.lax.add(x, x)  # a primitive: no jitted function inside
+
+    return jax.jit(probe)
+
+
+class TimedAnnotationSpy(AnnotationSpy):
+    """Also when each annotation was open, on the record's clock."""
+
+    intervals: dict = {}
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        TimedAnnotationSpy.intervals[self.name] = (self._t0, time.perf_counter())
+        return super().__exit__(exc_type, exc, tb)
+
+
+class TestJitSpans:
+    """JAX's trace, lower and compile phases as hot spans: no site opens
+    them, ``graph/program_cache.py``'s ``jax.monitoring`` listeners do."""
+
+    @pytest.mark.parametrize("call", ["first", "second", "new_jit"])
+    def test_phases_nest_in_the_hot_span_that_caused_them(self, annotations, call):
+        import jax.numpy as jnp
+
+        enable_hot_spans()
+        x = jnp.arange(8)
+        probe = build_probe()
+        if call != "first":
+            probe(x)
+        tracing.clear_hot_spans()  # of what made ``x``, too
+        annotations.opened.clear()
+        with hot_span("topo.dispatch", 17):
+            (build_probe() if call == "new_jit" else probe)(x)
+        record = hot_spans()
+        outer = record[-1]
+        assert outer.name == "topo.dispatch"
+        if call == "second":  # every cache of JAX's hits: nothing to report
+            assert [r.name for r in record] == ["topo.dispatch"]
+            return
+        # "new_jit" is the retrace shape: the same program, traced again
+        assert [r.name for r in record] == JIT_PHASES + ["topo.dispatch"]
+        edges = [outer.start]
+        for r in record[:-1]:
+            assert r.parent_id == outer.span_id and r.wave == 17
+            edges += [r.start, r.end]
+        edges.append(outer.end)
+        assert edges == sorted(edges)  # inside its interval, none overlapping
+        assert [n for n, _ in annotations.opened] == [
+            "fusion:topo.dispatch"] + ["fusion:" + n for n in JIT_PHASES]
+
+    def test_a_trace_inside_a_trace_is_counted_not_recorded(self, annotations):
+        import jax
+        import jax.numpy as jnp
+
+        @jax.jit
+        def outer_fn(x):  # every jnp function is a jitted function of its own
+            return jnp.where(x > 0, jnp.bitwise_or(x, 1), x).sum()
+
+        enable_hot_spans()
+        reset_program_warms()
+        with hot_span("lat.dispatch"):
+            outer_fn(jnp.arange(8))
+        names = Counter(r.name for r in hot_spans())
+        assert names == {"lat.dispatch": 1, "jit.trace.outer_fn": 1,
+                         "jit.lower.outer_fn": 1, "jit.compile.outer_fn": 1}
+        row = compile_report()["functions"]["outer_fn"]
+        assert (row["traces"], row["lowers"], row["compiles"]) == (1, 1, 1)
+        assert row["nested"] >= 4  # greater, bitwise_or, where, sum
+        assert "bitwise_or" not in compile_report()["functions"]
+
+    def test_spans_off_the_account_counts_the_same_phases(self, annotations):
+        import jax.numpy as jnp
+
+        assert not hot_spans_on()
+        reset_program_warms()
+        with hot_span("topo.dispatch"):
+            build_probe()(jnp.arange(8))
+        assert hot_spans() == [] and annotations.opened == []
+        report = compile_report()
+        row = report["functions"]["probe"]
+        assert (row["traces"], row["lowers"], row["compiles"], row["nested"]) == (1, 1, 1, 0)
+        assert row["trace_s"] > 0 and row["lower_s"] > 0 and row["compile_s"] > 0
+        assert report["totals"] == row and set(report["functions"]) == {"probe"}
+
+    @pytest.mark.parametrize("phase", sorted(JIT_EVENTS))
+    def test_each_phase_is_open_for_as_long_as_it_lasts(self, monkeypatch, annotations, phase):
+        """The pinned form: the annotation opens at the phase's entry event
+        and closes at its end event, so the trace viewer shows the phase's
+        own extent (the closed-at-once form would open it at the end)."""
+        import jax.numpy as jnp
+
+        TimedAnnotationSpy.intervals = {}
+        monkeypatch.setattr(tracing, "_TraceAnnotation", TimedAnnotationSpy)
+        enable_hot_spans()
+        build_probe()(jnp.arange(8))
+        r = next(r for r in hot_spans() if r.name == f"jit.{phase}.probe")
+        opened, closed = TimedAnnotationSpy.intervals[f"fusion:jit.{phase}.probe"]
+        assert opened <= r.start <= r.end <= closed
+
+    @pytest.mark.parametrize("entry", ["none", "other_context"])
+    @pytest.mark.parametrize("phase", sorted(JIT_EVENTS))
+    def test_an_end_with_no_entry_of_its_own_is_closed_at_once(
+        self, annotations, phase, entry
+    ):
+        """Listeners registered mid-phase (no entry event), or an entry that
+        ran in another context (its contextvar token is not this one's to
+        reset): one span, closed as soon as opened, the event's start mapped
+        onto the span clock."""
+        import contextvars
+
+        from jax import monitoring
+
+        enable_hot_spans()
+        reset_program_warms()
+        t1 = time.time()
+        t0 = t1 - 0.25
+        with hot_span("wave.apply") as outer:
+            if entry == "other_context":
+                contextvars.copy_context().run(
+                    monitoring.record_scalar, JIT_EVENTS[phase], t0, fun_name="jit(late)")
+            before = time.perf_counter()
+            monitoring.record_event_time_span(JIT_EVENTS[phase], t0, t1, fun_name="jit(late)")
+            after = time.perf_counter()
+        (r,) = [r for r in hot_spans() if r.name.startswith("jit.")]
+        assert r.name == f"jit.{phase}.late" and r.parent_id == outer.span_id
+        assert before <= r.end <= after
+        assert 0.25 <= r.end - r.start <= 0.25 + (after - before) + 0.05
+        row = compile_report()["functions"]["late"]
+        assert row[phase + "s"] == 1 and row[phase + "_s"] == pytest.approx(0.25, abs=1e-3)
+        with hot_span("flush"):
+            pass
+        assert hot_spans()[-1].parent_id is None  # nothing left open here
+
+    def test_a_compile_on_another_thread_leaves_this_threads_chain(self, annotations):
+        import threading
+
+        import jax.numpy as jnp
+
+        enable_hot_spans()
+        reset_program_warms()
+        x = jnp.arange(8)
+
+        def elsewhere():
+            with hot_span("lat.dispatch"):
+                build_probe()(x)
+
+        def inside_the_trace():
+            worker = threading.Thread(target=elsewhere)
+            worker.start()
+            worker.join()
+
+        slow = build_probe(inside_the_trace)
+        with hot_span("topo.dispatch") as outer:
+            slow(x)
+        record = hot_spans()
+        by_id = {r.span_id: r for r in record}
+        parents = Counter(
+            (r.name, by_id[r.parent_id].name) for r in record if r.name.startswith("jit."))
+        assert parents == {(n, p): 1 for n in JIT_PHASES for p in ("topo.dispatch", "lat.dispatch")}
+        mine = next(r for r in record if r.name == "jit.trace.probe"
+                    and r.parent_id == outer.span_id)
+        theirs = [r for r in record if r.name.startswith("jit.") and r.parent_id != outer.span_id]
+        assert all(mine.start <= r.start and r.end <= mine.end for r in theirs)
+        assert next(r for r in record if r.name == "lat.dispatch").parent_id is None
+        # the other thread's phases were outermost on ITS stack: counted in
+        # full, and not as nested in this thread's trace
+        row = compile_report()["functions"]["probe"]
+        assert (row["traces"], row["lowers"], row["compiles"], row["nested"]) == (2, 2, 2, 0)
+
+    def test_the_topo_program_is_not_traced_again_on_the_cpu(self, monkeypatch, annotations):
+        """``_run_mirror_union`` twice with the same shapes: the second
+        ``topo.dispatch`` has no ``jit.*`` child. (On the chip it looked
+        traced again on every call: PERF.md §7. A retrace HERE would be a
+        fault of the program.)"""
+        monkeypatch.setattr(DeviceGraph, "LAT_CAP", 32)
+        reset_program_warms()
+        backend, table, block = make_stack()
+        enable_hot_spans()
+        children = []
+        for _ in range(2):
+            tracing.clear_hot_spans()
+            backend.graph._run_mirror_union([[0]])
+            record = hot_spans()
+            (dispatch,) = [r for r in record if r.name == "topo.dispatch"]
+            children.append([r.name for r in record if r.parent_id == dispatch.span_id])
+            backend.refresh_block_on_device(block)
+            backend.flush()
+        # the first call compiles it, unless an earlier test's stack did
+        assert children[0] in ([], ["jit.trace.burst", "jit.lower.burst", "jit.compile.burst"])
+        assert children[1] == []
+        traced = compile_report()["functions"].get("burst", {"traces": 0})["traces"]
+        assert traced == len(children[0]) // 3  # and nowhere else in the stack's life
 
 
 class TestCommandTracer:
