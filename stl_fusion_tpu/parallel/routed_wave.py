@@ -36,12 +36,18 @@ frontier with mesh collectives instead of surfacing to the host:
 
 Once a wave, before the level loop: the version check, one local row
 gather (``node_epoch[dst] == edge epoch`` — device-local by construction,
-the reason edges shard by destination), folded into the scatter's index (a
-stale slot or a pad points at the row the scatter drops), and the per-edge
-word index. Per level, after the exchange: ONE indexed read over the
-shard's edge slots (the source word), one local scatter, the
-already-invalid mask on the node rows, and a ``psum`` for the continuation
-flag. The while_loop carries the flag, so no collective runs in ``cond``.
+the reason edges shard by destination), the per-edge word index, and the
+wave's WORKLIST: the slots that passed the check (no pad, none behind its
+destination's version) as (source key, destination row) pairs, sorted to a
+prefix. Per level, after the exchange: the worklist fires in chunks — per
+slot ONE indexed read (the source word) and one place in the chunk's local
+scatter — and a slot that fired leaves it (a node is in a frontier at most
+once a wave, so the slot can never change the result again): a wave's
+reads are the live slots whose source is still to come, not every slot at
+every level. Then the already-invalid mask on the node rows and a ``psum``
+for the continuation flag. The while_loop carries the flag, so no
+collective runs in ``cond``; the chunk loop's trip count is the worklist's
+length, read on the device.
 
 The **chain faces** (:meth:`RoutedShardedGraph.dispatch_union_chain` /
 :meth:`harvest_union_chain`) run K logical waves in ONE ``lax.scan`` with
@@ -135,17 +141,35 @@ def _psum_axes(mesh: Mesh):
     return names[0] if len(names) == 1 else tuple(names)
 
 
+#: a worklist key is ``_WAITING | word index << 5 | bit``: a slot still to
+#: fire. Under that bit lie the destination rows of the slots that fire in
+#: the chunk being sorted; above every key, the entry that is no slot
+_WAITING = np.uint32(1 << 31)
+_DEAD_KEY = np.uint32(0xFFFFFFFF)
+
+
+def _chunk_width(e_cap: int) -> int:
+    """Slots in one chunk of a level's worklist, from the edge capacity
+    alone: a power of two, 2**20 at most (on the v5e a wave at 10 M nodes
+    a chip took 2.92 / 2.44 / 2.35 / 2.06 s at 2**14 / 2**16 / 2**18 /
+    2**20 and no less above: CHANGES.md, PR 37), and an eighth of the
+    shard's slots at most, so that a small graph's worklist still spans
+    chunks."""
+    return min(1 << 20, max(64, 1 << ((e_cap // 8).bit_length() - 1)))
+
+
 def build_routed_wave(
     mesh: Mesh, n_global: int, n_dev: int, exchange: str, async_depth: int = 0
 ):
     """Compile the routed union wave for a mesh + geometry. Returns
     ``wave(frontier, send_idx, hsend_idx, eprod, ebslot, ebit, edst,
     elsrc, eepoch, nepoch, invalid) -> (invalid', count, levels,
-    spec_levels)`` — all arrays sharded over the mesh's flat device axis;
-    seeds conduct even when already invalid (the r4 union rule);
-    ``levels`` is the number of frontier exchanges the wave ran (the
+    spec_levels, visits)`` — all arrays sharded over the mesh's flat
+    device axis; seeds conduct even when already invalid (the r4 union
+    rule); ``levels`` is the number of frontier exchanges the wave ran (the
     collective-rounds telemetry ``fusion_mesh_exchange_levels``
-    aggregates). For ``exchange="hier"`` the mesh must be the 2-D
+    aggregates); ``visits`` the worklist slots its levels read, per device
+    as uint32 ``[lo, hi]``. For ``exchange="hier"`` the mesh must be the 2-D
     ``(host, ldev)`` mesh; bucket capacities are read from the
     (trace-time) table shapes, which is what lets an in-place bucket
     resize recompile instead of re-pack.
@@ -158,7 +182,7 @@ def build_routed_wave(
     exchanges the cumulative EVER-LIT accumulator through the unchanged
     OR-accumulation collectives (atomic-free by construction — packed-word
     OR is idempotent and order-independent, the Tascade reduction-tree
-    property) and fires every edge against it, which both completes the
+    property) and fires the worklist against it, which both completes the
     remote frontier and picks up local rows the bounded speculation left
     unexpanded. The per-level barrier becomes a counted QUIESCENCE vote:
     one psum of "did any shard's merge fire a row" per merge epoch —
@@ -263,31 +287,39 @@ def build_routed_wave(
             hstep *= 2
         return intra, acc.reshape(-1)  # [n_hosts(H) * n_hosts(G) * hcap]
 
-    def _word_lookup(send_idx_l, hsend_idx_l, eprod_l, ebslot_l):
-        """``lookup(intra_flat, cross_flat) -> per-edge source word`` over
-        what :func:`_exchange_words` returns, via the cap-independent
-        (eprod, ebslot) routing. The index arithmetic runs here, once a
-        wave; a level pays the indexed read alone. Capacities come from
-        trace-time table shapes — the hook dynamic bucket growth hangs
-        off."""
+    def _word_index(send_idx_l, hsend_idx_l, eprod_l, ebslot_l):
+        """``(idx, words)``: per edge slot the index of its source word in
+        ``words(intra_flat, cross_flat)``, ONE vector over what
+        :func:`_exchange_words` returns, via the cap-independent (eprod,
+        ebslot) routing. The index arithmetic runs here, once a wave; a
+        level pays the indexed read alone. Capacities come from trace-time
+        table shapes — the hook dynamic bucket growth hangs off."""
+        words = lambda intra_flat, _cross: intra_flat  # noqa: E731
         if exchange in ("tree", "gather"):
-            return lambda intra_flat, _cross: intra_flat[ebslot_l]
-        icap = send_idx_l.shape[-1]
-        if exchange == "a2a":
-            idx = eprod_l * icap + ebslot_l
-            return lambda intra_flat, _cross: intra_flat[idx]
-        # hier: intra edges read the subgroup-a2a rows; cross edges read
-        # the (producer host, consumer host) bucket of the host tree
-        hcap = hsend_idx_l.shape[-1]
-        g = lax.axis_index(HOST_AXIS)
-        is_cross = eprod_l >= n_dev
-        idx_i = jnp.where(is_cross, 0, (eprod_l % dph) * icap + ebslot_l)
-        idx_c = jnp.where(
-            is_cross, ((eprod_l - n_dev) * n_hosts + g) * hcap + ebslot_l, 0
-        )
-        return lambda intra_flat, cross_flat: jnp.where(
-            is_cross, cross_flat[idx_c], intra_flat[idx_i]
-        )
+            n_words, idx = n_dev * w_local, ebslot_l
+        elif exchange == "a2a":
+            icap = send_idx_l.shape[-1]
+            n_words, idx = n_dev * icap, eprod_l * icap + ebslot_l
+        else:
+            # hier: intra edges read the subgroup-a2a rows; cross edges the
+            # (producer host, consumer host) bucket of the host tree, laid
+            # behind them so that a visit is one read
+            icap, hcap = send_idx_l.shape[-1], hsend_idx_l.shape[-1]
+            n_intra = dph * icap
+            n_words = n_intra + n_hosts * n_hosts * hcap
+            g = lax.axis_index(HOST_AXIS)
+            idx = jnp.where(
+                eprod_l >= n_dev,
+                n_intra + ((eprod_l - n_dev) * n_hosts + g) * hcap + ebslot_l,
+                (eprod_l % dph) * icap + ebslot_l,
+            )
+            words = lambda intra_flat, cross_flat: jnp.concatenate(  # noqa: E731
+                [intra_flat, cross_flat]
+            )
+        if n_words >= 1 << 26:
+            # a worklist key is (word index << 5 | bit) under _WAITING
+            raise ValueError(f"{n_words} exchanged words a device: over 2**26")
+        return idx, words
 
     @shard_map_compat(
         mesh=mesh,
@@ -295,7 +327,7 @@ def build_routed_wave(
             node_spec, send_spec, send_spec, edge_spec, edge_spec, edge_spec,
             edge_spec, edge_spec, edge_spec, node_spec, node_spec,
         ),
-        out_specs=(node_spec, P(), P(), P()),
+        out_specs=(node_spec, P(), P(), P(), node_spec),
     )
     def _wave(seeds_l, send_idx_l, hsend_idx_l, eprod_l, ebslot_l, ebit_l,
               edst_l, elsrc_l, eepoch_l, nepoch_l, inv_l):
@@ -306,33 +338,101 @@ def build_routed_wave(
 
         # once a wave, outside the level loop: nothing here changes while a
         # wave runs. A slot whose captured epoch is behind its destination's
-        # (or a pad: eepoch -1 never matches, the gather clamps) points at
-        # the out-of-range row the scatter drops, so the version check costs
+        # (or a pad: eepoch -1 never matches, the gather clamps) never
+        # conducts: it stays out of the worklist, so the version check costs
         # a level nothing.
         live = nepoch_l[edst_l] == eepoch_l
-        edst_live = jnp.where(live, edst_l, n_local)
-        lookup = _word_lookup(send_idx_l, hsend_idx_l, eprod_l, ebslot_l)
-        ebit_u = ebit_l.astype(jnp.uint32)
+        idx, words = _word_index(send_idx_l, hsend_idx_l, eprod_l, ebslot_l)
 
-        def merge_fire(frontier, inv):
-            """One global exchange of ``frontier`` + a fire over EVERY
-            edge slot against it: one indexed read (the source word) and
-            one scatter. The already-invalid rule is a fact of the
+        # the WORKLIST: the live slots as (source key = word index and bit,
+        # destination row), in source order, compacted to a prefix by one
+        # sort (an order of magnitude cheaper an element than an indexed
+        # op); what is no live slot sorts behind them. It is a value of
+        # this wave: a slot leaves it once it has fired, because a node is
+        # in a frontier at most once a wave.
+        e_cap = edst_l.shape[0]
+        chunk = _chunk_width(e_cap)
+        key = _WAITING | (idx.astype(jnp.uint32) << 5) | ebit_l.astype(jnp.uint32)
+        wkey, wdst = lax.sort(
+            (jnp.where(live, key, _DEAD_KEY), edst_l), num_keys=1, is_stable=False
+        )
+        pad = -e_cap % chunk  # whole chunks: a slice never clamps
+        work0 = (
+            jnp.concatenate([wkey, jnp.full(pad, _DEAD_KEY, jnp.uint32)]),
+            jnp.concatenate([wdst, jnp.full(pad, n_local, jnp.int32)]),
+            live.sum(dtype=jnp.int32),
+        )
+        lane = jnp.arange(chunk, dtype=jnp.int32)
+
+        def merge_fire(frontier, inv, work):
+            """One global exchange of ``frontier`` + a fire of the
+            worklist against it, chunk by chunk: per slot ONE indexed read
+            (the source word) and one scatter; then the slots that did not
+            fire close up, in place (the write offset never passes the
+            read position). The already-invalid rule is a fact of the
             destination ROW, so it is applied on the node rows after the
             scatter — ``max(a & ~inv[dst]) == max(a) & ~inv``, bit for
             bit. Shared by the sync per-level step and the async merge
-            epoch."""
-            intra_flat, cross_flat = _exchange_words(
-                frontier, send_idx_l, hsend_idx_l
+            epoch. Returns ``(newly lit rows, the worklist left)``."""
+            wkey, wdst, n_work = work
+            recv = words(*_exchange_words(frontier, send_idx_l, hsend_idx_l))
+
+            def fire(hit, k, d, valid, fired, n_fired):
+                """ONE sort serves the scatter and the partition: the slots
+                that fired come first, keyed by their destination row, so
+                the scatter's indices are sorted and XLA sorts nothing
+                itself; the waiting slots follow in source order (keys
+                from ``_WAITING`` up clamp to the dropped row) and move
+                to the front."""
+                key = jnp.where(
+                    fired, d.astype(jnp.uint32), jnp.where(valid, k, _DEAD_KEY)
+                )
+                key, d = lax.sort((key, d), num_keys=1, is_stable=False)
+                hit = hit.at[jnp.minimum(key, n_local).astype(jnp.int32)].set(
+                    True, mode="drop", indices_are_sorted=True
+                )
+                return hit, jnp.roll(key, -n_fired), jnp.roll(d, -n_fired)
+
+            def fire_chunk(i, st):
+                wkey, wdst, hit, kept = st
+                base = i * chunk
+                k = lax.dynamic_slice(wkey, (base,), (chunk,))
+                d = lax.dynamic_slice(wdst, (base,), (chunk,))
+                valid = lane < n_work - base
+                word = recv[(k & ~_WAITING) >> 5]
+                fired = valid & ((word >> (k & 31)) & 1).astype(bool)
+                n_fired = fired.sum(dtype=jnp.int32)
+                # a chunk in which nothing fired moves as it is: a wave
+                # with a tiny frontier pays its reads alone
+                hit, k, d = lax.cond(
+                    n_fired > 0, fire, lambda hit, k, d, *_: (hit, k, d),
+                    hit, k, d, valid, fired, n_fired,
+                )
+                wkey = lax.dynamic_update_slice(wkey, k, (kept,))
+                wdst = lax.dynamic_update_slice(wdst, d, (kept,))
+                n_valid = jnp.clip(n_work - base, 0, chunk)
+                return wkey, wdst, hit, kept + n_valid - n_fired
+
+            wkey, wdst, hit, kept = lax.fori_loop(
+                0, (n_work + chunk - 1) // chunk, fire_chunk,
+                (wkey, wdst, jnp.zeros_like(frontier), jnp.int32(0)),
             )
-            word = lookup(intra_flat, cross_flat)
-            src_active = ((word >> ebit_u) & 1).astype(bool)
-            hit = jnp.zeros_like(frontier).at[edst_live].max(src_active)
-            return hit & ~inv
+            return hit & ~inv, (wkey, wdst, kept)
+
+        def visited(visits, work):
+            """``visits`` (uint32 [lo, hi]) plus the slots the level about
+            to run reads: 64 bits, a deep wave over a full shard passes
+            2**31."""
+            lo = visits[0] + work[2].astype(jnp.uint32)
+            return jnp.stack([lo, visits[1] + (lo < visits[0])])
+
+        visits0 = jnp.zeros(2, jnp.uint32)
 
         if async_depth and async_depth > 0:
             # ---- asynchronous mode: speculative local levels between
             # counted-quiescence merges (ISSUE 17) ----
+            edst_live = jnp.where(live, edst_l, n_local)
+
             def spec_body(_i, st):
                 f, inv, acc, newly_l, spec = st
                 # local-only expansion: a remote-sourced edge's elsrc is
@@ -349,43 +449,49 @@ def build_routed_wave(
                 return carry[6]
 
             def body(carry):
-                f, inv, acc, count, merges, spec, _go = carry
+                f, inv, acc, count, merges, spec, _go, work, visits = carry
                 f, inv, acc, newly_l, spec = lax.fori_loop(
                     0, async_depth, spec_body,
                     (f, inv, acc, jnp.int32(0), spec),
                 )
                 # merge epoch: exchange the EVER-LIT accumulator and fire
-                # every edge against it — completes remote frontiers AND
-                # local rows the bounded speculation left unexpanded
-                nxt_m = merge_fire(acc, inv)
+                # the worklist against it — completes remote frontiers AND
+                # local rows the bounded speculation left unexpanded. A
+                # slot that fired is done here too: its source stays lit,
+                # its destination invalid
+                visits = visited(visits, work)
+                nxt_m, work = merge_fire(acc, inv, work)
                 inv = inv | nxt_m
                 acc = acc | nxt_m
                 newly = lax.psum(newly_l + nxt_m.sum(dtype=jnp.int32), ax)
                 # quiescence vote: the merge covers ALL edges against all
                 # ever-lit rows — firing nothing anywhere proves closure
                 go = lax.psum(nxt_m.any().astype(jnp.int32), ax) > 0
-                return nxt_m, inv, acc, count + newly, merges + 1, spec, go
+                return (nxt_m, inv, acc, count + newly, merges + 1, spec, go,
+                        work, visits)
 
-            _f, inv_l, _acc, count, levels, spec, _go = lax.while_loop(
+            _f, inv_l, _acc, count, levels, spec, _go, _work, visits = lax.while_loop(
                 cond, body,
-                (seeds_l, inv_l, seeds_l, count0, jnp.int32(0), jnp.int32(0), go0),
+                (seeds_l, inv_l, seeds_l, count0, jnp.int32(0), jnp.int32(0), go0,
+                 work0, visits0),
             )
-            return inv_l, count, levels, lax.pmax(spec, ax)
+            return inv_l, count, levels, lax.pmax(spec, ax), visits
 
         def cond(carry):
             return carry[4]
 
         def body(carry):
-            f_l, inv_l, count, levels, _go = carry
-            nxt_l = merge_fire(f_l, inv_l)
+            f_l, inv_l, count, levels, _go, work, visits = carry
+            visits = visited(visits, work)
+            nxt_l, work = merge_fire(f_l, inv_l, work)
             inv_l = inv_l | nxt_l
             newly = lax.psum(nxt_l.sum(dtype=jnp.int32), ax)
-            return nxt_l, inv_l, count + newly, levels + 1, newly > 0
+            return nxt_l, inv_l, count + newly, levels + 1, newly > 0, work, visits
 
-        _f, inv_l, count, levels, _go = lax.while_loop(
-            cond, body, (seeds_l, inv_l, count0, jnp.int32(0), go0)
+        _f, inv_l, count, levels, _go, _work, visits = lax.while_loop(
+            cond, body, (seeds_l, inv_l, count0, jnp.int32(0), go0, work0, visits0)
         )
-        return inv_l, count, levels, jnp.int32(0)
+        return inv_l, count, levels, jnp.int32(0), visits
 
     return jax.jit(_wave)
 
@@ -527,6 +633,7 @@ class RoutedShardedGraph:
         # -- telemetry --
         self.waves_run = 0
         self.levels_total = 0  # frontier exchanges (collective rounds)
+        self.slot_visits_total = 0  # worklist slots the levels read, all chips
         self.quiescence_checks = 0  # async merge epochs (each = one vote)
         self.spec_levels_total = 0  # deepest shard's productive spec levels
         self.shard_moves = 0
@@ -1070,8 +1177,18 @@ class RoutedShardedGraph:
         return True
 
     # ------------------------------------------------------------------ waves
-    def _count_exchange(self, levels: int, spec_levels: int = 0) -> None:
+    def _count_exchange(self, levels: int, spec_levels: int, visits) -> None:
+        """``visits``: uint32 [lo, hi] pairs, one a chip and wave."""
         self.levels_total += levels
+        lo_hi = np.asarray(visits, dtype=np.uint64).reshape(-1, 2)
+        visited = int(lo_hi[:, 0].sum() + (lo_hi[:, 1].sum() << np.uint64(32)))
+        self.slot_visits_total += visited
+        global_metrics().counter(
+            "fusion_mesh_routed_slot_visits_total",
+            help="edge slots the routed level loops read, summed over "
+            "levels and chips: a slot leaves its wave's worklist once it "
+            "has fired (a dense level loop reads levels x e_cap x n_dev)",
+        ).inc(visited)
         if self.exchange_async and levels:
             # async mode: each merge epoch ends in exactly one counted
             # quiescence vote over the psum plane (the level fence that
@@ -1206,15 +1323,16 @@ class RoutedShardedGraph:
                     key=(self.n_global, self.n_dev, self.exchange, capd, width),
                 ):
                     fn.lower(*args).compile()
-            self.g_invalid, counts, levels, spec, bufs = fn(*args)
+            self.g_invalid, counts, levels, spec, bufs, visits = fn(*args)
         with hot_span("routed.readback"):
-            self._sync(self.g_invalid, counts, levels, spec, bufs)
+            self._sync(self.g_invalid, counts, levels, spec, bufs, visits)
             counts = self._fetch(counts)
             levels = self._fetch(levels)
             spec = self._fetch(spec)
             bufs = self._fetch(bufs)
+            visits = self._fetch(visits)
         self.waves_run += 1
-        self._count_exchange(int(levels), int(spec))
+        self._count_exchange(int(levels), int(spec), visits)
         count = int(counts.sum())
         overflow = bool((counts > capd).any())
         node_ids: Optional[np.ndarray] = None
@@ -1245,12 +1363,12 @@ class RoutedShardedGraph:
                 jnp.zeros(n_global, bool).at[seed_rows].set(True, mode="drop"),
                 node_sh,
             )
-            inv2, _count, levels, spec = wave(
+            inv2, _count, levels, spec, visits = wave(
                 frontier, send, hsend, eprod, ebslot, ebit, edst, elsrc,
                 eep, nepoch, inv,
             )
             counts, bufs = compact(inv2, inv, is_real)
-            return inv2, counts, levels, spec, bufs
+            return inv2, counts, levels, spec, bufs, visits
 
         return collect
 
@@ -1314,7 +1432,7 @@ class RoutedShardedGraph:
             self._chain_cache[(K, width, capd)] = fn
         trace_cause = self._trace_cause_for_dispatch()
         trace_t0 = time.perf_counter()
-        self.g_invalid, counts, levels, spec, bufs = fn(
+        self.g_invalid, counts, levels, spec, bufs, visits = fn(
             self._host_arg(mat), self.g_send, self.g_hsend, self.g_eprod,
             self.g_ebslot, self.g_ebit, self.g_edst, self.g_elsrc, self.g_eep,
             self.g_node_epoch, self.g_invalid, self.g_is_real,
@@ -1322,8 +1440,9 @@ class RoutedShardedGraph:
         # multi-process: the chain's collectives must fully drain before
         # any later module's (harvest fetch, patch) hit the gloo pairs —
         # the dispatch stays nonblocking on a single-process mesh
-        self._sync(self.g_invalid, counts, levels, spec, bufs)
+        self._sync(self.g_invalid, counts, levels, spec, bufs, visits)
         return {"counts": counts, "levels": levels, "spec": spec, "bufs": bufs,
+                "visits": visits,
                 "stages": K, "capd": capd, "dispatches": 1,
                 "trace_cause": trace_cause, "trace_t0": trace_t0}
 
@@ -1341,15 +1460,15 @@ class RoutedShardedGraph:
                     jnp.zeros(n_global, bool).at[seed_rows].set(True, mode="drop"),
                     node_sh,
                 )
-                inv2, _c, levels, spec = wave(
+                inv2, _c, levels, spec, visits = wave(
                     frontier, send, hsend, eprod, ebslot, ebit, edst, elsrc,
                     eep, nepoch, inv,
                 )
                 counts, bufs = compact(inv2, inv, is_real)
-                return inv2, (counts, levels, spec, bufs)
+                return inv2, (counts, levels, spec, bufs, visits)
 
-            inv, (counts, levels, spec, bufs) = lax.scan(body, inv0, seed_mat)
-            return inv, counts, levels, spec, bufs
+            inv, (counts, levels, spec, bufs, visits) = lax.scan(body, inv0, seed_mat)
+            return inv, counts, levels, spec, bufs, visits
 
         return chain
 
@@ -1365,7 +1484,9 @@ class RoutedShardedGraph:
         bufs = self._fetch(pending["bufs"])
         capd = pending["capd"]
         self.waves_run += pending["stages"]
-        self._count_exchange(int(levels.sum()), int(spec.sum()))
+        self._count_exchange(
+            int(levels.sum()), int(spec.sum()), self._fetch(pending["visits"])
+        )
         counts = counts_dev.astype(np.int64).sum(axis=1)
         stage_ids: List[Optional[np.ndarray]] = []
         overflowed = False
@@ -2017,6 +2138,7 @@ class RoutedShardedGraph:
             "placement_epoch": self.placement.epoch,
             "waves_run": self.waves_run,
             "exchange_levels_total": self.levels_total,
+            "slot_visits_total": self.slot_visits_total,
             "exchange_async": self.exchange_async,
             "async_depth": self.async_depth,
             "quiescence_checks": self.quiescence_checks,

@@ -19,7 +19,9 @@ import pytest
 from stl_fusion_tpu.cluster import DevicePlacement, ShardMap
 from stl_fusion_tpu.graph.synthetic import power_law_dag
 from stl_fusion_tpu.parallel import RoutedShardedGraph, graph_mesh
-from test_routed_wave import EXCHANGES, LEVEL_RULE_CASES, check_level_rules
+from test_routed_wave import (
+    EXCHANGES, LEVEL_RULE_CASES, check_level_rules, check_worklist_chain,
+)
 
 
 def bfs_closure(adj, seeds):
@@ -94,6 +96,20 @@ def test_async_level_rules_match_host_levels(exchange, case):
     """ISSUE 33: the speculative levels and the merge epoch share the
     hoisted version check and the node-row invalid mask."""
     check_level_rules(exchange, case, async_depth=2)
+
+
+@pytest.mark.parametrize("case", ["bump_between_waves", "second_wave_no_restore"])
+@pytest.mark.parametrize("depth", [1, 4])
+def test_async_merge_fires_the_worklist(depth, case):
+    """ISSUE 37: a merge epoch fires the wave's worklist against the
+    ever-lit rows; a slot that fired there is done for the wave, at the
+    shallowest and at the default depth."""
+    check_level_rules("a2a", case, async_depth=depth)
+
+
+@pytest.mark.parametrize("depth", [1, 4])
+def test_async_chain_builds_a_worklist_per_stage(depth):
+    check_worklist_chain("a2a", async_depth=depth)
 
 
 def test_async_deep_chain_reclaims_barriers_strictly():

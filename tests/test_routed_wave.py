@@ -152,7 +152,10 @@ def test_routed_wave_matches_bfs_oracle(exchange):
 
 # ------------------------------------------------- the level's two rules
 EXCHANGES = ["a2a", "tree", "gather", "hier"]
-LEVEL_RULE_CASES = ["stale_and_invalid", "invalid_seed_conducts", "bump_between_waves"]
+LEVEL_RULE_CASES = [
+    "stale_and_invalid", "invalid_seed_conducts", "bump_between_waves",
+    "second_wave_no_restore",
+]
 
 
 class HostLevels:
@@ -175,15 +178,22 @@ class HostLevels:
         self.eep = np.concatenate([self.eep, np.asarray(add_ep, dtype=np.int32)])
 
     def wave(self, seeds, versions=True, skip_invalid=True, commit=True):
-        """(count, levels, newly ids ascending, mask after)."""
+        """(count, levels, newly ids ascending, mask after). ``slot_visits``
+        is then what the levels read: at each level the live slots whose
+        source has been in no earlier frontier (ISSUE 37: a slot leaves
+        the wave once it has fired)."""
         live = self.nepoch[self.dst] == self.eep if versions else slice(None)
         src, dst = self.src[live], self.dst[live]
         blocked = self.inv if skip_invalid else np.zeros_like(self.inv)
         frontier = np.zeros_like(self.inv)
         frontier[np.asarray(seeds, dtype=np.int64)] = True
         lit = frontier.copy()
+        fired = np.zeros_like(lit)  # rows that have been in a frontier
         levels = 0
+        self.slot_visits = 0
         while frontier.any():
+            self.slot_visits += int(np.count_nonzero(~fired[src]))
+            fired |= frontier
             hit = np.zeros_like(lit)
             hit[dst[frontier[src]]] = True
             frontier = hit & ~lit & ~blocked
@@ -196,10 +206,21 @@ class HostLevels:
         return int(newly.sum()), levels, np.flatnonzero(newly), mask
 
 
+def live_slots(g):
+    """Per device, the slots of its edge slice that a wave's worklist
+    starts with: neither pads nor behind their destination's version."""
+    nepoch = np.asarray(g.g_node_epoch).reshape(g.n_dev, g.n_local)
+    edst = g._h_edst.reshape(g.n_dev, g.e_cap)
+    eep = g._h_eep.reshape(g.n_dev, g.e_cap)
+    rows = np.minimum(edst, g.n_local - 1)  # a pad's gather clamps; -1 never matches
+    return (np.take_along_axis(nepoch, rows, axis=1) == eep).sum(axis=1)
+
+
 def check_level_rules(exchange, case, async_depth=0):
     """One case of the level's rules on the device against
     :class:`HostLevels`: count, compacted newly ids, the mask and (sync
-    mode: an async merge epoch is no BFS level) the number of levels."""
+    mode: an async merge epoch is no BFS level) the number of levels and
+    the slots they read."""
     n = 4000
     smap = ShardMap.initial(["a", "b"], n_shards=32)
     pl = DevicePlacement.build(
@@ -235,17 +256,22 @@ def check_level_rules(exchange, case, async_depth=0):
         assert g.patch_batch(bumped, u, again, ep.astype(np.int32))
 
     def wave(seeds):
-        levels0 = g.levels_total
+        levels0, visits0 = g.levels_total, g.slot_visits_total
         want_count, want_levels, want_ids, want_mask = host.wave(seeds)
         count, ids, over = g.run_wave_collect(seeds)
         assert not over
         assert count == want_count
         assert np.array_equal(np.sort(ids), want_ids)
         assert np.array_equal(g.invalid_mask(), want_mask)
+        levels, visits = g.levels_total - levels0, g.slot_visits_total - visits0
         if async_depth:
-            assert 1 <= g.levels_total - levels0 <= want_levels
+            # a merge epoch fires the ever-lit rows: no BFS level, so its
+            # reads are bounded, not the host's
+            assert 1 <= levels <= want_levels
+            assert 0 < visits <= levels * int(live_slots(g).sum())
         else:
-            assert g.levels_total - levels0 == want_levels
+            assert levels == want_levels
+            assert visits == host.slot_visits
         return want_count, want_ids, want_mask
 
     if case == "stale_and_invalid":
@@ -264,6 +290,15 @@ def check_level_rules(exchange, case, async_depth=0):
         assert np.flatnonzero(mask).tolist() == [5, 700, 1005, 2005, 2700]
         count, ids, mask = wave([2005])  # as a seed it conducts
         assert count == 2 and ids.tolist() == [3005, 3905]
+    elif case == "second_wave_no_restore":
+        # ISSUE 37: the worklist is a wave's own value. The first wave's
+        # closure (a seed of it invalid before) is the second's ``inv`` at
+        # entry: slots that fired then start in the worklist again, their
+        # destinations stay dark, and the counts add up
+        patch(n_bump=200, n_redeclare=50)
+        first = rng.choice(n // 10, size=4, replace=False).tolist()
+        wave(first + np.flatnonzero(pre)[:1].tolist())
+        wave(rng.choice(n // 10, size=4, replace=False).tolist() + first[:2])
     else:
         wave(rng.choice(np.arange(n // 2, n), size=5, replace=False).tolist())
         seeds = rng.choice(n // 10, size=5, replace=False).tolist()
@@ -284,6 +319,67 @@ def test_level_rules_match_host_levels(exchange, case):
     check_level_rules(exchange, case)
 
 
+def check_worklist_chain(exchange, async_depth=0):
+    """ISSUE 37: the worklist spans several chunks with a partial last one
+    (pads behind it, slots behind their destination's version out of it),
+    and the chain builds one per stage: three stages in ONE dispatch, the
+    first two sharing seeds, against :class:`HostLevels` stage by stage,
+    then a single wave on top with no restore between."""
+    from stl_fusion_tpu.parallel.routed_wave import _chunk_width
+
+    n = 4000
+    pl = DevicePlacement.build(
+        ShardMap.initial(["a", "b"], n_shards=32), 8, n,
+        devices_per_host=4 if exchange == "hier" else None,
+    )
+    rng = np.random.default_rng(37)
+    src, dst, _adj = make_graph(n, seed=5)
+    pre = np.zeros(n, dtype=bool)
+    pre[rng.choice(n, size=200, replace=False)] = True
+    host = HostLevels(src, dst, n, invalid=pre)
+    g = RoutedShardedGraph(
+        src, dst, n, pl, mesh=graph_mesh(), exchange=exchange, invalid=pre,
+        exchange_async=async_depth > 0, async_depth=async_depth,
+    )
+    bumped = rng.choice(np.unique(dst), size=300, replace=False)
+    host.patch(bumped, [], [], [])
+    assert g.patch_batch(bumped, np.empty(0, np.int64), np.empty(0, np.int64),
+                         np.empty(0, np.int32))
+    chunk, live = _chunk_width(g.e_cap), live_slots(g)
+    # every chip's worklist spans chunks and ends inside one
+    assert (live > chunk).all() and (live.max() > 4 * chunk) and (live % chunk != 0).all()
+    assert (live < (g._h_eep.reshape(g.n_dev, -1) >= 0).sum(axis=1)).all()  # stale
+    assert live.max() < g.e_cap  # pads
+
+    low = rng.choice(n // 10, size=6, replace=False).tolist()
+    stages = [low[:3], low[2:], rng.choice(n, size=3, replace=False).tolist()]
+    want = [host.wave(st) + (host.slot_visits,) for st in stages]
+    counts, stage_ids, info = g.harvest_union_chain(g.dispatch_union_chain(stages))
+    assert not info["overflowed"]
+    for (count, levels, ids, _mask, _v), c, got, lv in zip(
+        want, counts, stage_ids, info["levels"].ravel()
+    ):
+        assert int(c) == count and np.array_equal(np.sort(got), ids)
+        assert int(lv) == levels if not async_depth else 1 <= int(lv) <= max(levels, 1)
+    assert np.array_equal(g.invalid_mask(), want[-1][3])
+    levels = sum(w[1] for w in want)
+    if not async_depth:
+        assert g.levels_total == levels
+        assert g.slot_visits_total == sum(w[4] for w in want)
+    assert 0 < g.slot_visits_total < g.levels_total * g.e_cap * g.n_dev  # dense
+    assert g.stats()["slot_visits_total"] == g.slot_visits_total
+    seeds = rng.choice(n // 10, size=4, replace=False).tolist()
+    count, _levels, ids, mask = host.wave(seeds)
+    got_count, got, over = g.run_wave_collect(seeds)
+    assert not over and got_count == count and np.array_equal(np.sort(got), ids)
+    assert np.array_equal(g.invalid_mask(), mask)
+
+
+@pytest.mark.parametrize("exchange", EXCHANGES)
+def test_worklist_spans_chunks_and_chain_counts_visits(exchange):
+    check_worklist_chain(exchange)
+
+
 def _sub_jaxprs(eqn):
     for v in eqn.params.values():
         for x in v if isinstance(v, (list, tuple)) else (v,):
@@ -299,33 +395,66 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
+def _named(jaxpr, *prefixes):
+    return [e for e in _eqns(jaxpr) if e.primitive.name.startswith(prefixes)]
+
+
 def test_level_loop_reads_each_edge_slot_once():
-    """ISSUE 33, structural: the ``while`` body of the a2a sync wave holds
-    exactly ONE gather indexed per edge slot (the source word). A per-edge
-    row lookup put back inside the level fails here, not in a benchmark."""
+    """ISSUE 33 and 37, structural: the level loop (the a2a sync wave's
+    outer ``while``) holds ONE inner loop, over the chunks of the
+    worklist; a chunk is exactly one indexed read of the exchanged words
+    and one scatter, both of chunk width. Nothing of the width of the
+    edge slice is read or scattered inside a level, and no node-row table
+    is read per slot. A per-edge lookup put back inside the level fails
+    here, not in a benchmark."""
     import jax
+
+    from stl_fusion_tpu.parallel.routed_wave import _chunk_width
 
     n = 4000
     src, dst, _adj = make_graph(n)
     pl = DevicePlacement.build(ShardMap.initial(["a", "b"], n_shards=32), 8, n)
     g = RoutedShardedGraph(src, dst, n, pl, mesh=graph_mesh(), exchange="a2a")
-    assert g.e_cap not in (g.n_local, g.w_local, g.g_send.shape[-1])
+    chunk = _chunk_width(g.e_cap)
+    assert g.e_cap not in (g.n_local, g.w_local, g.g_send.shape[-1], chunk)
+    assert chunk not in (g.n_local, g.w_local, g.g_send.shape[-1])
     jaxpr = jax.make_jaxpr(g._wave)(
         g.g_invalid, g.g_send, g.g_hsend, g.g_eprod, g.g_ebslot, g.g_ebit,
         g.g_edst, g.g_elsrc, g.g_eep, g.g_node_epoch, g.g_invalid,
     )
-    loops = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "while"]
-    assert len(loops) == 1  # the level loop, one program
-    body = loops[0].params["body_jaxpr"].jaxpr
-    per_edge = [
-        e for e in _eqns(body)
-        if e.primitive.name == "gather" and e.invars[1].aval.shape[0] == g.e_cap
+    # two loops in the program: the level loop and, in its body, the chunks'
+    whiles = _named(jaxpr.jaxpr, "while")
+    assert len(whiles) == 2
+    (level,) = [
+        w.params["body_jaxpr"].jaxpr for w in whiles
+        if _named(w.params["body_jaxpr"].jaxpr, "while")
     ]
-    assert len(per_edge) == 1
-    # and it reads the exchanged words, not a node-row table
-    assert per_edge[0].invars[0].aval.dtype == np.uint32
-    scatters = [e for e in _eqns(body) if e.primitive.name.startswith("scatter")]
-    assert [e.invars[1].aval.shape[0] for e in scatters] == [g.e_cap]
+    (chunk_loop,) = _named(level, "while")  # its trip count a carried value
+    chunk_body = chunk_loop.params["body_jaxpr"].jaxpr
+    # the level outside its chunk loop: the pack and the send buckets,
+    # nothing per edge slot and nothing that looks a node row up
+    in_chunk = {id(e) for e in _eqns(chunk_body)}
+    outside = [e for e in _named(level, "gather", "scatter") if id(e) not in in_chunk]
+    assert [e.primitive.name for e in outside] == ["gather"]  # words_p[send_idx]
+    assert outside[0].invars[1].aval.shape[:2] == (g.n_dev, g.g_send.shape[-1])
+    # a chunk: one read of the exchanged words, one scatter, chunk wide
+    (read,) = _named(chunk_body, "gather")
+    assert read.invars[1].aval.shape[0] == chunk
+    assert read.invars[0].aval.dtype == np.uint32
+    assert read.invars[0].aval.shape == (g.n_dev * g.g_send.shape[-1],)
+    (scatter,) = _named(chunk_body, "scatter")
+    assert scatter.invars[1].aval.shape[0] == chunk
+    assert scatter.invars[0].aval.shape == (g.n_local,)
+    # one sort a chunk serves the scatter (its indices arrive sorted, so the
+    # compiler adds none of its own) and the partition
+    (sort,) = _named(chunk_body, "sort")
+    assert sort.invars[0].aval.shape == (chunk,) and len(sort.invars) == 2
+    assert scatter.params["indices_are_sorted"]
+    # and nothing as wide as the edge slice is read, scattered or sorted
+    padded = -(-g.e_cap // chunk) * chunk
+    for e in _named(level, "gather", "scatter", "sort"):
+        for v in e.invars:
+            assert g.e_cap not in v.aval.shape and padded not in v.aval.shape, e
 
 
 @pytest.mark.parametrize("dph", [2, 4])
