@@ -4,6 +4,13 @@ pair): products and carts with transparent caching and command-driven
 cascading invalidation, plus a `changes()` watcher that live-prints totals.
 
 Run: python examples/hello_cart.py
+
+Scalar nodes only, four products, no device backend. The same sample as a
+TABLE-BACKED service at TPC-C's scale (100,000 products, 3,000,000 carts,
+3,000,000 derived totals kept hot on the device: ``TableBacking(hot=True)``),
+served to subscribed clients, is the benchmark's deployment
+``benchmarks/deployments/cart_served.py`` (configuration
+``benchmarks/configs/hellocart-w100-1c.json``).
 """
 import asyncio
 import os

@@ -210,6 +210,18 @@ class ComputeMethodFunction(FunctionBase):
                     if table is not None:
                         row = method_def.row_for_args(args, table)
                         if row is not None:
+                            backend = self.hub.graph_backend
+                            if (
+                                table.hot
+                                and backend is not None
+                                and backend.is_wave_echo(_node._backend_nid)
+                            ):
+                                # a hot table's row was marked by the wave
+                                # itself and has been refreshed since: the
+                                # wave's echo (a displaced computed
+                                # materializes at its re-read) must not
+                                # make the fresh row stale again
+                                return
                             table.invalidate([row])
 
                 computed.on_invalidated(mark_row_stale)

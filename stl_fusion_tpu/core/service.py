@@ -119,12 +119,13 @@ class TableBacking:
 
     __slots__ = (
         "rows", "batch", "row_shape", "dtype", "keys", "device_batch",
-        "device_args",
+        "device_args", "hot",
     )
 
     def __init__(
         self, rows: int, batch: str, row_shape: tuple = (), dtype=None, keys=False,
         device_batch: Optional[str] = None, device_args: Optional[str] = None,
+        hot: bool = False,
     ):
         self.rows = int(rows)
         self.batch = batch
@@ -145,6 +146,16 @@ class TableBacking:
         self.device_args = device_args
         if device_batch is not None and keys:
             raise ValueError("device_batch requires dense int keys (keys=False)")
+        #: KEPT HOT: once the table is bound to a graph backend
+        #: (``bind_table_rows``, a full bind), the rows a wave invalidates
+        #: are recomputed on the device through the device loader right
+        #: after the wave is applied, sources before the rows derived from
+        #: them (the declared cross-block edges give the order), and are
+        #: valid again in the graph before the next wave. Needs
+        #: ``device_batch``.
+        self.hot = bool(hot)
+        if self.hot and device_batch is None:
+            raise ValueError("hot=True requires a device loader (device_batch=...)")
 
     def make_codec(self) -> Optional["InternKeyCodec"]:
         if self.keys is True:
@@ -257,6 +268,7 @@ class ComputeMethodDef:
             )
             table.key_codec = codec
             table.key_arity = arity
+            table.hot = spec.hot
             if spec.device_batch is not None:
                 table.device_compute_fn = getattr(service, spec.device_batch)
                 if spec.device_args is not None:

@@ -282,7 +282,7 @@ class TpuGraphBackend:
             # own (placed) methods: the table programs and the loader's args
             run_on_device(self, device, (
                 "refresh_block_on_device", "warm_block_on_device",
-                "_block_refresh_state",
+                "_block_refresh_state", "refresh_rows_on_device",
             ))
         self._lock = threading.Lock()
         self._id_by_input: Dict["ComputedInput", int] = {}
@@ -312,6 +312,19 @@ class TpuGraphBackend:
         self._block_bases = np.empty(0, dtype=np.int64)
         self._block_ends = np.empty(0, dtype=np.int64)
         self._block_by_table: Dict[int, RowBlock] = {}
+        #: the blocks of tables declared ``hot`` (kept fresh after every
+        #: wave: :meth:`refresh_hot`), in refresh order: a block that is the
+        #: source of a declared cross-block edge before the edge's target.
+        #: Empty unless a bound table says so: no path then gains a call
+        self._hot_blocks: List[RowBlock] = []
+        #: declared cross-block edges, as (source block base, target block
+        #: base) pairs: what orders the hot blocks
+        self._block_edges: set = set()
+        self.hot_refresh_rows = 0  # rows the sparse refresh recomputed
+        self.hot_refresh_dispatches = 0  # refresh_rows programs dispatched
+        #: waves whose hot blocks the whole-block program refreshed: the
+        #: wave arrived as a mask, or with more rows than the sparse widths
+        self.hot_refresh_block_fallbacks = 0
         self._sharded_mirror: Optional[dict] = None  # see sharded_mirror
         self._packed_mirror: Optional[dict] = None  # see packed_mirror
         self._routed_mirror: Optional[dict] = None  # see routed_mirror
@@ -385,6 +398,11 @@ class TpuGraphBackend:
             "fusion_device_invalidations_total": self.device_invalidations,
             "fusion_wave_echo_marks_dropped_total": self.wave_echo_marks_dropped,
             "fusion_sweep_packed_dispatches_total": self.graph.sweep_packed_dispatches,
+            "fusion_mirror_recaptures_in_place_total": self.graph.mirror_rows_kept,
+            "fusion_mirror_slots_revived_total": self.graph.mirror_slots_revived,
+            "fusion_hot_refresh_rows_total": self.hot_refresh_rows,
+            "fusion_hot_refresh_dispatches_total": self.hot_refresh_dispatches,
+            "fusion_hot_refresh_block_fallbacks_total": self.hot_refresh_block_fallbacks,
         }
 
     def _begin_wave(self) -> str:
@@ -680,7 +698,7 @@ class TpuGraphBackend:
         """:meth:`flush`'s body, for a non-empty journal taken at ``t_flush0``."""
         journal_pre = len(journal)
         with hot_span("flush.coalesce"):
-            journal = self._coalesce_bump_epack_pairs(journal)
+            journal = self._group_commuting_entries(journal)
         journal_post = len(journal)
         icasc_parts: List[np.ndarray] = []
         icasc_s = 0.0  # embedded wave time: reported on the wave records,
@@ -720,8 +738,8 @@ class TpuGraphBackend:
             j = i
             while j < n and journal[j][0] == kind:
                 j += 1
-            # (after _coalesce_bump_epack_pairs, an N-recompute storm's
-            # alternating pairs arrive here as two long same-kind runs)
+            # (after _group_commuting_entries, an N-recompute storm's
+            # alternating entries arrive here as a few long same-kind runs)
             batch = [payload for _, payload in journal[i:j]]
             if kind in ("cpack", "bump") and icasc_parts:
                 # a refresh/recompute of an ALREADY-ACCUMULATED mark must
@@ -781,57 +799,60 @@ class TpuGraphBackend:
             self.graph.mark_invalid(np.asarray(batch, dtype=np.int32))
 
     @staticmethod
-    def _coalesce_bump_epack_pairs(journal: List[Tuple[str, object]]) -> List[Tuple[str, object]]:
-        """Rewrite maximal alternating ``bump x, epack(→x), bump y,
-        epack(→y), ...`` runs (pairwise-distinct nids) into a bump run
-        followed by an epack run, so the batcher below replays them as ONE
-        epoch scatter + ONE edge append instead of 2N device dispatches.
+    def _group_commuting_entries(journal: List[Tuple[str, object]]) -> List[Tuple[str, object]]:
+        """Regroup every stretch of ``bump``, ``cpack``, ``edge`` and
+        ``epack`` entries by kind (bumps, then cpacks, then edges, then
+        epacks, each kind in its own order), so that the batcher replays the
+        stretch as four runs and not as one run an entry.
 
-        This is the re-subscription/scalar-churn storm shape: every scalar
-        recompute of a row node journals exactly this pair
-        (``_on_register``), and at N recomputes per flush the per-op replay
-        dominated the live loop (~0.5 s/op at 10M — the r5 'scalar churn'
-        phase). Reordering is sound because the entries commute: an epack's
-        edges carry their DEPENDENT's current epoch, which only that
-        dependent's own bump (already ahead of it) changes — a later bump
-        of a DIFFERENT nid cannot affect them. A repeated nid ends the run
-        (its second bump must observe the first pair applied in order)."""
-        n = len(journal)
-        if n < 4:
-            return journal
+        Scalar reads journal alternations. A recompute of a row node is
+        ``bump x, epack(-> x)`` and then one captured ``edge`` per awaited
+        dependency, some of which recompute in their turn; N recomputes a
+        flush were 2N device dispatches and more (~0.5 s/op at 10M: the r5
+        'scalar churn' phase; five re-read totals of ten products each
+        twenty-five runs a command). A FIRST read of a row whose body awaits
+        other rows is adoption ``cpack``, captured ``edge``, the next
+        dependency's ``cpack``, its ``edge``, ...: 1,600 subscribed totals
+        were 38,000 runs of one entry.
+
+        Why the regrouping is sound: a ``cpack`` clears invalid bits and
+        touches neither epochs nor edges; an edge append (``edge``,
+        ``epack``) reads its DEPENDENT's current epoch and nothing else, so
+        it commutes with everything but a ``bump`` of that dependent; a
+        ``bump`` commutes with the bumps and cpacks of other nodes. So a
+        bump may move to the front of its stretch unless an entry before it
+        in the stretch adds an edge INTO its node (that edge was captured at
+        the old epoch and must stay dead) or bumps the same node again (the
+        second bump must see the first recapture applied): such a bump
+        starts a new stretch where it stands. ``icasc`` and ``invalid`` set
+        invalid bits, which bumps and cpacks clear: they end a stretch and
+        nothing crosses them."""
         out: List[Tuple[str, object]] = []
-        i = 0
+        i, n = 0, len(journal)
         while i < n:
-            if (
-                i + 3 < n
-                and journal[i][0] == "bump"
-                and journal[i + 1][0] == "epack"
-            ):
-                bumps: List[Tuple[str, object]] = []
-                epacks: List[Tuple[str, object]] = []
-                seen = set()
-                j = i
-                while (
-                    j + 1 < n
-                    and journal[j][0] == "bump"
-                    and journal[j + 1][0] == "epack"
-                    and journal[j][1] not in seen
-                ):
-                    nid = journal[j][1]
-                    _srcs, dsts = journal[j + 1][1]
-                    if len(dsts) == 0 or not (dsts == nid).all():
-                        break  # not the re-declare shape: keep strict order
-                    seen.add(nid)
-                    bumps.append(journal[j])
-                    epacks.append(journal[j + 1])
-                    j += 2
-                if len(bumps) > 1:
-                    out.extend(bumps)
-                    out.extend(epacks)
-                    i = j
-                    continue
-            out.append(journal[i])
-            i += 1
+            kinds: Dict[str, list] = {"bump": [], "cpack": [], "edge": [], "epack": []}
+            bumped, into, into_packs = set(), set(), []
+            while i < n and journal[i][0] in kinds:
+                kind, payload = journal[i]
+                if kind == "bump":
+                    if (
+                        payload in bumped
+                        or payload in into
+                        or any((dsts == payload).any() for dsts in into_packs)
+                    ):
+                        break  # stays behind what it must follow
+                    bumped.add(payload)
+                elif kind == "edge":
+                    into.add(payload[1])
+                elif kind == "epack":
+                    into_packs.append(payload[1])
+                kinds[kind].append(journal[i])
+                i += 1
+            for kind in ("bump", "cpack", "edge", "epack"):
+                out.extend(kinds[kind])
+            if i < n and journal[i][0] not in kinds:
+                out.append(journal[i])  # icasc / invalid: where it stood
+                i += 1
         return out
 
     # ------------------------------------------------------------------ columnar ingest
@@ -855,6 +876,12 @@ class TpuGraphBackend:
         n = int(n_rows if n_rows is not None else table.n_rows)
         if n > table.n_rows:
             raise ValueError(f"n_rows {n} exceeds table rows {table.n_rows}")
+        if table.hot and (table.device_compute_fn is None or n != table.n_rows):
+            raise ValueError(
+                "a hot table is refreshed on the device after every wave: it "
+                "needs a device loader (TableBacking(device_batch=...)) and a "
+                "FULL bind"
+            )
         with self._lock:
             existing = self._block_by_table.get(id(table))
             if existing is not None:
@@ -878,6 +905,8 @@ class TpuGraphBackend:
                 [b.end() for b in self._row_blocks], dtype=np.int64
             )
             self._block_by_table[id(table)] = blk
+            if table.hot:
+                self._hot_blocks = self._ordered_hot_blocks(self._block_edges)
 
         def on_inv(ids_np, _blk=blk):
             ids64 = np.asarray(ids_np, np.int64)
@@ -937,12 +966,47 @@ class TpuGraphBackend:
         src_nids = (src_block.base + src_rows).astype(np.int32)
         dst_nids = (dst_block.base + dst_rows).astype(np.int32)
         with self._lock:
+            pair = (src_block.base, dst_block.base)
+            if src_block is not dst_block and pair not in self._block_edges:
+                # raises on a cycle between hot blocks, before anything is
+                # declared
+                self._hot_blocks = self._ordered_hot_blocks(self._block_edges | {pair})
+                self._block_edges.add(pair)
             self._journal.append(("epack", (src_nids, dst_nids)))
             dst_block._decl_src.append(src_nids)
             dst_block._decl_dst.append(dst_nids)
             # the cached CSR stays: per-row queries scan the new tail
             # (declared_in_srcs); only clear_declared_row_edges rebuilds
         return int(src_nids.size)
+
+    def _ordered_hot_blocks(self, block_edges: set) -> List[RowBlock]:
+        """The hot blocks in refresh order under the declared cross-block
+        edges ``block_edges``: a block whose rows others are computed from
+        (through any chain of blocks) comes before them. Two hot blocks
+        that each reach the other have no order: ValueError."""
+        hot = [b for b in self._row_blocks if b.table.hot]
+        below: Dict[int, set] = {}
+        for blk in hot:  # what each hot block reaches (a handful of blocks)
+            seen, stack = set(), [blk.base]
+            while stack:
+                at = stack.pop()
+                for s_base, d_base in block_edges:
+                    if s_base == at and d_base not in seen:
+                        seen.add(d_base)
+                        stack.append(d_base)
+            below[blk.base] = seen
+        for a in hot:
+            for b in hot:
+                if a is not b and b.base in below[a.base] and a.base in below[b.base]:
+                    raise ValueError(
+                        "declared edges make a cycle between two hot tables "
+                        f"(blocks at {a.base} and {b.base}): neither can be "
+                        "refreshed before the other"
+                    )
+        # fewer hot blocks below = later in a chain; ties keep bind order
+        return sorted(
+            hot, key=lambda b: -sum(1 for o in hot if o.base in below[b.base])
+        )
 
     @staticmethod
     def _check_rows(block: RowBlock, rows) -> np.ndarray:
@@ -1004,6 +1068,12 @@ class TpuGraphBackend:
             self._profile_wave("union", len(nids), cause, t0, t1, len(newly_ids), wave_seq)
             return total
 
+    @staticmethod
+    def _loader_args(table) -> tuple:
+        """The device loader's runtime arguments, made now (the loader's
+        state may have changed since the last refresh)."""
+        return tuple(table.device_loader_args()) if table.device_loader_args is not None else ()
+
     def _block_refresh_state(self, block: RowBlock) -> dict:
         """The device-refresh runtime state the resident super-round
         program (``graph/superround.py``) threads through its loop carry
@@ -1023,11 +1093,7 @@ class TpuGraphBackend:
                 "the fused burst→refresh composition requires a FULL table bind"
             )
         update_valid = not table._valid_dev_dirty
-        loader_args = (
-            tuple(table.device_loader_args())
-            if table.device_loader_args is not None
-            else ()
-        )
+        loader_args = self._loader_args(table)
         return {
             "base": block.base,
             "n_rows": block.n_rows,
@@ -1081,7 +1147,12 @@ class TpuGraphBackend:
         the columnar cache, and this refresh recomputes exactly the rows
         the graph holds invalid, so ``valid_mask`` shows that row stale
         until the table's own next read of it (``read_batch``,
-        ``table.refresh``) recomputes it."""
+        ``table.refresh``) recomputes it.
+
+        The loader runs for EVERY row of the table and the result is
+        masked. Its sparse twin, :meth:`refresh_rows_on_device`, runs it
+        for the rows of one wave alone; a table declared ``hot`` gets one
+        or the other after every wave (:meth:`refresh_hot`)."""
         with hot_span("refresh"):
             self.flush()
             table = block.table
@@ -1099,11 +1170,7 @@ class TpuGraphBackend:
                 )
             g = self.graph.device_arrays()
             update_valid = not table._valid_dev_dirty
-            loader_args = (
-                tuple(table.device_loader_args())
-                if table.device_loader_args is not None
-                else ()
-            )
+            loader_args = self._loader_args(table)
             prog = block._dev_refresh.get(update_valid)
             if prog is None:
                 import jax
@@ -1151,6 +1218,128 @@ class TpuGraphBackend:
             _finish_block_refresh_bookkeeping(table, cleared)
             return n_cleared
 
+    #: a sparse refresh pads its ids to a power of two, no narrower than
+    #: this: every distinct width is a compile, and a wave of a few hundred
+    #: rows costs the device the same as one of one row
+    HOT_REFRESH_MIN_WIDTH = 512
+    #: a wave with more rows than this in one hot block refreshes the block
+    #: by the whole-block program (the lat kernel's own cap on a wave's ids:
+    #: the sparse widths stay a handful of programs)
+    HOT_REFRESH_MAX_ROWS = 8192
+
+    def refresh_hot(self, newly) -> int:
+        """Make fresh again, on the device, what one applied wave
+        invalidated in the hot tables: called by the wave pipeline after
+        the wave's apply (marks, fan-out, ticket), before the next wave is
+        dispatched. The form ``newly`` has decides the program: an id array
+        (the small-wave path) refreshes exactly those rows
+        (:meth:`refresh_rows_on_device`); a bool mask over node ids (a
+        fused chain, a lane burst) refreshes every hot block it touches by
+        :meth:`refresh_block_on_device`, in the same block order, and is
+        counted in ``hot_refresh_block_fallbacks``. Dispatch only: nothing
+        here waits for the device. Returns the rows made fresh."""
+        if not self._hot_blocks or len(newly) == 0:
+            return 0
+        if not (isinstance(newly, np.ndarray) and newly.dtype == np.bool_):
+            return self.refresh_rows_on_device(newly)
+        touched = [b for b in self._hot_blocks if newly[b.base : b.end()].any()]
+        self.hot_refresh_block_fallbacks += bool(touched)
+        return sum(self.refresh_block_on_device(blk) for blk in touched)
+
+    def refresh_rows_on_device(self, nids) -> int:
+        """The sparse twin of :meth:`refresh_block_on_device`: recompute on
+        the device the rows of the HOT tables among the node ids ``nids``
+        (one applied wave's newly invalid set), through each table's device
+        loader called on those ids alone, and make them valid again in the
+        graph. Per hot block that holds some of them ONE program,
+        ``refresh_rows``: ``fn(ids, *loader_args)``, a scatter into the
+        table's values (donated: in place), the rows' bits cleared in the
+        device ``invalid`` array; ids padded to a power of two by repeating
+        the first (:data:`HOT_REFRESH_MIN_WIDTH`). Blocks go in the order of
+        the declared cross-block edges, and each block's loader arguments
+        are made when its turn comes: a derived row is computed from source
+        rows that are already fresh. Host bookkeeping from the ids, no
+        readback, exactly what the whole-block refresh does for a block:
+        ``_h_invalid``, ``invalid_version``, the table's stale mask and
+        count, its version, the non-backend ``on_refresh`` hooks. Scalar
+        twins stay pending-invalid until their next read. A block with more
+        than :data:`HOT_REFRESH_MAX_ROWS` of the ids takes the whole-block
+        program instead (counted as a fallback). Rows of tables that are not
+        hot, and every other row of the hot ones, are not touched. Returns
+        the rows refreshed."""
+        nids = np.asarray(nids, dtype=np.int64)
+        if not self._hot_blocks or nids.size == 0:
+            return 0
+        with hot_span("refresh.rows"):
+            self.flush()
+            dg = self.graph
+            sparse, whole = 0, []  # rows by refresh_rows; by the block program
+            for blk in self._hot_blocks:
+                rows = nids[(nids >= blk.base) & (nids < blk.end())] - blk.base
+                if rows.size == 0:
+                    continue
+                if rows.size > self.HOT_REFRESH_MAX_ROWS:
+                    whole.append(self.refresh_block_on_device(blk))
+                    continue
+                rows = np.unique(rows).astype(np.int32)
+                table = blk.table
+                padded = dg._pad_ids_pow2(rows, self.HOT_REFRESH_MIN_WIDTH)
+                loader_args = self._loader_args(table)
+                g = dg.device_arrays()
+                with hot_span("refresh.rows.dispatch"):
+                    table._values, inv2 = self._refresh_rows_program(blk)(
+                        table._values, g.invalid, table._put(padded), *loader_args
+                    )
+                dg._g = g._replace(invalid=inv2)
+                dg._h_invalid[blk.base + rows] = False
+                dg.invalid_version += 1
+                table._stale_count -= int(np.count_nonzero(table._stale_host[rows]))
+                table._stale_host[rows] = False
+                table._defer_valid(rows, True)
+                table._bump()
+                for h in table.on_refresh:
+                    if not getattr(h, "_backend_hook", False):
+                        h(rows)
+                self.hot_refresh_dispatches += 1
+                sparse += len(rows)
+            self.hot_refresh_rows += sparse
+            self.hot_refresh_block_fallbacks += bool(whole)
+            return sparse + sum(whole)
+
+    @staticmethod
+    def _refresh_rows_program(block: RowBlock):
+        """The jitted ``refresh_rows`` of one block (one trace a width)."""
+        prog = block._dev_refresh.get("rows")
+        if prog is None:
+            import functools
+
+            import jax
+
+            fn, base = block.table.device_compute_fn, block.base
+
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def refresh_rows(values, g_invalid, ids, *largs):
+                return (
+                    values.at[ids].set(fn(ids, *largs)),
+                    g_invalid.at[base + ids].set(False),
+                )
+
+            prog = block._dev_refresh["rows"] = refresh_rows
+        return prog
+
+    def warm_hot_refresh(self) -> None:
+        """Compile (or load) every hot block's ``refresh_rows`` program at
+        the narrowest width, recorded as ``refresh_rows`` in
+        ``program_warm_report()``: each block refreshes its row 0, which
+        changes nothing on a table that holds nothing stale."""
+        from .program_cache import time_program_warm
+
+        key = tuple((b.base, b.n_rows) for b in self._hot_blocks)
+        with time_program_warm("refresh_rows", key=(key, self.HOT_REFRESH_MIN_WIDTH)):
+            self.refresh_rows_on_device([b.base for b in self._hot_blocks])
+            for blk in self._hot_blocks:
+                blk.table._values.block_until_ready()
+
     def warm_block_on_device(self, block: RowBlock) -> int:
         """Load EVERY row of a bound table through its DEVICE loader in one
         dispatch — the cold-start warm. The host-loader alternative
@@ -1174,11 +1363,7 @@ class TpuGraphBackend:
                 "block has outstanding invalid marks — use "
                 "refresh_block_on_device() (warm is for cold tables)"
             )
-        loader_args = (
-            tuple(table.device_loader_args())
-            if table.device_loader_args is not None
-            else ()
-        )
+        loader_args = self._loader_args(table)
         prog = block._dev_refresh.get("warm")
         if prog is None:
             import jax

@@ -257,6 +257,12 @@ class DeviceGraph:
         self._async_rebuild: Optional[dict] = None
         self._rebuild_deltas: Optional[list] = None
         self.mirror_patches = 0  # patch applications (batches, not deltas)
+        #: recaptures the patcher served IN PLACE: topo in-rows (with their
+        #: collectors) kept because the batch's adds restored exactly the
+        #: in-set the mirror held, and lat slots whose epoch was rewritten
+        #: where they lay (the source's own row or its forwarding tree)
+        self.mirror_rows_kept = 0
+        self.mirror_slots_revived = 0
         self.mirror_rebuilds = 0  # full topo rebuilds
         # adaptive sweep passes (ISSUE 17): a patched mirror runs sweeps
         # under a device-side fixed-point loop (passes=0 sentinel) instead
@@ -396,7 +402,8 @@ class DeviceGraph:
             # inside the next burst is exactly the cost live churn can't pay
             jnp = self._jnp
             idx = np.arange(start, start + k, dtype=np.int32)
-            pad = self._pad_ids_pow2(idx)  # repeats idx[0]: same values rewrite
+            # repeats idx[0]: same values rewrite
+            pad = self._pad_ids_pow2(idx, self.SCATTER_MIN_WIDTH)
             if len(pad) != k:
                 src = np.concatenate([src, np.full(len(pad) - k, src[0], np.int32)])
                 dst = np.concatenate([dst, np.full(len(pad) - k, dst[0], np.int32)])
@@ -467,7 +474,7 @@ class DeviceGraph:
             self._record_mirror_delta("bump", node_ids.copy())
         if self._g is not None and not self._dirty:
             jnp = self._jnp
-            ids = jnp.asarray(self._pad_ids_pow2(node_ids))
+            ids = jnp.asarray(self._pad_ids_pow2(node_ids, self.SCATTER_MIN_WIDTH))
             # pads repeat the first id: the epoch bump must NOT double-
             # apply, so the fused op masks pad lanes via a length scalar
             ne, inv = _fused_bump()(
@@ -478,13 +485,20 @@ class DeviceGraph:
         else:
             self._dirty = True
 
+    #: narrowest scatter of an edge append or an epoch bump: a served
+    #: command's re-reads journal a few dozen edges and a handful of bumps,
+    #: a different count every command, and each power of two below this
+    #: would be a program of its own, first met (and compiled) whenever
+    #: traffic happens to produce it
+    SCATTER_MIN_WIDTH = 256
+
     @staticmethod
-    def _pad_ids_pow2(node_ids: np.ndarray) -> np.ndarray:
-        """Pow2-pad an id batch by REPEATING the first id (idempotent for
-        set-style scatters) so the device scatter's shape quantizes: live
-        batches vary per call, and every fresh shape is a fresh
-        executable (~seconds of compile)."""
-        width = _round_up_pow2(len(node_ids))
+    def _pad_ids_pow2(node_ids: np.ndarray, floor: int = 1) -> np.ndarray:
+        """Pow2-pad an id batch (no narrower than ``floor``) by REPEATING
+        the first id (idempotent for set-style scatters) so the device
+        scatter's shape quantizes: live batches vary per call, and every
+        fresh shape is a fresh executable (~seconds of compile)."""
+        width = max(_round_up_pow2(len(node_ids)), floor)
         if width == len(node_ids):
             return node_ids
         out = np.full(width, node_ids[0], dtype=np.int32)
@@ -816,9 +830,17 @@ class DeviceGraph:
         Patchable deltas (the churn shapes, VERDICT r3 #1):
         - ``bump v``: v's in-edges die → clear v's mirror in-row (levels
           only lose constraints — still a valid topological order); the
-          lat mirror needs nothing (its slot epochs stop matching);
-        - ``add u→v`` where both are mirror-known and v's row has a free
-          slot. A LEVEL-VIOLATING add (``level(u) >= level(v)`` in the
+          lat mirror needs nothing (its slot epochs stop matching). A
+          RECAPTURE is kept instead: where the adds that follow the bump in
+          this batch (up to v's next bump) restore exactly the in-set the
+          mirror holds for v — the real sources under v's in-row and under
+          its collectors — nothing is cleared, no slot moves and no
+          violation is counted (``mirror_rows_kept``). A row with fan-in
+          past ``k`` could not be re-spliced at all: its sources sit under
+          collectors, and the row itself has only the slack slots free;
+        - ``add u→v`` where both are mirror-known, the mirror does not hold
+          the edge yet (in v's row or under its collectors) and v's row has
+          a free slot. A LEVEL-VIOLATING add (``level(u) >= level(v)`` in the
           frozen order — a genuinely new dependency direction) is still
           patchable: each such edge needs one extra sweep pass to
           propagate, so the mirror runs ``1 + n_viol`` passes (monotone OR
@@ -858,6 +880,8 @@ class DeviceGraph:
         viol_by_row: Dict[int, set] = m.setdefault("viol_by_row", {})
         n_viol = int(m.get("n_viol", 0))
         mutated = False
+        stride = np.int64(n_tot + 1)  # (in-row, source row) -> one int64 key
+        restored = self._restored_in_keys(deltas, inv_perm, n_known, stride)
 
         def _break_patched():
             if mutated:
@@ -866,13 +890,21 @@ class DeviceGraph:
                 m["fp"] = None
             return self._break_mirror_deltas()
 
-        for kind, payload in deltas:
+        for seq, (kind, payload) in enumerate(deltas):
             if kind == "bump":
                 v = np.asarray(payload, dtype=np.int64)
                 v = v[v < n_known]  # born after build: no mirrored in-edges
                 if v.size == 0:
                     continue
                 rows = inv_perm[v]
+                # a recapture: the adds this bump governs restore the very
+                # in-set the mirror holds → the row and its collectors stay
+                held = self._mirror_in_keys(m, rows, stride)
+                differ = np.setxor1d(held, restored.get(seq, held[:0])) // stride
+                rows = rows[np.isin(rows, differ)]
+                self.mirror_rows_kept += len(np.setdiff1d(held // stride, differ))
+                if rows.size == 0:
+                    continue
                 h[rows, :] = n_tot
                 changed_parts.append(rows)
                 mutated = True
@@ -899,8 +931,12 @@ class DeviceGraph:
                     )
                 ru = inv_perm[u64]
                 rv = inv_perm[v64]
-                # drop edges already present (duplicates: closure-identical)
-                present = (h[rv] == ru[:, None]).any(axis=1)
+                # drop edges already present (duplicates: closure-identical),
+                # in the row itself or under one of its collectors
+                present = np.isin(
+                    rv * stride + ru,
+                    self._mirror_in_keys(m, np.unique(rv), stride),
+                )
                 ru, rv = ru[~present], rv[~present]
                 if ru.size == 0:
                     continue
@@ -973,6 +1009,58 @@ class DeviceGraph:
         return True
 
     @staticmethod
+    def _mirror_in_keys(m: dict, rows: np.ndarray, stride) -> np.ndarray:
+        """The in-set the topo mirror holds for each of ``rows`` (mirror row
+        ids): every REAL source in the row itself and under the collectors
+        below it, as sorted unique keys ``row * stride + source row``. A
+        walk of the collector tree, one gather a level: its depth is
+        log_k of the row's fan-in."""
+        h, real, n_tot = m["h_in_src"], m["h_row_real"], m["n_tot"]
+        owner = cur = np.asarray(rows, dtype=np.int64)
+        parts = []
+        while cur.size:
+            ent = h[cur].astype(np.int64)
+            own = np.broadcast_to(owner[:, None], ent.shape)
+            is_src = real[ent]  # the null row is not real: pads drop out
+            parts.append(own[is_src] * stride + ent[is_src])
+            below = (ent != n_tot) & ~is_src  # collectors: one level down
+            cur, owner = ent[below], own[below]
+        return np.unique(np.concatenate(parts)) if parts else np.empty(0, np.int64)
+
+    @staticmethod
+    def _restored_in_keys(deltas, inv_perm, n_known: int, stride) -> Dict[int, np.ndarray]:
+        """Per ``bump`` delta of a patch batch (by its index in the batch),
+        the in-edges the batch re-adds under it: the adds into a bumped node
+        that follow that bump and precede the node's next one, as sorted
+        unique :meth:`_mirror_in_keys` keys. A bump with no such add has no
+        entry."""
+        b_seq, b_v, a_seq, a_u, a_v = [], [], [], [], []
+        for seq, (kind, payload) in enumerate(deltas):
+            if kind == "bump":
+                v = np.asarray(payload, dtype=np.int64)
+                b_v.append(v)
+                b_seq.append(np.full(len(v), seq, dtype=np.int64))
+            else:
+                a_u.append(np.asarray(payload[0], dtype=np.int64))
+                a_v.append(np.asarray(payload[1], dtype=np.int64))
+                a_seq.append(np.full(len(a_u[-1]), seq, dtype=np.int64))
+        if not b_v or not a_v:
+            return {}
+        b_seq, b_v = np.concatenate(b_seq), np.concatenate(b_v)
+        a_seq, a_u, a_v = (np.concatenate(x) for x in (a_seq, a_u, a_v))
+        known = (a_u < n_known) & (a_v < n_known)
+        a_seq, a_u, a_v = a_seq[known], a_u[known], a_v[known]
+        # the bump that governs an add: the last bump of its target before it
+        n_seq = np.int64(len(deltas) + 1)
+        order = np.argsort(b_v * n_seq + b_seq)
+        b_key, b_seq = (b_v * n_seq + b_seq)[order], b_seq[order]
+        at = np.searchsorted(b_key, a_v * n_seq + a_seq) - 1
+        governed = (at >= 0) & (b_key[np.maximum(at, 0)] // n_seq == a_v)
+        gov = b_seq[np.maximum(at, 0)][governed]
+        keys = inv_perm[a_v[governed]] * stride + inv_perm[a_u[governed]]
+        return {int(seq): np.unique(keys[gov == seq]) for seq in np.unique(gov)}
+
+    @staticmethod
     def _quantize_scatter_rows(rows: np.ndarray, null_row: int) -> np.ndarray:
         """Pad a changed-row batch to a coarse width bucket (pow2, floor
         1024) with the null row: every distinct scatter width is a fresh
@@ -1030,36 +1118,54 @@ class DeviceGraph:
     def _patch_lat_add_batch(
         self, m: dict, lat: dict, u64, v64, ep_a, lat_changed_parts: list
     ):
-        """Vectorized lat-mirror half of an add-delta: one new out-slot per
-        (u, v, epoch) triple, duplicates dropped, free slots assigned by
-        within-row rank. A slot is free when it is a pad or DEAD: its real
-        dependent has been bumped past the slot's captured epoch, so it can
-        never fire again (a bump leaves the lat tables alone, and a row
-        whose dependent is recaptured over and over, a written row that
-        its subscribers keep re-reading, would otherwise fill up with dead
-        slots within a few commands). A full out-row (or unknown node)
-        breaks ONLY the lat mirror — lone waves fall back to the topo sweep
-        while lane bursts keep patching. Returns the lat dict, or None once
-        broken."""
+        """Vectorized lat-mirror half of an add-delta, per (u, v, epoch)
+        triple (duplicates dropped, of one edge the newest capture kept).
+
+        An edge the mirror already holds a slot for is REVIVED where the
+        slot lies: in ``u``'s own row, or in a virtual row of ``u``'s
+        forwarding tree (found through the tree index built with the
+        mirror, never by walking a hub's tree). A slot at the add's epoch
+        is a duplicate; any other is dead, because its dependent has been
+        bumped past its captured epoch, and takes the new epoch
+        (``mirror_slots_revived``). A recaptured dependent so keeps its one
+        slot under every source, however many dependents the source has.
+
+        Only an edge the mirror does not hold looks for a free slot in
+        ``u``'s own row, by within-row rank. A slot is free when it is a pad
+        or DEAD (a bump leaves the lat tables alone). A full out-row (or
+        unknown node) breaks ONLY the lat mirror — lone waves fall back to
+        the topo sweep while lane bursts keep patching. Returns the lat
+        dict, or None once broken."""
         if u64.size == 0:
             return lat
         if int(u64.max()) >= lat["n_real"] or int(v64.max()) >= lat["n_real"]:
             m["lat"] = None
             return None
         hd, he = lat["h_ell_dst"], lat["h_ell_epoch"]
-        ln_tot = lat["n_tot"]
-        ep = np.asarray(ep_a, dtype=np.int64)
-        # drop slots already live-present with the same captured epoch
-        dup = ((hd[u64] == v64[:, None]) & (he[u64] == ep[:, None])).any(axis=1)
-        u, v, e = u64[~dup], v64[~dup], ep[~dup]
-        if u.size == 0:
-            return lat
-        # in-batch dedup by (u, v, epoch); sort groups edges by out-row
-        order = np.lexsort((e, v, u))
-        u, v, e = u[order], v[order], e[order]
-        first = np.ones(len(u), dtype=bool)
-        first[1:] = (u[1:] != u[:-1]) | (v[1:] != v[:-1]) | (e[1:] != e[:-1])
-        u, v, e = u[first], v[first], e[first]
+        ln_tot, k = lat["n_tot"], hd.shape[1]
+        # of one edge's captures the newest: an older one is dead on arrival
+        order = np.lexsort((np.asarray(ep_a, dtype=np.int64), v64, u64))
+        u, v, e = u64[order], v64[order], np.asarray(ep_a, dtype=np.int64)[order]
+        last = np.ones(len(u), dtype=bool)
+        last[:-1] = (u[1:] != u[:-1]) | (v[1:] != v[:-1])
+        u, v, e = u[last], v[last], e[last]
+        # where the mirror already holds u→v: the flat slot, or -1
+        own = hd[u] == v[:, None]
+        at = np.where(own.any(axis=1), u * k + own.argmax(axis=1), -1)
+        miss = np.flatnonzero(at < 0)
+        if miss.size and len(lat["tree_slots"]):
+            at[miss] = self._lat_tree_slots(lat, u[miss], v[miss])
+        held = at >= 0
+        if held.any():
+            flat_e = he.reshape(-1)
+            stale = flat_e[at[held]] != e[held]
+            if stale.any():
+                flat_e[at[held][stale]] = e[held][stale]
+                self.mirror_slots_revived += int(stale.sum())
+                lat_changed_parts.append(at[held][stale] // k)
+            u, v, e = u[~held], v[~held], e[~held]
+            if u.size == 0:
+                return lat
         idx = np.arange(len(u))
         grp_start = np.ones(len(u), dtype=bool)
         grp_start[1:] = u[1:] != u[:-1]
@@ -1077,6 +1183,47 @@ class DeviceGraph:
         he[u, slot] = e
         lat_changed_parts.append(u)
         return lat
+
+    @staticmethod
+    def _lat_tree_index(ell_dst: np.ndarray, n_real: int, n_tot: int):
+        """Where each real dependent sits below a forwarding tree of the
+        lat out-ELL: ``(tree_slots, tree_root)``. ``tree_slots`` is every
+        slot of a VIRTUAL row that holds a real target, as sorted int64
+        keys ``target << 32 | flat slot``; ``tree_root[row]`` is the real
+        source at the top of a virtual row's tree (a real row is its own).
+        A dependent's slots are one ``searchsorted`` range, and the root
+        says which of them is under the source asked for."""
+        k = ell_dst.shape[1]
+        root = np.arange(n_tot + 1, dtype=np.int32)
+        cur = np.flatnonzero(
+            ((ell_dst[:n_real] >= n_real) & (ell_dst[:n_real] < n_tot)).any(axis=1)
+        )
+        while cur.size:  # one round a tree level
+            ent = ell_dst[cur]
+            below = (ent >= n_real) & (ent < n_tot)
+            children = ent[below]
+            root[children] = np.broadcast_to(root[cur][:, None], ent.shape)[below]
+            cur = children
+        flat = ell_dst[n_real:n_tot].reshape(-1)
+        sel = np.flatnonzero(flat < n_real)
+        slots = np.sort((flat[sel].astype(np.int64) << 32) | (sel + n_real * k))
+        return slots, root
+
+    @staticmethod
+    def _lat_tree_slots(lat: dict, u: np.ndarray, v: np.ndarray) -> np.ndarray:
+        """The flat slot that holds ``u[i] → v[i]`` in a virtual row of
+        ``u[i]``'s forwarding tree, or -1: ``v``'s range of the tree index,
+        filtered by the tree's root."""
+        slots, root, k = lat["tree_slots"], lat["tree_root"], lat["h_ell_dst"].shape[1]
+        lo = np.searchsorted(slots, v << 32)
+        n = np.searchsorted(slots, (v + 1) << 32) - lo
+        pair = np.repeat(np.arange(len(v)), n)
+        flat = slots[np.repeat(lo, n) + np.arange(n.sum()) - np.repeat(n.cumsum() - n, n)]
+        flat &= 0xFFFFFFFF
+        under = root[flat // k] == u[pair]
+        out = np.full(len(v), -1, dtype=np.int64)
+        out[pair[under]] = flat[under]
+        return out
 
     def _live_edge_fingerprint(self):
         """(live src, live dst, fingerprint) of the CURRENT live edge set
@@ -1428,6 +1575,9 @@ class DeviceGraph:
             # incremental-patch state: host copy of the in-ELL (slot
             # occupancy truth) + level boundaries as an array for row→level
             "h_in_src": topo.in_src.copy(),
+            # mirror rows of real nodes (a collector or a pad row is not):
+            # the walk of a row's in-set tells sources from collectors by it
+            "h_row_real": np.asarray(topo.is_real, dtype=bool),
             "level_starts_arr": np.asarray(topo.level_starts, dtype=np.int64),
             # a fresh install honors the adaptive-sweep mode (ISSUE 17): a
             # mid-loop re-level must not silently revert to fixed passes
@@ -1486,12 +1636,17 @@ class DeviceGraph:
                     0,
                 ).astype(np.int32)
             ))
+        tree_slots, tree_root = self._lat_tree_index(lat.ell_dst, lat.n_real, lat.n_tot)
         return {
             "n_tot": lat.n_tot,
             "n_real": lat.n_real,
             "k": lat.k,
             "ell_dst": ell_dst_dev,
             "ell_epoch": ell_epoch_dev,
+            # the build-time slots below forwarding trees, for the patcher's
+            # revive-in-place (a slot the patcher adds lies in a real row)
+            "tree_slots": tree_slots,
+            "tree_root": tree_root,
             # slot-occupancy truth for patching — a REAL copy: jnp.asarray
             # above may be zero-copy on the CPU backend, and patching this
             # table in place would race the async kernel reads of the

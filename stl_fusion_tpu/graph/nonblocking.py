@@ -72,6 +72,8 @@ import time
 from collections import deque
 from typing import TYPE_CHECKING, Deque, List, Optional, Sequence
 
+import numpy as np
+
 from ..diagnostics.metrics import global_metrics
 from ..diagnostics.tracing import hot_span
 
@@ -271,7 +273,10 @@ class WavePipeline:
             {"pending": pending, "waves": waves, "seqs": seqs,
              "cause": cause, "t0": t0, "harvest": harvest}
         )
-        while len(self._inflight) > self.MAX_INFLIGHT:
+        # a backend with hot tables refreshes them after every harvest, from
+        # the device's invalid state: no later chain may be in flight then
+        window = 0 if backend._hot_blocks else self.MAX_INFLIGHT
+        while len(self._inflight) > window:
             self._harvest(self._inflight.popleft())
 
     def harvest_inflight(self) -> None:
@@ -331,6 +336,8 @@ class WavePipeline:
         finally:
             backend.overlap_active = False
             backend.last_wave_seq = seqs[0]
+        if backend._hot_blocks and len(stage_masks):
+            backend.refresh_hot(np.logical_or.reduce(stage_masks))
         dt_apply = time.perf_counter() - t_apply0
         self.apply_s_total += dt_apply
         if overlap:
@@ -384,6 +391,8 @@ class WavePipeline:
                     wave._resolve(int(count), seqs[i])
                 self.apply_s_total += time.perf_counter() - t_apply0
                 total += int(count)
+                if backend._hot_blocks:
+                    backend.refresh_hot(ids)
         except Exception as e:  # noqa: BLE001 — no watchdog contained it
             self._on_chain_fault(e, waves[i:], seqs[i:], cause)  # wave i and after
             return
@@ -431,6 +440,8 @@ class WavePipeline:
                 backend.last_cause_id = cause
                 backend.last_wave_seq = seqs[0]
                 backend._apply_newly(committed)
+                if backend._hot_blocks:
+                    backend.refresh_hot(committed)
         wd = backend.watchdog
         if wd is not None:
             wd._on_fault(e)
@@ -462,6 +473,8 @@ class WavePipeline:
                 wave.cause = cause
                 wave._resolve(int(count), seqs[i])
                 total += int(count)
+                if backend._hot_blocks:
+                    backend.refresh_hot(ids)
         finally:
             backend.last_wave_seq = seqs[0]
         t1 = time.perf_counter()

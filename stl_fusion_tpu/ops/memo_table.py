@@ -119,6 +119,10 @@ class MemoTable:
         #: (threaded as runtime args, never closure constants).
         self.device_compute_fn = None
         self.device_loader_args = None
+        #: kept hot (set by TableBacking(hot=True)): a graph backend this
+        #: table is bound to recomputes on the device, after every wave,
+        #: the rows the wave invalidated (TpuGraphBackend.refresh_hot)
+        self.hot = False
         #: optional key codec (set by TableBacking wiring): arbitrary
         #: hashable keys ⇄ dense rows — see read_keys/invalidate_keys
         self.key_codec = None

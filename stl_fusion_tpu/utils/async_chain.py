@@ -10,6 +10,7 @@ a cancellation-scoped lifetime. Same shape here on asyncio.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import logging
 import random
 from dataclasses import dataclass, replace
@@ -125,7 +126,17 @@ class WorkerBase:
         if self._task is None or self._task.done():
             self._stop_requested = False
             loop = asyncio.get_event_loop()
-            self._task = loop.create_task(self._run_guarded(), name=self._worker_name)
+            # a FRESH context: a long-lived worker must not inherit what its
+            # starter happened to be in the middle of. An RPC peer is made
+            # by a client's first call, inside that call's compute lock
+            # (``_held_keys``), its dependency capture and its spans; every
+            # handler the peer later runs (a ``$sys-c`` invalidation and
+            # whatever the application does on it, such as a re-read of that
+            # same key) would otherwise run "inside" that first compute
+            self._task = loop.create_task(
+                self._run_guarded(), name=self._worker_name,
+                context=contextvars.Context(),
+            )
         return self
 
     async def _run_guarded(self) -> None:

@@ -889,3 +889,158 @@ async def test_backend_lane_burst_applies_to_hub():
         assert c2[1] == 3  # base, mid, top (counts mid+top again)
     finally:
         set_default_hub(old)
+
+
+# ------------------------------------------------- recaptures with fan-in (PR 38)
+
+def fan_in_graph(indeg: int, fanout: int, spare: int = 2):
+    """``indeg`` sources, ``fanout`` dependents that each depend on ALL of
+    them (in-degree ``indeg``, every source with ``fanout`` dependents), and
+    ``spare`` sources beside them with one dependent each (for in-sets that
+    differ). Both mirrors built. Returns (graph, sources, dependents,
+    spare sources)."""
+    srcs = np.arange(indeg, dtype=np.int32)
+    deps = np.arange(indeg, indeg + fanout, dtype=np.int32)
+    extra = np.arange(indeg + fanout, indeg + fanout + spare, dtype=np.int32)
+    sink = indeg + fanout + spare
+    src = np.concatenate([np.repeat(srcs, fanout), extra])
+    dst = np.concatenate([np.tile(deps, indeg), np.full(spare, sink, np.int32)])
+    g = DeviceGraph(node_capacity=sink + 8, edge_capacity=len(src) + 4096)
+    g.add_nodes(sink + 1)
+    g.add_edges(src, dst)
+    g.build_topo_mirror()
+    return g, srcs, deps, extra
+
+
+def live_closure(g, seeds):
+    """Host BFS over the graph's own live edges (captured-at-epoch rule)."""
+    m = g.n_edges
+    live = g._h_node_epoch[g._h_edge_dst[:m]] == g._h_edge_dst_epoch[:m]
+    adj = {}
+    for u, v in zip(g._h_edge_src[:m][live].tolist(), g._h_edge_dst[:m][live].tolist()):
+        adj.setdefault(u, set()).add(v)
+    seen, frontier = set(seeds), list(seeds)
+    while frontier:
+        frontier = [v for u in frontier for v in adj.get(u, ()) if v not in seen
+                    and not seen.add(v)]
+    return seen
+
+
+def recapture(g, v, sources):
+    """What a scalar twin's recompute journals: bump, then the in-edges."""
+    g.bump_epochs(np.array([v], np.int32))
+    if len(sources):
+        g.add_edges(np.asarray(sources, np.int32), np.full(len(sources), v, np.int32))
+
+
+def assert_waves_equal_bfs(g, seed):
+    want = live_closure(g, [seed])
+    lat0 = g.lat_waves
+    _count, ids = g.run_waves_union([[seed]])
+    assert set(ids.tolist()) == want
+    g.clear_invalid()
+    _count, ids = g._run_mirror_union([[seed]])
+    assert set(ids.tolist()) == want
+    g.clear_invalid()
+    return g.lat_waves - lat0
+
+
+@pytest.mark.parametrize("indeg,fanout", [(6, 3), (7, 7), (16, 500)])
+def test_recapture_survives_fan_in_and_hubs(indeg, fanout):
+    """A row behind collectors (in-degree past the in-ELL's 4 + 2) under
+    sources behind forwarding trees (out-degree past the out-ELL's): a
+    recapture that restores the in-set keeps both mirrors, moves no slot,
+    and the waves after it equal the host BFS."""
+    g, srcs, deps, _extra = fan_in_graph(indeg, fanout)
+    m = g._topo_mirror
+    h0, d0 = m["h_in_src"].copy(), m["lat"]["h_ell_dst"].copy()
+    v = int(deps[len(deps) // 2])
+    recapture(g, v, srcs)
+    assert g._mirror_valid() and g._mirror_deltas == []
+    assert m["lat"] is not None and g.mirror_patches == 1
+    assert g.mirror_rows_kept == 1 and g.mirror_slots_revived == indeg
+    np.testing.assert_array_equal(m["h_in_src"], h0)
+    np.testing.assert_array_equal(m["lat"]["h_ell_dst"], d0)
+    assert assert_waves_equal_bfs(g, int(srcs[0])) == 1  # the lat mirror served
+
+
+@pytest.mark.parametrize("indeg,fanout", [(6, 3), (16, 40)])
+def test_ten_recaptures_in_a_row_leak_no_slot(indeg, fanout):
+    g, srcs, deps, _extra = fan_in_graph(indeg, fanout)
+    m = g._topo_mirror
+    h0, d0 = m["h_in_src"].copy(), m["lat"]["h_ell_dst"].copy()
+    for i in range(10):
+        recapture(g, int(deps[i % 3]), srcs)
+        # the same edges captured once more, as a scalar body's awaits do
+        g.add_edges(srcs, np.full(len(srcs), int(deps[i % 3]), np.int32))
+        assert g._mirror_valid() and m["lat"] is not None
+    assert g.mirror_rows_kept == 10 and g.mirror_slots_revived == 10 * indeg
+    np.testing.assert_array_equal(m["h_in_src"], h0)
+    np.testing.assert_array_equal(m["lat"]["h_ell_dst"], d0)
+    assert g.mirror_rebuilds == 1
+    assert assert_waves_equal_bfs(g, int(srcs[1])) == 1
+
+
+@pytest.mark.parametrize("change", ["one_more", "one_less", "another"])
+@pytest.mark.parametrize("indeg", [3, 16])
+def test_a_recapture_with_another_in_set_takes_the_old_road(indeg, change):
+    """The row is cleared and its new in-set spliced into its free slots:
+    patched where they suffice (a row of 3, which has no collectors), broken
+    where they do not (a row of 16 has 6 slots for 15-17 sources)."""
+    g, srcs, deps, extra = fan_in_graph(indeg, 5)
+    v = int(deps[0])
+    new = {
+        "one_more": np.concatenate([srcs, extra[:1]]),
+        "one_less": srcs[:-1],
+        "another": np.concatenate([srcs[:-1], extra[:1]]),
+    }[change]
+    recapture(g, v, new)
+    assert g._mirror_valid() == (indeg == 3)
+    assert g.mirror_rows_kept == 0
+    if indeg == 3:
+        assert g._mirror_deltas == [] and g.mirror_patches == 1
+        row = g._topo_mirror["h_in_src"][g._topo_mirror["inv_perm"][v]]
+        assert sorted(row[row != g._topo_mirror["n_tot"]].tolist()) == sorted(
+            g._topo_mirror["inv_perm"][new].tolist()
+        )
+        for seed in (int(srcs[0]), int(srcs[-1]), int(extra[0])):
+            assert_waves_equal_bfs(g, seed)
+    else:
+        assert g._mirror_deltas is None  # in-degree overflow: a rebuild's
+        want = live_closure(g, [int(srcs[0])])
+        assert set(g.run_waves_union([[int(srcs[0])]])[1].tolist()) == want
+
+
+def test_a_bump_with_no_add_clears_the_row():
+    g, srcs, deps, _extra = fan_in_graph(7, 7)
+    v = int(deps[2])
+    g.bump_epochs(np.array([v], np.int32))
+    assert g._mirror_valid() and g.mirror_rows_kept == 0
+    m = g._topo_mirror
+    assert (m["h_in_src"][m["inv_perm"][v]] == m["n_tot"]).all()
+    for seed in srcs[:2].tolist():
+        assert v not in live_closure(g, [seed])
+        assert_waves_equal_bfs(g, seed)
+
+
+def test_plawdag_recaptures_patch_as_before():
+    """In-degree <= 3, no collectors: every recapture is served by one patch
+    batch, both mirrors stay, no rebuild, the in-sets and the waves are the
+    host's. (Rows whose in-set is restored are kept where they lie; the
+    device tables are then not rewritten for them.)"""
+    from stl_fusion_tpu.graph.synthetic import power_law_dag
+
+    n = 400
+    src, dst = power_law_dag(n, avg_degree=3, seed=38)
+    g = DeviceGraph(node_capacity=n, edge_capacity=len(src) * 4)
+    g.add_nodes(n)
+    g.add_edges(src, dst)
+    g.build_topo_mirror()
+    rng = np.random.default_rng(38)
+    for i, v in enumerate(rng.choice(np.unique(dst), size=12, replace=False).tolist()):
+        recapture(g, v, src[dst == v])
+        assert g._mirror_valid() and g._mirror_deltas == []
+        assert g.mirror_patches == i + 1 and g._topo_mirror["lat"] is not None
+        assert assert_waves_equal_bfs(g, int(src[dst == v][0])) == 1
+    assert g.mirror_rebuilds == 1 and g._topo_mirror.get("n_viol", 0) == 0
+    assert g.mirror_rows_kept == 12

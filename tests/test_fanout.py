@@ -397,10 +397,10 @@ async def test_fanout_index_drains_newly_mask_to_batches():
         set_default_hub(old)
 
 
-def test_coalesce_bump_epack_pairs_rules():
+def test_group_commuting_entries_keeps_the_bump_epack_rules():
     """The flush pre-pass: alternating distinct-nid bump/epack pairs regroup
     into runs; repeated nids and foreign kinds end a run in place."""
-    coalesce = TpuGraphBackend._coalesce_bump_epack_pairs
+    coalesce = TpuGraphBackend._group_commuting_entries
 
     def ep(nid, srcs=(5,)):
         return (
