@@ -325,6 +325,9 @@ class TpuGraphBackend:
         #: waves whose hot blocks the whole-block program refreshed: the
         #: wave arrived as a mask, or with more rows than the sparse widths
         self.hot_refresh_block_fallbacks = 0
+        #: blocks refresh_block_on_device refreshed by the refresh_rows
+        #: program on their few invalid rows, not by the whole-block one
+        self.block_refresh_sparse = 0
         self._sharded_mirror: Optional[dict] = None  # see sharded_mirror
         self._packed_mirror: Optional[dict] = None  # see packed_mirror
         self._routed_mirror: Optional[dict] = None  # see routed_mirror
@@ -403,6 +406,7 @@ class TpuGraphBackend:
             "fusion_hot_refresh_rows_total": self.hot_refresh_rows,
             "fusion_hot_refresh_dispatches_total": self.hot_refresh_dispatches,
             "fusion_hot_refresh_block_fallbacks_total": self.hot_refresh_block_fallbacks,
+            "fusion_refresh_block_sparse_total": self.block_refresh_sparse,
         }
 
     def _begin_wave(self) -> str:
@@ -1149,10 +1153,21 @@ class TpuGraphBackend:
         until the table's own next read of it (``read_batch``,
         ``table.refresh``) recomputes it.
 
-        The loader runs for EVERY row of the table and the result is
-        masked. Its sparse twin, :meth:`refresh_rows_on_device`, runs it
-        for the rows of one wave alone; a table declared ``hot`` gets one
-        or the other after every wave (:meth:`refresh_hot`)."""
+        The program is chosen by how many rows the graph holds invalid in
+        the block. From 1 to :data:`HOT_REFRESH_MAX_ROWS` of them, the
+        ``refresh_rows`` program of :meth:`refresh_rows_on_device` runs on
+        those rows alone (span ``refresh.sparse``, counted in
+        ``block_refresh_sparse``), with the same host bookkeeping by the
+        ids; its ids are padded to the cap itself, so a block has one such
+        program, compiled by its first sparse refresh, and no width can
+        first appear (and compile) later. With more rows, or none, the
+        whole-block program runs: the loader for EVERY row of the table,
+        the result masked. Every graph-invalid row of a bound block is
+        stale on its table, so the table's exact O(1) stale count above the
+        cap takes the whole program with no scan of the host mirror; a
+        scan that finds more rows than the cap takes it too. A table
+        declared ``hot`` gets one refresh or the other after every wave
+        (:meth:`refresh_hot`)."""
         with hot_span("refresh"):
             self.flush()
             table = block.table
@@ -1168,6 +1183,14 @@ class TpuGraphBackend:
                     f"(block covers {block.n_rows} of {table.n_rows} rows); "
                     "partially bound tables refresh through table.refresh()"
                 )
+            cap = self.HOT_REFRESH_MAX_ROWS
+            if table._stale_count <= cap:
+                rows = np.flatnonzero(self.graph._h_invalid[block.base : block.end()])
+                if 0 < rows.size <= cap:
+                    with hot_span("refresh.sparse"):
+                        self._refresh_block_rows(block, rows.astype(np.int32), cap)
+                    self.block_refresh_sparse += 1
+                    return int(rows.size)
             g = self.graph.device_arrays()
             update_valid = not table._valid_dev_dirty
             loader_args = self._loader_args(table)
@@ -1224,7 +1247,9 @@ class TpuGraphBackend:
     HOT_REFRESH_MIN_WIDTH = 512
     #: a wave with more rows than this in one hot block refreshes the block
     #: by the whole-block program (the lat kernel's own cap on a wave's ids:
-    #: the sparse widths stay a handful of programs)
+    #: the sparse widths stay a handful of programs); so does
+    #: refresh_block_on_device for a block with more invalid rows, and pads
+    #: the ids of one with fewer to this width (a power of two)
     HOT_REFRESH_MAX_ROWS = 8192
 
     def refresh_hot(self, newly) -> int:
@@ -1272,7 +1297,6 @@ class TpuGraphBackend:
             return 0
         with hot_span("refresh.rows"):
             self.flush()
-            dg = self.graph
             sparse, whole = 0, []  # rows by refresh_rows; by the block program
             for blk in self._hot_blocks:
                 rows = nids[(nids >= blk.base) & (nids < blk.end())] - blk.base
@@ -1282,29 +1306,37 @@ class TpuGraphBackend:
                     whole.append(self.refresh_block_on_device(blk))
                     continue
                 rows = np.unique(rows).astype(np.int32)
-                table = blk.table
-                padded = dg._pad_ids_pow2(rows, self.HOT_REFRESH_MIN_WIDTH)
-                loader_args = self._loader_args(table)
-                g = dg.device_arrays()
-                with hot_span("refresh.rows.dispatch"):
-                    table._values, inv2 = self._refresh_rows_program(blk)(
-                        table._values, g.invalid, table._put(padded), *loader_args
-                    )
-                dg._g = g._replace(invalid=inv2)
-                dg._h_invalid[blk.base + rows] = False
-                dg.invalid_version += 1
-                table._stale_count -= int(np.count_nonzero(table._stale_host[rows]))
-                table._stale_host[rows] = False
-                table._defer_valid(rows, True)
-                table._bump()
-                for h in table.on_refresh:
-                    if not getattr(h, "_backend_hook", False):
-                        h(rows)
+                self._refresh_block_rows(blk, rows, self.HOT_REFRESH_MIN_WIDTH)
                 self.hot_refresh_dispatches += 1
                 sparse += len(rows)
             self.hot_refresh_rows += sparse
             self.hot_refresh_block_fallbacks += bool(whole)
             return sparse + sum(whole)
+
+    def _refresh_block_rows(self, blk: RowBlock, rows: np.ndarray, floor: int) -> None:
+        """One ``refresh_rows`` dispatch on the rows ``rows`` (unique int32
+        row ids of ``blk``), padded to a power of two no narrower than
+        ``floor``, and the host bookkeeping from the ids: ``_h_invalid``,
+        ``invalid_version``, the table's stale mask and count, its deferred
+        validity, its version, the non-backend ``on_refresh`` hooks."""
+        dg, table = self.graph, blk.table
+        padded = dg._pad_ids_pow2(rows, floor)
+        loader_args = self._loader_args(table)
+        g = dg.device_arrays()
+        with hot_span("refresh.rows.dispatch"):
+            table._values, inv2 = self._refresh_rows_program(blk)(
+                table._values, g.invalid, table._put(padded), *loader_args
+            )
+        dg._g = g._replace(invalid=inv2)
+        dg._h_invalid[blk.base + rows] = False
+        dg.invalid_version += 1
+        table._stale_count -= int(np.count_nonzero(table._stale_host[rows]))
+        table._stale_host[rows] = False
+        table._defer_valid(rows, True)
+        table._bump()
+        for h in table.on_refresh:
+            if not getattr(h, "_backend_hook", False):
+                h(rows)
 
     @staticmethod
     def _refresh_rows_program(block: RowBlock):

@@ -267,6 +267,12 @@ def act_superround(backend, table, block):
 
 
 def act_refresh(backend, table, block):
+    backend.refresh_block_on_device(block)  # a lone wave's rows: the sparse branch
+    return None
+
+
+def act_refresh_whole(backend, table, block):
+    backend.HOT_REFRESH_MAX_ROWS = 0  # instance override: the whole-block program
     backend.refresh_block_on_device(block)
     return None
 
@@ -299,6 +305,7 @@ SPAN_SITES = {
         "superround.dispatch": None, "superround.wait": None,
         "superround.apply": None, "wave.profile": "superround.apply"}),
     "refresh": (act_refresh, {}),
+    "refresh_whole": (act_refresh_whole, {}),
     "patch": (act_patch, {
         "cascade": None, "wave.union": "cascade",
         "mirror.validate": "wave.union", "mirror.patch": "mirror.validate",
@@ -308,7 +315,9 @@ SPAN_SITES = {
 SEQLESS = {
     "flush_icasc": {"flush": None, "flush.coalesce": "flush", "flush.replay.icasc": "flush"},
     "superround": {"superround.stage": None},
-    "refresh": {"refresh": None, "refresh.dispatch": "refresh"},
+    "refresh": {"refresh": None, "refresh.sparse": "refresh",
+                "refresh.rows.dispatch": "refresh.sparse"},
+    "refresh_whole": {"refresh": None, "refresh.dispatch": "refresh"},
     "patch": {"flush": "cascade", "flush.coalesce": "flush", "flush.replay.epack": "flush"},
 }
 
@@ -319,7 +328,7 @@ def test_span_sites_of_the_live_loop(site, gate, monkeypatch, annotations):
     if site == "overflow":
         monkeypatch.setattr(DeviceGraph, "LAT_CAP", 32)
     backend, table, block = make_stack()
-    if site == "refresh":
+    if site.startswith("refresh"):
         backend.cascade_rows_batch(block, [N_ROWS - 1])  # something to refresh
     act, with_seq = SPAN_SITES[site]
     if gate == "off":

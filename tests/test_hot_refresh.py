@@ -173,6 +173,7 @@ def test_rows_refresh_equals_block_refresh_on_the_waves_rows(worlds, with_cart):
         t.on_refresh.append(lambda rows, n=n: hooks[n].append(np.asarray(rows).copy()))
     v0 = a.be.graph.invalid_version
     assert a.be.refresh_rows_on_device(ids) == len(ids)
+    b.be.HOT_REFRESH_MAX_ROWS = 0  # instance override: b's blocks take the whole program
     for blk in b.be._hot_blocks:
         b.be.refresh_block_on_device(blk)
     sa, sb = a.state(), b.state()
