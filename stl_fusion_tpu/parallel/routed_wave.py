@@ -34,12 +34,15 @@ frontier with mesh collectives instead of surfacing to the host:
 - ``exchange="gather"``: plain ``lax.all_gather`` of packed words — the
   reference for equivalence tests.
 
-Once a wave, before the level loop: the version check, one local row
-gather (``node_epoch[dst] == edge epoch`` — device-local by construction,
-the reason edges shard by destination), the per-edge word index, and the
-wave's WORKLIST: the slots that passed the check (no pad, none behind its
-destination's version) as (source key, destination row) pairs, sorted to a
-prefix. Per level, after the exchange: the worklist fires in chunks — per
+The WORKLIST is resident: the slots that pass the version check (one local
+row gather, ``node_epoch[dst] == edge epoch`` — device-local by
+construction, the reason edges shard by destination; no pad, none behind
+its destination's version) as (source key, destination row) pairs, sorted
+to a prefix by a program of its own. It is a fact of the graph, not of the
+wave: the graph builds it again only after one of the arrays it derives
+from was replaced (a patch, a resize, a reshard, a snapshot import), so a
+static graph builds it once. A wave starts from a copy of it. Per level,
+after the exchange: the worklist fires in chunks — per
 slot ONE indexed read (the source word) and one place in the chunk's local
 scatter — and a slot that fired leaves it (a node is in a frontier at most
 once a wave, so the slot can never change the result again): a wave's
@@ -161,18 +164,28 @@ def _chunk_width(e_cap: int) -> int:
 def build_routed_wave(
     mesh: Mesh, n_global: int, n_dev: int, exchange: str, async_depth: int = 0
 ):
-    """Compile the routed union wave for a mesh + geometry. Returns
-    ``wave(frontier, send_idx, hsend_idx, eprod, ebslot, ebit, edst,
-    elsrc, eepoch, nepoch, invalid) -> (invalid', count, levels,
-    spec_levels, visits)`` — all arrays sharded over the mesh's flat
-    device axis; seeds conduct even when already invalid (the r4 union
-    rule); ``levels`` is the number of frontier exchanges the wave ran (the
-    collective-rounds telemetry ``fusion_mesh_exchange_levels``
-    aggregates); ``visits`` the worklist slots its levels read, per device
-    as uint32 ``[lo, hi]``. For ``exchange="hier"`` the mesh must be the 2-D
-    ``(host, ldev)`` mesh; bucket capacities are read from the
-    (trace-time) table shapes, which is what lets an in-place bucket
-    resize recompile instead of re-pack.
+    """Compile the routed union wave for a mesh + geometry. Returns the
+    pair ``(worklist, wave)``:
+
+    - ``worklist(send_idx, hsend_idx, eprod, ebslot, ebit, edst, eepoch,
+      nepoch) -> (wkey, wdst, n_live)``: the live slots of each device's
+      edge slice (none behind its destination's version, no pad) as
+      (source key, destination row) pairs sorted to a prefix, and their
+      number per device. A fact of the graph: it changes only with what it
+      is computed from.
+    - ``wave(frontier, send_idx, hsend_idx, (wkey, wdst, n_live), invalid,
+      *local) -> (invalid', count, levels, spec_levels, visits)``, starting
+      its level loop from that worklist; ``local`` is ``(elsrc, edst,
+      eepoch, nepoch)`` in the async mode and empty otherwise.
+
+    All arrays are sharded over the mesh's flat device axis; seeds conduct
+    even when already invalid (the r4 union rule); ``levels`` is the number
+    of frontier exchanges the wave ran (the collective-rounds telemetry
+    ``fusion_mesh_exchange_levels`` aggregates); ``visits`` the worklist
+    slots its levels read, per device as uint32 ``[lo, hi]``. For
+    ``exchange="hier"`` the mesh must be the 2-D ``(host, ldev)`` mesh;
+    bucket capacities are read from the (trace-time) table shapes, which is
+    what lets an in-place bucket resize recompile instead of re-pack.
 
     ``async_depth >= 1`` compiles the ASYNCHRONOUS execution mode (ISSUE
     17): between global merges each shard advances its LOCAL frontier
@@ -288,13 +301,12 @@ def build_routed_wave(
         return intra, acc.reshape(-1)  # [n_hosts(H) * n_hosts(G) * hcap]
 
     def _word_index(send_idx_l, hsend_idx_l, eprod_l, ebslot_l):
-        """``(idx, words)``: per edge slot the index of its source word in
-        ``words(intra_flat, cross_flat)``, ONE vector over what
-        :func:`_exchange_words` returns, via the cap-independent (eprod,
-        ebslot) routing. The index arithmetic runs here, once a wave; a
-        level pays the indexed read alone. Capacities come from trace-time
-        table shapes — the hook dynamic bucket growth hangs off."""
-        words = lambda intra_flat, _cross: intra_flat  # noqa: E731
+        """Per edge slot the index of its source word in :func:`_words`,
+        ONE vector over what :func:`_exchange_words` returns, via the
+        cap-independent (eprod, ebslot) routing. The index arithmetic runs
+        with the worklist's build; a level pays the indexed read alone.
+        Capacities come from trace-time table shapes — the hook dynamic
+        bucket growth hangs off."""
         if exchange in ("tree", "gather"):
             n_words, idx = n_dev * w_local, ebslot_l
         elif exchange == "a2a":
@@ -313,54 +325,71 @@ def build_routed_wave(
                 n_intra + ((eprod_l - n_dev) * n_hosts + g) * hcap + ebslot_l,
                 (eprod_l % dph) * icap + ebslot_l,
             )
-            words = lambda intra_flat, cross_flat: jnp.concatenate(  # noqa: E731
-                [intra_flat, cross_flat]
-            )
         if n_words >= 1 << 26:
             # a worklist key is (word index << 5 | bit) under _WAITING
             raise ValueError(f"{n_words} exchanged words a device: over 2**26")
-        return idx, words
+        return idx
+
+    def _words(intra_flat, cross_flat):
+        """The exchanged words as the one vector :func:`_word_index`
+        points into: hier's host-tree buckets lie behind its intra rows."""
+        if exchange == "hier":
+            return jnp.concatenate([intra_flat, cross_flat])
+        return intra_flat
 
     @shard_map_compat(
         mesh=mesh,
         in_specs=(
-            node_spec, send_spec, send_spec, edge_spec, edge_spec, edge_spec,
-            edge_spec, edge_spec, edge_spec, node_spec, node_spec,
+            send_spec, send_spec, edge_spec, edge_spec, edge_spec, edge_spec,
+            edge_spec, node_spec,
+        ),
+        out_specs=(edge_spec, edge_spec, edge_spec),
+    )
+    def _worklist(send_idx_l, hsend_idx_l, eprod_l, ebslot_l, ebit_l, edst_l,
+                  eepoch_l, nepoch_l):
+        # A slot whose captured epoch is behind its destination's (or a pad:
+        # eepoch -1 never matches, the gather clamps) never conducts: it
+        # stays out of the worklist, so no level reads it.
+        live = nepoch_l[edst_l] == eepoch_l
+        idx = _word_index(send_idx_l, hsend_idx_l, eprod_l, ebslot_l)
+        # the live slots as (source key = word index and bit, destination
+        # row), in source order, compacted to a prefix by one sort (an
+        # order of magnitude cheaper an element than an indexed op); what
+        # is no live slot sorts behind them
+        key = _WAITING | (idx.astype(jnp.uint32) << 5) | ebit_l.astype(jnp.uint32)
+        wkey, wdst = lax.sort(
+            (jnp.where(live, key, _DEAD_KEY), edst_l), num_keys=1, is_stable=False
+        )
+        return wkey, wdst, live.sum(dtype=jnp.int32)[None]
+
+    # the async mode's local speculation reads the edge slice itself
+    local_specs = (edge_spec, edge_spec, edge_spec, node_spec) if async_depth else ()
+
+    @shard_map_compat(
+        mesh=mesh,
+        in_specs=(
+            node_spec, send_spec, send_spec, (edge_spec, edge_spec, edge_spec),
+            node_spec, *local_specs,
         ),
         out_specs=(node_spec, P(), P(), P(), node_spec),
     )
-    def _wave(seeds_l, send_idx_l, hsend_idx_l, eprod_l, ebslot_l, ebit_l,
-              edst_l, elsrc_l, eepoch_l, nepoch_l, inv_l):
+    def _wave(seeds_l, send_idx_l, hsend_idx_l, work_l, inv_l, *local_l):
         fresh = seeds_l & ~inv_l
         inv_l = inv_l | seeds_l
         count0 = lax.psum(fresh.sum(dtype=jnp.int32), ax)
         go0 = lax.psum(seeds_l.any().astype(jnp.int32), ax) > 0
 
-        # once a wave, outside the level loop: nothing here changes while a
-        # wave runs. A slot whose captured epoch is behind its destination's
-        # (or a pad: eepoch -1 never matches, the gather clamps) never
-        # conducts: it stays out of the worklist, so the version check costs
-        # a level nothing.
-        live = nepoch_l[edst_l] == eepoch_l
-        idx, words = _word_index(send_idx_l, hsend_idx_l, eprod_l, ebslot_l)
-
-        # the WORKLIST: the live slots as (source key = word index and bit,
-        # destination row), in source order, compacted to a prefix by one
-        # sort (an order of magnitude cheaper an element than an indexed
-        # op); what is no live slot sorts behind them. It is a value of
-        # this wave: a slot leaves it once it has fired, because a node is
-        # in a frontier at most once a wave.
-        e_cap = edst_l.shape[0]
+        # the wave's own copy of the worklist, in whole chunks (a slice
+        # never clamps): a slot leaves it once it has fired, because a node
+        # is in a frontier at most once a wave
+        wkey, wdst, n_live = work_l
+        e_cap = wkey.shape[0]
         chunk = _chunk_width(e_cap)
-        key = _WAITING | (idx.astype(jnp.uint32) << 5) | ebit_l.astype(jnp.uint32)
-        wkey, wdst = lax.sort(
-            (jnp.where(live, key, _DEAD_KEY), edst_l), num_keys=1, is_stable=False
-        )
-        pad = -e_cap % chunk  # whole chunks: a slice never clamps
+        pad = -e_cap % chunk
         work0 = (
             jnp.concatenate([wkey, jnp.full(pad, _DEAD_KEY, jnp.uint32)]),
             jnp.concatenate([wdst, jnp.full(pad, n_local, jnp.int32)]),
-            live.sum(dtype=jnp.int32),
+            n_live[0],
         )
         lane = jnp.arange(chunk, dtype=jnp.int32)
 
@@ -375,7 +404,7 @@ def build_routed_wave(
             bit. Shared by the sync per-level step and the async merge
             epoch. Returns ``(newly lit rows, the worklist left)``."""
             wkey, wdst, n_work = work
-            recv = words(*_exchange_words(frontier, send_idx_l, hsend_idx_l))
+            recv = _words(*_exchange_words(frontier, send_idx_l, hsend_idx_l))
 
             def fire(hit, k, d, valid, fired, n_fired):
                 """ONE sort serves the scatter and the partition: the slots
@@ -431,6 +460,8 @@ def build_routed_wave(
         if async_depth and async_depth > 0:
             # ---- asynchronous mode: speculative local levels between
             # counted-quiescence merges (ISSUE 17) ----
+            elsrc_l, edst_l, eepoch_l, nepoch_l = local_l
+            live = nepoch_l[edst_l] == eepoch_l
             edst_live = jnp.where(live, edst_l, n_local)
 
             def spec_body(_i, st):
@@ -493,7 +524,7 @@ def build_routed_wave(
         )
         return inv_l, count, levels, jnp.int32(0), visits
 
-    return jax.jit(_wave)
+    return jax.jit(_worklist), jax.jit(_wave)
 
 
 def build_routed_compact(mesh: Mesh, n_global: int, n_dev: int, capd: int):
@@ -534,8 +565,31 @@ def build_routed_compact(mesh: Mesh, n_global: int, n_dev: int, capd: int):
     return _compact
 
 
+class _WorklistSource:
+    """A resident array the wave's worklist is computed from. Assigning it
+    drops the worklist, so the next wave builds it again: no writer can
+    forget to. It defines no ``__get__``, so a read is the plain instance
+    attribute."""
+
+    def __set_name__(self, owner, name):
+        self.name = name
+
+    def __set__(self, graph, value):
+        graph.__dict__[self.name] = value
+        graph.__dict__["g_work"] = None
+
+
 class RoutedShardedGraph:
     """Mesh-sharded device graph whose layout IS the cluster shard map."""
+
+    g_send = _WorklistSource()
+    g_hsend = _WorklistSource()
+    g_eprod = _WorklistSource()
+    g_ebslot = _WorklistSource()
+    g_ebit = _WorklistSource()
+    g_edst = _WorklistSource()
+    g_eep = _WorklistSource()
+    g_node_epoch = _WorklistSource()
 
     def __init__(
         self,
@@ -632,6 +686,7 @@ class RoutedShardedGraph:
         self.async_depth = int(async_depth) if self.exchange_async else 0
         # -- telemetry --
         self.waves_run = 0
+        self.worklist_builds = 0  # the resident worklist built (anew)
         self.levels_total = 0  # frontier exchanges (collective rounds)
         self.slot_visits_total = 0  # worklist slots the levels read, all chips
         self.quiescence_checks = 0  # async merge epochs (each = one vote)
@@ -708,10 +763,14 @@ class RoutedShardedGraph:
         else:
             self.set_invalid(invalid)
         self.g_is_real = self._put(self._h_is_real, self._node_sh)
-        self._wave = build_routed_wave(
+        self._worklist, self._wave = build_routed_wave(
             self.mesh, self.n_global, self.n_dev, self.exchange,
             async_depth=self.async_depth,
         )
+        #: the resident worklist ``(wkey, wdst, n_live)``; None until the
+        #: next wave builds it (assigning a ``_WorklistSource`` drops it)
+        self.g_work = None
+        self._worklist_warmed: set = set()
         self._collect_cache: dict = {}
         self._chain_cache: dict = {}
         self._patch_cache: dict = {}
@@ -1304,14 +1363,11 @@ class RoutedShardedGraph:
                 raise PlacementError("seed node lands on an off-mesh shard")
             rows[:k] = r
         capd = max(cap // self.n_dev, 1024)
+        self._ensure_worklist()
         cause = self._trace_cause_for_dispatch()
         t0 = time.perf_counter()
         with hot_span("routed.dispatch"):
-            args = (
-                self._host_arg(rows), self.g_send, self.g_hsend, self.g_eprod,
-                self.g_ebslot, self.g_ebit, self.g_edst, self.g_elsrc, self.g_eep,
-                self.g_node_epoch, self.g_invalid, self.g_is_real,
-            )
+            args = (self._host_arg(rows), self.g_is_real, *self._wave_args())
             fn = self._collect_cache.get((capd, width))
             if fn is None:
                 fn = self._build_collect(capd)
@@ -1357,20 +1413,56 @@ class RoutedShardedGraph:
         n_global = self.n_global
 
         @jax.jit
-        def collect(seed_rows, send, hsend, eprod, ebslot, ebit, edst, elsrc,
-                    eep, nepoch, inv, is_real):
+        def collect(seed_rows, is_real, send, hsend, work, inv, *local):
             frontier = lax.with_sharding_constraint(
                 jnp.zeros(n_global, bool).at[seed_rows].set(True, mode="drop"),
                 node_sh,
             )
             inv2, _count, levels, spec, visits = wave(
-                frontier, send, hsend, eprod, ebslot, ebit, edst, elsrc,
-                eep, nepoch, inv,
+                frontier, send, hsend, work, inv, *local
             )
             counts, bufs = compact(inv2, inv, is_real)
             return inv2, counts, levels, spec, bufs, visits
 
         return collect
+
+    def _wave_args(self) -> tuple:
+        """What a wave reads besides its seeds and ``g_is_real``, in
+        ``wave``'s order: the send tables, the resident worklist, the
+        invalid state and, in the async mode, the edge slice its local
+        speculation reads."""
+        local = (
+            (self.g_elsrc, self.g_edst, self.g_eep, self.g_node_epoch)
+            if self.async_depth else ()
+        )
+        return (self.g_send, self.g_hsend, self.g_work, self.g_invalid, *local)
+
+    def _ensure_worklist(self) -> None:
+        """Build the resident worklist where there is none: at the first
+        wave, and after an array it is computed from was replaced. In a
+        graph whose topology and epochs stay put, every later wave reuses
+        it. Counted in ``worklist_builds``."""
+        if self.g_work is not None:
+            return
+        args = (
+            self.g_send, self.g_hsend, self.g_eprod, self.g_ebslot, self.g_ebit,
+            self.g_edst, self.g_eep, self.g_node_epoch,
+        )
+        with hot_span("routed.worklist"):
+            key = (self.n_global, self.n_dev, self.exchange, self.e_cap,
+                   self.bucket_cap, self.hbucket_cap)
+            if key not in self._worklist_warmed:
+                self._worklist_warmed.add(key)
+                with time_program_warm("routed_worklist", key=key):
+                    self._worklist.lower(*args).compile()
+            self.g_work = self._worklist(*args)
+        self.worklist_builds += 1
+        global_metrics().counter(
+            "fusion_mesh_routed_worklist_builds_total",
+            help="routed worklists built: at a graph's first wave and after "
+            "a patch, resize, reshard or snapshot import replaced an array "
+            "it is computed from (a static graph builds one)",
+        ).inc()
 
     # ------------------------------------------------------------------ chain
     def stage_union_chain(
@@ -1430,12 +1522,11 @@ class RoutedShardedGraph:
         if fn is None:
             fn = self._build_chain(capd)
             self._chain_cache[(K, width, capd)] = fn
+        self._ensure_worklist()
         trace_cause = self._trace_cause_for_dispatch()
         trace_t0 = time.perf_counter()
         self.g_invalid, counts, levels, spec, bufs, visits = fn(
-            self._host_arg(mat), self.g_send, self.g_hsend, self.g_eprod,
-            self.g_ebslot, self.g_ebit, self.g_edst, self.g_elsrc, self.g_eep,
-            self.g_node_epoch, self.g_invalid, self.g_is_real,
+            self._host_arg(mat), self.g_is_real, *self._wave_args()
         )
         # multi-process: the chain's collectives must fully drain before
         # any later module's (harvest fetch, patch) hit the gloo pairs —
@@ -1453,16 +1544,16 @@ class RoutedShardedGraph:
         n_global = self.n_global
 
         @jax.jit
-        def chain(seed_mat, send, hsend, eprod, ebslot, ebit, edst, elsrc,
-                  eep, nepoch, inv0, is_real):
+        def chain(seed_mat, is_real, send, hsend, work, inv0, *local):
+            # the graph cannot change inside the scan: every stage starts
+            # from the one resident worklist
             def body(inv, seed_rows):
                 frontier = lax.with_sharding_constraint(
                     jnp.zeros(n_global, bool).at[seed_rows].set(True, mode="drop"),
                     node_sh,
                 )
                 inv2, _c, levels, spec, visits = wave(
-                    frontier, send, hsend, eprod, ebslot, ebit, edst, elsrc,
-                    eep, nepoch, inv,
+                    frontier, send, hsend, work, inv, *local
                 )
                 counts, bufs = compact(inv2, inv, is_real)
                 return inv2, (counts, levels, spec, bufs, visits)
@@ -2137,6 +2228,7 @@ class RoutedShardedGraph:
             "hbucket_cap": self.hbucket_cap,
             "placement_epoch": self.placement.epoch,
             "waves_run": self.waves_run,
+            "worklist_builds": self.worklist_builds,
             "exchange_levels_total": self.levels_total,
             "slot_visits_total": self.slot_visits_total,
             "exchange_async": self.exchange_async,

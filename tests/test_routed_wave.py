@@ -380,6 +380,114 @@ def test_worklist_spans_chunks_and_chain_counts_visits(exchange):
     check_worklist_chain(exchange)
 
 
+WORKLIST_WRITERS = ["patch", "patch_resize", "placement", "snapshot"]
+
+
+@pytest.mark.parametrize("writer", WORKLIST_WRITERS)
+@pytest.mark.parametrize("exchange", EXCHANGES)
+def test_resident_worklist_follows_every_writer(exchange, writer):
+    """A wave starts from the RESIDENT worklist, built by the
+    first wave and again only after an array it is computed from was
+    replaced. After construction and after each writer (``patch_batch``'s
+    bumps and adds; a ``patch_batch`` whose adds overflow the edge slack,
+    which grows in place; a kill and a join moved by ``apply_placement``;
+    ``import_shard_state``) the worklist the next wave used equals a fresh
+    build exactly, and ``n_live`` counts the live slots on the host; a
+    wave between two writers builds nothing; every wave matches
+    :class:`HostLevels`, its levels and slot visits included."""
+    n = 4000
+    smap = ShardMap.initial(["a", "b"], n_shards=32)
+    pl = DevicePlacement.build(
+        smap, 8, n, slot_headroom=3.0,
+        devices_per_host=4 if exchange == "hier" else None,
+    )
+    rng = np.random.default_rng(43)
+    src, dst, _adj = make_graph(n, seed=13)
+    pre = np.zeros(n, dtype=bool)
+    pre[rng.choice(n, size=150, replace=False)] = True
+    host = HostLevels(src, dst, n, invalid=pre)
+    g = RoutedShardedGraph(
+        src, dst, n, pl, mesh=graph_mesh(), exchange=exchange, invalid=pre,
+        edge_headroom=1.3 if writer == "patch_resize" else 2.5,
+        bucket_headroom=2.5,
+    )
+    assert g.exchange == exchange
+
+    def waves(builds):
+        """Two waves: the first rebuilt the worklist when a writer dropped
+        it, the second reuses it."""
+        for _ in range(2):
+            seeds = rng.choice(n // 10, size=4, replace=False).tolist()
+            levels0, visits0 = g.levels_total, g.slot_visits_total
+            want_count, want_levels, want_ids, want_mask = host.wave(seeds)
+            count, ids, over = g.run_wave_collect(seeds)
+            assert not over and count == want_count
+            assert np.array_equal(np.sort(ids), want_ids)
+            assert np.array_equal(g.invalid_mask(), want_mask)
+            assert g.levels_total - levels0 == want_levels
+            assert g.slot_visits_total - visits0 == host.slot_visits
+            assert g.worklist_builds == builds == g.stats()["worklist_builds"]
+            fresh = g._worklist(
+                g.g_send, g.g_hsend, g.g_eprod, g.g_ebslot, g.g_ebit, g.g_edst,
+                g.g_eep, g.g_node_epoch,
+            )
+            for got, want in zip(g.g_work, fresh):
+                assert np.array_equal(np.asarray(got), np.asarray(want))
+            assert np.array_equal(np.asarray(g.g_work[2]), live_slots(g))
+
+    def bump(n_bump, n_redeclare):
+        """Bump rows (their in-edges go stale) and declare one in-edge of
+        some of them again at the new epoch."""
+        bumped = rng.choice(np.unique(host.dst), size=n_bump, replace=False)
+        again = bumped[:n_redeclare]
+        first_in = dict(zip(host.dst[::-1].tolist(), host.src[::-1].tolist()))
+        u = np.array([first_in[v] for v in again.tolist()], dtype=np.int64)
+        ep = host.nepoch[again] + 1
+        host.patch(bumped, u, again, ep)
+        assert g.patch_batch(bumped, u, again, ep.astype(np.int32))
+
+    assert g.worklist_builds == 0 and g.g_work is None
+    waves(builds=1)
+    if writer == "patch":
+        bump(n_bump=200, n_redeclare=50)
+        assert g.g_work is None
+        waves(builds=2)
+    elif writer == "patch_resize":
+        # adds into one row, more than its device's edge slack holds
+        v = n - 1
+        d = int(g.perm[v]) // g.n_local
+        k = g.e_cap - int(g._dev_edge_count[d]) + 1
+        u = rng.integers(0, v, size=k)
+        ep = np.full(k, host.nepoch[v], dtype=np.int32)
+        host.patch([], u, np.full(k, v), ep)
+        e_cap = g.e_cap
+        assert g.patch_batch(np.empty(0, np.int64), u, np.full(k, v), ep)
+        assert g.resize_detail["edge"] >= 1 and g.e_cap > e_cap
+        assert g.g_work is None
+        waves(builds=2)
+    elif writer == "placement":
+        m2 = smap.with_members(["a"])
+        pl2, moves = pl.moved_to(m2, mesh_members=["a"])
+        assert moves
+        g.apply_placement(pl2, moves)
+        assert g.g_work is None
+        waves(builds=2)
+        pl3, moves3 = pl2.moved_to(m2.with_members(["a", "c"]), mesh_members=["a", "c"])
+        assert moves3
+        g.apply_placement(pl3, moves3)
+        assert g.g_work is None
+        waves(builds=3)
+    else:
+        snap = g.export_shard_state()
+        saved = host.nepoch.copy(), host.inv.copy()
+        bump(n_bump=200, n_redeclare=0)
+        waves(builds=2)
+        assert g.import_shard_state(snap) == 32
+        host.nepoch, host.inv = saved
+        assert g.g_work is None
+        waves(builds=3)
+
+
 def _sub_jaxprs(eqn):
     for v in eqn.params.values():
         for x in v if isinstance(v, (list, tuple)) else (v,):
@@ -400,13 +508,17 @@ def _named(jaxpr, *prefixes):
 
 
 def test_level_loop_reads_each_edge_slot_once():
-    """ISSUE 33 and 37, structural: the level loop (the a2a sync wave's
-    outer ``while``) holds ONE inner loop, over the chunks of the
+    """Structural: the level loop (the a2a sync
+    wave's outer ``while``) holds ONE inner loop, over the chunks of the
     worklist; a chunk is exactly one indexed read of the exchanged words
     and one scatter, both of chunk width. Nothing of the width of the
     edge slice is read or scattered inside a level, and no node-row table
-    is read per slot. A per-edge lookup put back inside the level fails
-    here, not in a benchmark."""
+    is read per slot. Nor once a wave: the version check's gather and the
+    worklist's sort are the worklist program's, and the wave program
+    holds no gather from a node-row table at edge width and no sort of
+    edge width outside its level loop. A per-edge lookup put back inside
+    the level, or the worklist's build put back into the wave, fails here,
+    not in a benchmark."""
     import jax
 
     from stl_fusion_tpu.parallel.routed_wave import _chunk_width
@@ -418,9 +530,9 @@ def test_level_loop_reads_each_edge_slot_once():
     chunk = _chunk_width(g.e_cap)
     assert g.e_cap not in (g.n_local, g.w_local, g.g_send.shape[-1], chunk)
     assert chunk not in (g.n_local, g.w_local, g.g_send.shape[-1])
+    g._ensure_worklist()
     jaxpr = jax.make_jaxpr(g._wave)(
-        g.g_invalid, g.g_send, g.g_hsend, g.g_eprod, g.g_ebslot, g.g_ebit,
-        g.g_edst, g.g_elsrc, g.g_eep, g.g_node_epoch, g.g_invalid,
+        g.g_invalid, g.g_send, g.g_hsend, g.g_work, g.g_invalid,
     )
     # two loops in the program: the level loop and, in its body, the chunks'
     whiles = _named(jaxpr.jaxpr, "while")
@@ -455,6 +567,24 @@ def test_level_loop_reads_each_edge_slot_once():
     for e in _named(level, "gather", "scatter", "sort"):
         for v in e.invars:
             assert g.e_cap not in v.aval.shape and padded not in v.aval.shape, e
+    # outside the level loop, the wave neither reads a node row per edge
+    # slot nor sorts the edge slice: it starts from the resident worklist
+    in_level = {id(e) for e in _eqns(level)}
+    once = [e for e in _named(jaxpr.jaxpr, "gather", "sort") if id(e) not in in_level]
+    for e in once:
+        widths = {d for v in e.invars for d in v.aval.shape}
+        assert not widths & {g.e_cap, padded}, e
+    # both are the worklist program's: ONE gather of the node epochs at
+    # edge width (the version check) and ONE sort of the edge slice
+    wl = jax.make_jaxpr(g._worklist)(
+        g.g_send, g.g_hsend, g.g_eprod, g.g_ebslot, g.g_ebit, g.g_edst, g.g_eep,
+        g.g_node_epoch,
+    )
+    (check,) = _named(wl.jaxpr, "gather")
+    assert check.invars[0].aval.shape == (g.n_local,)
+    assert check.invars[1].aval.shape[0] == g.e_cap
+    (sort,) = _named(wl.jaxpr, "sort")
+    assert sort.invars[0].aval.shape == (g.e_cap,) and len(sort.invars) == 2
 
 
 @pytest.mark.parametrize("dph", [2, 4])
